@@ -493,7 +493,7 @@ def _run_concurrent(sf: float, conc: int, which) -> None:
         "concurrency": conc,
         "sf": sf,
         "n_queries": len(which),
-        "backend": _backend(),
+        "device": _device(),
         "serial_wall_s": round(serial_wall, 5),
         "concurrent_wall_s": round(conc_wall, 5),
         "serial_qps": round(len(which) / serial_wall, 4),
@@ -868,10 +868,12 @@ def main() -> None:
         return
     name = which[0]
     print(json.dumps({name: _run_one(name, sf, iters),
-                      "backend": _backend()}))
+                      "device": _device()}))
 
 
-def _assemble(sf: float, results: dict, detail: dict) -> dict:
+def _assemble(sf: float, results: dict, detail: dict, device) -> dict:
+    """``device`` is the identity the first answering child printed (None
+    until one has)."""
     speedups = list(results.values())
     geomean = (math.exp(sum(math.log(s) for s in speedups) / len(speedups))
                if speedups else 0.0)
@@ -883,7 +885,7 @@ def _assemble(sf: float, results: dict, detail: dict) -> dict:
         "sf": sf,
         "queries_completed": sorted(results),
         "n_queries": len(results),
-        "backend": _backend(),
+        "device": device,
         **detail,
     }
 
@@ -898,6 +900,10 @@ def _run_isolated(sf: float, iters: int, which) -> None:
     t_start = time.monotonic()
     results = {}
     detail = {}
+    # this parent never imports jax: a process that has touched JAX holds
+    # the chip and the next child could not get it.  Device identity is
+    # copied from the first child that answered.
+    device = None
     for q in which:
         remaining = wall - (time.monotonic() - t_start)
         if remaining < 15:
@@ -924,6 +930,7 @@ def _run_isolated(sf: float, iters: int, which) -> None:
             if proc.returncode == 0 and sub is not None and q in sub:
                 detail[q] = sub[q]
                 results[q] = sub[q]["speedup"]
+                device = device or sub["device"]
             else:
                 detail[q] = {"error":
                              proc.stderr.strip().splitlines()[-1][:200]
@@ -932,8 +939,12 @@ def _run_isolated(sf: float, iters: int, which) -> None:
             detail[q] = {"error": f"timeout after {q_budget}s"}
         # flush the aggregate after EVERY query: a killed run still
         # leaves the latest complete snapshot as the last stdout line
-        print(json.dumps(_assemble(sf, results, detail)), flush=True)
-    print(json.dumps(_assemble(sf, results, detail)), flush=True)
+        print(json.dumps(_assemble(sf, results, detail, device)),
+              flush=True)
+    print(json.dumps(_assemble(sf, results, detail, device)), flush=True)
+    failed = [q for q in which if q not in results]
+    if failed:
+        sys.exit(f"bench: no result for {','.join(failed)}")
 
 
 def _soak_drill() -> dict:
@@ -1102,9 +1113,12 @@ def _fuzz_drill() -> dict:
         _srt.Session.reset()
 
 
-def _backend() -> str:
+def _device() -> dict:
+    """Device identity as JAX reports it (child side only)."""
     import jax
-    return jax.default_backend()
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs)}
 
 
 if __name__ == "__main__":

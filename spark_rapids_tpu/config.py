@@ -110,8 +110,7 @@ COALESCE_ENABLED = register(
     "Insert CoalesceBatches operators (GpuCoalesceBatches analog) that "
     "merge small batches to each consumer's declared goal — TargetSize "
     "(batchSizeRows) before aggregates/sorts, RequireSingleBatch before "
-    "windows. Amortizes per-batch dispatch (a full RPC round-trip on "
-    "tunneled backends) and XLA program reuse.")
+    "windows. Amortizes per-batch dispatch and XLA program reuse.")
 
 MIN_CAPACITY = register(
     "spark.rapids.tpu.sql.minBatchCapacity", 1024,
@@ -511,9 +510,9 @@ DENSE_JOIN_MIN_PROBE = register(
     "Smallest ESTIMATED probe-side row count for which a broadcast join "
     "engages the dense direct-address machinery (build-key stats fetch, "
     "dense table, dynamic partition pruning). Below it the sorted "
-    "kernel runs without the stats round trip — on tunneled backends "
-    "each host sync costs ~0.1-0.2 s, which a tiny probe never earns "
-    "back. 0 always engages.")
+    "kernel runs without the stats round trip: a blocking fetch stalls "
+    "the dispatch front, which a tiny probe never earns back. 0 always "
+    "engages.")
 
 DENSE_JOIN_DOMAIN_CAP = register(
     "spark.rapids.tpu.join.denseDomainCap", 1 << 26,
@@ -641,7 +640,7 @@ CACHE_BROADCAST_ENABLED = register(
     "subtree's structural fingerprint (scan tokens + stage expression "
     "fingerprints). Cached builds carry their probed dense-join key "
     "stats, so a reuse hit also skips the build's blocking stats "
-    "fetches (~2 host round trips per join on tunneled backends).")
+    "fetches (two per join).")
 
 CACHE_TTL_MS = register(
     "spark.rapids.tpu.sql.cache.ttlMs", 0,
@@ -1467,10 +1466,13 @@ class TpuConf:
 
 
 XLA_CACHE_DIR = register(
-    "spark.rapids.tpu.xla.cacheDir", "~/.cache/spark_rapids_tpu/xla",
+    "spark.rapids.tpu.xla.cacheDir", ".cache/xla",
     "Persistent XLA compilation cache directory; compiled programs survive "
-    "process restarts, fixing minutes-long cold starts on remote-tunneled "
-    "backends. Empty disables.", startup_only=True)
+    "process restarts. A relative path is resolved against the checkout "
+    "(the directory that holds the spark_rapids_tpu package), so every "
+    "process of one checkout shares one cache. When the environment sets "
+    "JAX_COMPILATION_CACHE_DIR that directory is used instead and this "
+    "key is ignored. Empty disables.", startup_only=True)
 
 # -- warm-start subsystem (runtime/warmstore.py, plan/bucketing.py) -----------
 
@@ -1482,8 +1484,11 @@ WARMSTORE_ENABLED = register(
     "restart (docs/warmstart.md).")
 
 WARMSTORE_DIR = register(
-    "spark.rapids.tpu.warmstore.dir", "~/.cache/spark_rapids_tpu/warmstore",
-    "Directory for the warm-start store's index manifest. Unwritable paths "
+    "spark.rapids.tpu.warmstore.dir", "warmstore",
+    "Directory for the warm-start store's index manifest. A relative path "
+    "is resolved against the XLA compilation cache directory in effect "
+    "(JAX_COMPILATION_CACHE_DIR, else xla.cacheDir): the index describes "
+    "the executables cached there and travels with them. Unwritable paths "
     "degrade to an in-memory store (warmstore_errors_total{kind=store_dir}) "
     "instead of failing startup. Empty keeps the store in-memory only.",
     startup_only=True)

@@ -377,8 +377,8 @@ def _is_transient_device(ex: BaseException) -> bool:
     memory/retry.py) and never ordinary Python errors."""
     if isinstance(ex, TransientFault):
         return True
-    name = type(ex).__name__
-    if "XlaRuntimeError" not in name:
+    import jax
+    if not isinstance(ex, jax.errors.JaxRuntimeError):
         return False
     msg = str(ex)
     if "RESOURCE_EXHAUSTED" in msg:

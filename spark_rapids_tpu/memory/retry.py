@@ -18,6 +18,8 @@ from __future__ import annotations
 import threading
 from typing import Callable, Iterator, List, Optional
 
+import jax
+
 from ..batch import ColumnBatch
 
 __all__ = ["RetryOOM", "SplitAndRetryOOM", "OOMInjector", "device_op",
@@ -68,11 +70,15 @@ INJECTOR = OOMInjector()
 
 
 def _is_xla_oom(ex: BaseException) -> bool:
-    name = type(ex).__name__
     msg = str(ex)
-    return ("XlaRuntimeError" in name or "RuntimeError" in name) and (
-        "RESOURCE_EXHAUSTED" in msg or "Out of memory" in msg
-        or "out of memory" in msg)
+    if isinstance(ex, jax.errors.JaxRuntimeError):
+        return ("RESOURCE_EXHAUSTED" in msg or "Out of memory" in msg
+                or "out of memory" in msg)
+    # seen on the v5e (jaxlib 0.9.0, libtpu 0.0.34): a buffer the runtime
+    # cannot allocate at dispatch is raised as a plain ValueError whose
+    # text opens with the status code ("RESOURCE_EXHAUSTED: Error
+    # allocating device buffer: Attempting to allocate 4.00G ...")
+    return isinstance(ex, ValueError) and msg.startswith("RESOURCE_EXHAUSTED")
 
 
 def device_op(ctx, fn: Callable, *args):

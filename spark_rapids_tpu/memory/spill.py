@@ -416,15 +416,12 @@ def reset_catalog() -> None:
 
 
 def _device_budget(conf) -> int:
-    """poolFraction × device memory (fallback 8 GiB when the backend does
-    not report memory stats, e.g. the CPU test platform)."""
-    import jax
-    frac = conf["spark.rapids.tpu.memory.tpu.poolFraction"]
-    try:
-        stats = jax.devices()[0].memory_stats()
-        total = stats.get("bytes_limit") or stats.get("bytes_reservable_limit")
-        if total:
-            return int(total * frac)
-    except Exception:  # fault-ok (backend reports no memory stats; use fallback)
-        pass
-    return int((8 << 30) * frac)
+    """poolFraction × the session device's memory."""
+    from ..runtime.device import DeviceManager, device_memory_bytes
+    info = DeviceManager.info()
+    if info is not None:
+        total = info.memory_bytes
+    else:
+        import jax
+        total = device_memory_bytes(jax.devices()[0])
+    return int(total * conf["spark.rapids.tpu.memory.tpu.poolFraction"])

@@ -11,6 +11,7 @@ fallback so the engine still works where a toolchain is unavailable —
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -29,7 +30,6 @@ __all__ = ["available", "murmur3_int", "murmur3_long", "murmur3_utf8",
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _SRC = os.path.join(_REPO, "native", "srt_native.cpp")
 _BUILD_DIR = os.path.join(_REPO, "native", "build")
-_SO = os.path.join(_BUILD_DIR, "libsrt_native.so")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -37,15 +37,24 @@ _tried = False
 
 
 def _build() -> Optional[str]:
+    """The library built from this source, named by the source's content
+    hash: a copied tree keeps no mtimes, and a binary built from another
+    version of the source must never be loaded."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    so = os.path.join(_BUILD_DIR, f"libsrt_native-{digest}.so")
+    if os.path.exists(so):
+        return so
     os.makedirs(_BUILD_DIR, exist_ok=True)
-    if (os.path.exists(_SO)
-            and os.path.getmtime(_SO) >= os.path.getmtime(_SRC)):
-        return _SO
+    # build under a private name, then rename: a concurrent process never
+    # loads a half-written library
+    tmp = f"{so}.{os.getpid()}.tmp"
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-           "-o", _SO, _SRC]
+           "-o", tmp, _SRC]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        return _SO
+        os.replace(tmp, so)
+        return so
     except Exception as e:
         log.warning("native build failed (%s); using numpy fallbacks", e)
         return None

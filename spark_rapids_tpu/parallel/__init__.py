@@ -11,20 +11,6 @@ rendezvous, heartbeats, and TCP peer-to-peer partition fetch — the UCX
 transport analog, with the host-shuffle frame file as the wire format.
 """
 
-def shard_map_fn():
-    """The installed jax's shard_map: ``jax.shard_map`` moved in and out
-    of the top-level namespace across releases (0.4.x keeps it at
-    jax.experimental.shard_map.shard_map; the top-level alias raises an
-    accelerated DeprecationError on some builds).  One resolver so every
-    SPMD lowering keeps working across the supported jax range."""
-    import jax
-    try:
-        return jax.shard_map
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map
-        return shard_map
-
-
 from .dcn import (Coordinator, DcnShuffle, PeerFailedError,  # noqa: F401
                   ProcessGroup, run_distributed_agg,
                   run_distributed_query)

@@ -614,8 +614,10 @@ def _materialize_leaf(leaf: _Leaf, ctx, n_dev: int, string_dict):
     return cols, rows
 
 
-def _execute_fragment(lowered, leaves: List[_Leaf], ctx, mesh, axis: str):
-    """Trace + run the fragment on the mesh; return a host Arrow table."""
+def _execute_fragment(lowered, leaves: List[_Leaf], ctx, mesh, axis: str,
+                      metrics):
+    """Trace + run the fragment on the mesh; return a host Arrow table.
+    ``metrics`` is the fragment root's MetricSet."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
@@ -677,9 +679,18 @@ def _execute_fragment(lowered, leaves: List[_Leaf], ctx, mesh, axis: str):
     n_out_cols = len(lowered.schema)
     in_specs = tuple(feed_specs)
     out_specs = tuple(P(axis) for _ in range(2 * n_out_cols + 1)) + (P(axis),)
-    from . import shard_map_fn
-    fn = jax.jit(shard_map_fn()(step, mesh=mesh, in_specs=in_specs,
-                                out_specs=out_specs))
+    fn = jax.jit(jax.shard_map(step, mesh=mesh, in_specs=in_specs,
+                            out_specs=out_specs))
+    # place the inputs on the mesh here, not inside the jit call, so the
+    # bytes each device received are on record: a feed that landed whole
+    # on one device shows in the fragment's iciInputBytes.<device id>
+    from jax.sharding import NamedSharding
+    feeds = [jax.device_put(a, NamedSharding(mesh, spec))
+             for a, spec in zip(feeds, in_specs)]
+    for a in feeds:
+        for shard in a.addressable_shards:
+            metrics.add(f"iciInputBytes.{shard.device.id}",
+                        shard.data.nbytes)
     outs = fn(*feeds)
     ov = np.asarray(outs[-1])
     if ov.sum() > 0:
@@ -742,8 +753,9 @@ def distribute_plan(phys, ctx, mesh, axis: str = "data"):
                 from ..utils import tracing
                 with tracing.span(frag_node.op_id, "ici:fragment",
                                   "ici") as sp:
-                    table = _execute_fragment(lowered, leaves, ctx, mesh,
-                                              axis)
+                    table = _execute_fragment(
+                        lowered, leaves, ctx, mesh, axis,
+                        ctx.metric_set(frag_node.op_id))
                     sp.set(devices=n_dev, leaves=len(leaves),
                            rows=table.num_rows)
                 break

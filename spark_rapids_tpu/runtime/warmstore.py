@@ -51,7 +51,8 @@ from typing import Any, Dict, List, Optional
 
 log = logging.getLogger("spark_rapids_tpu")
 
-__all__ = ["WarmStore", "setup_jax_cache", "topology_key", "initialize",
+__all__ = ["WarmStore", "setup_jax_cache", "xla_cache_dir", "store_dir",
+           "topology_key", "initialize",
            "store", "is_active", "note_statement", "note_program",
            "prewarm", "snapshot", "reset_for_tests"]
 
@@ -66,28 +67,70 @@ _SAVE_INTERVAL_S = 1.0  # throttle: at most one manifest write per second
 # runtime/device.py so one module owns the warm-start disk story).
 # ---------------------------------------------------------------------------------
 
-def setup_jax_cache(conf) -> bool:
-    """Point ``jax_compilation_cache_dir`` at ``xla.cacheDir``.
+_CACHE_DIR_KEY = "spark.rapids.tpu.xla.cacheDir"
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
-    The dir is PROBED for writability first; an unwritable path logs,
-    counts ``warmstore_errors_total{kind=cache_dir}`` (so a fleet
-    silently proceeding cold is visible on /metrics), and returns
-    False — device init never fails over a cache."""
-    cache_dir = conf["spark.rapids.tpu.xla.cacheDir"]
-    if not cache_dir:
+
+def _conf_cache_dir(conf) -> Optional[str]:
+    d = conf[_CACHE_DIR_KEY]
+    return os.path.join(_CHECKOUT, os.path.expanduser(d)) if d else None
+
+
+def xla_cache_dir(conf) -> Optional[str]:
+    """The persistent XLA cache directory in effect:
+    ``JAX_COMPILATION_CACHE_DIR`` when the environment sets it, else
+    ``xla.cacheDir`` with a relative path resolved against the checkout
+    (never the working directory, a pid or a time: the cache must be at
+    the same place for every process of one checkout).  None = disabled."""
+    return (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or _conf_cache_dir(conf))
+
+
+def store_dir(conf) -> Optional[str]:
+    """Where the warm-start index lives.  The index describes the
+    executables in the XLA cache, so a relative ``warmstore.dir`` follows
+    that cache wherever :func:`xla_cache_dir` put it.  None = in-memory."""
+    d = os.path.expanduser(conf["spark.rapids.tpu.warmstore.dir"])
+    if not d or os.path.isabs(d):
+        return d or None
+    base = xla_cache_dir(conf)
+    return os.path.join(base, d) if base else None
+
+
+def _probe_writable(path: str) -> None:
+    """Create ``path`` and prove a file can be written there (raises)."""
+    os.makedirs(path, exist_ok=True)
+    probe = os.path.join(path, ".srt_write_probe")
+    with open(probe, "w") as f:
+        f.write("ok")
+    os.remove(probe)
+
+
+def setup_jax_cache(conf) -> bool:
+    """Turn on JAX's persistent compilation cache at :func:`xla_cache_dir`.
+
+    With ``JAX_COMPILATION_CACHE_DIR`` set JAX reads the variable itself
+    and no directory is set in code.  Otherwise the dir is PROBED for
+    writability first; an unwritable path logs, counts
+    ``warmstore_errors_total{kind=cache_dir}`` (so a fleet silently
+    proceeding cold is visible on /metrics), and returns False — device
+    init never fails over a cache."""
+    path = xla_cache_dir(conf)
+    if not path:
         return False
     import jax
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        if conf.is_set(_CACHE_DIR_KEY) and _conf_cache_dir(conf) != path:
+            log.warning("%s=%s ignored: JAX_COMPILATION_CACHE_DIR=%s "
+                        "places the cache", _CACHE_DIR_KEY,
+                        conf[_CACHE_DIR_KEY], path)
+        return True
     from ..utils import telemetry
-    path = os.path.expanduser(cache_dir)
     try:
-        os.makedirs(path, exist_ok=True)
-        probe = os.path.join(path, ".srt_write_probe")
-        with open(probe, "w") as f:
-            f.write("ok")
-        os.remove(probe)
+        _probe_writable(path)
         jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          0.5)
         return True
     except Exception as e:  # fault-ok (an unwritable cache dir degrades to cold compiles, never fails init)
         log.warning("xla compilation cache unavailable at %s (%s): "
@@ -129,7 +172,7 @@ class WarmStore:
         # same process (the two-door drain/ship shape) must SHARE the
         # live index, not replace it with a stale disk load
         self.conf_key = (self.enabled, self.max_entries, self.max_bytes,
-                         str(conf["spark.rapids.tpu.warmstore.dir"]))
+                         store_dir(conf))
         self._lock = threading.RLock()
         self._entries: "OrderedDict[str, dict]" = OrderedDict()
         self._touched: set = set()        # entry keys noted this process
@@ -146,9 +189,9 @@ class WarmStore:
         self.prewarmed = 0
         self._topo: Optional[str] = None  # resolved lazily (jax init)
         self._dir: Optional[str] = None
-        d = conf["spark.rapids.tpu.warmstore.dir"]
+        d = store_dir(conf)
         if self.enabled and d:
-            self._dir = self._probe_dir(os.path.expanduser(d))
+            self._dir = self._probe_dir(d)
         if self._dir:
             self._load()
 
@@ -156,11 +199,7 @@ class WarmStore:
     def _probe_dir(self, path: str) -> Optional[str]:
         from ..utils import telemetry
         try:
-            os.makedirs(path, exist_ok=True)
-            probe = os.path.join(path, ".srt_write_probe")
-            with open(probe, "w") as f:
-                f.write("ok")
-            os.remove(probe)
+            _probe_writable(path)
             return path
         except Exception as e:  # fault-ok (unwritable store dir degrades to in-memory)
             log.warning("warmstore dir unusable at %s (%s): "
@@ -492,7 +531,7 @@ def initialize(conf) -> Optional[WarmStore]:
             conf_key = (True,
                         conf["spark.rapids.tpu.warmstore.maxEntries"],
                         conf["spark.rapids.tpu.warmstore.maxBytes"],
-                        str(conf["spark.rapids.tpu.warmstore.dir"]))
+                        store_dir(conf))
             if _STORE is not None and _STORE.conf_key == conf_key:
                 return _STORE
             old, _STORE = _STORE, WarmStore(conf)

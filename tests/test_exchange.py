@@ -8,7 +8,6 @@ mechanism the driver uses to validate multi-chip sharding
 import jax
 import jax.numpy as jnp
 
-from spark_rapids_tpu.parallel import shard_map_fn
 import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
@@ -131,8 +130,8 @@ def _run_exchange(n_devices, keys_np, vals_np, bucket_cap=256):
         n_groups = jnp.sum(fmask.astype(jnp.int32)).reshape(1)
         return total, n_groups, overflow.reshape(1)
 
-    fn = jax.jit(shard_map_fn()(step, mesh=mesh, in_specs=(P("data"),) * 2,
-                                out_specs=(P("data"),) * 3))
+    fn = jax.jit(jax.shard_map(step, mesh=mesh, in_specs=(P("data"),) * 2,
+                            out_specs=(P("data"),) * 3))
     totals, n_groups, overflow = fn(keys, vals)
     return (float(jnp.sum(totals)), int(jnp.sum(n_groups)),
             int(jnp.sum(overflow)))
@@ -190,8 +189,8 @@ def test_exchange_multi_key():
         ng = jnp.sum(fmask.astype(jnp.int32)).reshape(1)
         return total, ng, overflow.reshape(1)
 
-    fn = jax.jit(shard_map_fn()(step, mesh=mesh, in_specs=(P("data"),) * 3,
-                                out_specs=(P("data"),) * 3))
+    fn = jax.jit(jax.shard_map(step, mesh=mesh, in_specs=(P("data"),) * 3,
+                            out_specs=(P("data"),) * 3))
     totals, ng, overflow = fn(jnp.asarray(k1), jnp.asarray(k2),
                               jnp.asarray(vals))
     assert int(jnp.sum(overflow)) == 0

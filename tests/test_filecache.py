@@ -85,15 +85,13 @@ def test_device_cache_cleared_on_oom_path(pq_file):
         cache = get_device_cache(1 << 30)
         assert cache._bytes > 0
 
-        class FakeOOM(RuntimeError):
-            pass
-
-        FakeOOM.__name__ = "XlaRuntimeError"
+        import jax
 
         from spark_rapids_tpu.memory.retry import RetryOOM, device_op
 
         def boom():
-            raise FakeOOM("RESOURCE_EXHAUSTED: out of memory")
+            raise jax.errors.JaxRuntimeError(
+                "RESOURCE_EXHAUSTED: out of memory")
 
         with pytest.raises(RetryOOM):
             device_op(None, boom)

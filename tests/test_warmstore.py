@@ -175,13 +175,46 @@ class TestStore:
         st.flush()  # and flushing nowhere never raises
         assert st.snapshot()["entries"] == 1
 
-    def test_setup_jax_cache_unwritable_counts(self, tmp_path):
+    def test_setup_jax_cache_unwritable_counts(self, tmp_path, monkeypatch):
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         blocker = tmp_path / "file"
         blocker.write_text("x")
         conf = TpuConf({"spark.rapids.tpu.xla.cacheDir":
                         str(blocker / "sub")})
         assert warmstore.setup_jax_cache(conf) is False
         assert _ctr("warmstore_errors_total", "cache_dir") == 1.0
+
+    def test_env_places_the_cache_and_code_sets_none(self, tmp_path,
+                                                     monkeypatch):
+        """JAX_COMPILATION_CACHE_DIR set: JAX reads it itself, so
+        setup_jax_cache leaves jax_compilation_cache_dir alone, a
+        disagreeing xla.cacheDir is ignored, and the index follows."""
+        import jax
+        env_dir = str(tmp_path / "from_env")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+        conf = TpuConf({"spark.rapids.tpu.xla.cacheDir":
+                        str(tmp_path / "from_conf")})
+        before = jax.config.jax_compilation_cache_dir
+        assert warmstore.setup_jax_cache(conf) is True
+        assert jax.config.jax_compilation_cache_dir == before
+        assert warmstore.xla_cache_dir(conf) == env_dir
+        assert warmstore.store_dir(conf) == env_dir + "/warmstore"
+        assert not (tmp_path / "from_conf").exists()
+
+    def test_default_cache_is_one_path_inside_the_checkout(
+            self, tmp_path, monkeypatch):
+        import os
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        monkeypatch.chdir(tmp_path)  # never the working directory
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert warmstore.xla_cache_dir(TpuConf()) == \
+            os.path.join(repo, ".cache", "xla")
+        assert warmstore.store_dir(TpuConf()) == \
+            os.path.join(repo, ".cache", "xla", "warmstore")
+        # no cache, no relative place for the index: in-memory
+        off = TpuConf({"spark.rapids.tpu.xla.cacheDir": ""})
+        assert warmstore.xla_cache_dir(off) is None
+        assert warmstore.store_dir(off) is None
 
 
 # ---------------------------------------------------------------------------
