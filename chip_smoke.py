@@ -13,9 +13,11 @@ when more than one device is visible, a shuffled join + grouped aggregate
 over the ICI mesh compared with the single-chip answer.
 
 Prints one JSON line per phase as it goes, so a killed run still says where
-it was, and one JSON object as the last line of stdout.  Exits 0 only when
-the platform is ``tpu`` and every phase passed; without an accelerator it
-exits 2 and prints no result.  The numbers it prints are observations of one
+it was, then one ``{"summary": ...}`` line with everything observed, and as
+the last line of stdout the verdict and nothing else:
+``{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}``.
+Exits 0 only when the platform is ``tpu`` and every phase passed; without an
+accelerator it exits 2 and prints no result.  The numbers it prints are observations of one
 run, not a benchmark: nothing is claimed from them.
 
     python chip_smoke.py                 # the whole smoke, SF1
@@ -71,8 +73,9 @@ class Watchdog(threading.Thread):
     deadline.  A compile that hangs cannot be interrupted from Python, so
     the process exits from this thread."""
 
-    def __init__(self, budget_s: float):
+    def __init__(self, budget_s: float, device: dict):
         super().__init__(daemon=True, name="chip-smoke-watchdog")
+        self._device = device
         self._lock = threading.Lock()
         self._end = time.monotonic() + budget_s
         self._phase = None  # (name, limit_s, deadline)
@@ -96,8 +99,8 @@ class Watchdog(threading.Thread):
                        f"({ph[1]:.0f} s, run budget {BUDGET_S:.0f} s)")
                 print(f"chip_smoke: {msg}", file=sys.stderr, flush=True)
                 faulthandler.dump_traceback(file=sys.stderr)
-                print(json.dumps({"ok": False, "failures": [msg]}),
-                      flush=True)
+                print(json.dumps({"summary": {"failures": [msg]}}))
+                print_verdict(False, self._device)
                 os._exit(4)
 
 
@@ -107,7 +110,6 @@ class Smoke:
         self.queries = queries
         self.require_tpu = require_tpu
         self.failures = []
-        self.dog = Watchdog(BUDGET_S)
         self.cache_counts = {"requests": 0, "hits": 0, "writes": 0}
         self.compile_log = []  # (seconds, program name), every compile
         self.out = {}
@@ -156,8 +158,9 @@ class Smoke:
         import spark_rapids_tpu as srt  # enables x64 before jax is used
         import jax
         devs = jax.devices()
-        device = {"platform": devs[0].platform,
-                  "kind": devs[0].device_kind, "count": len(devs)}
+        self.device = device = {"platform": devs[0].platform,
+                                "kind": devs[0].device_kind,
+                                "count": len(devs)}
         if self.require_tpu and device["platform"] != "tpu":
             print(f"chip_smoke: platform is {device['platform']}, not tpu "
                   f"({device['count']} x {device['kind']}): no accelerator, "
@@ -175,7 +178,12 @@ class Smoke:
                 self.compile_log.append((round(duration, 2), fun_name))
         jax.monitoring.register_event_duration_secs_listener(on_duration)
 
+        self.dog = Watchdog(BUDGET_S, device)
         self.dog.start()
+
+    def session(self):
+        import jax
+        import spark_rapids_tpu as srt
         with self.phase("session", 180, fatal=True) as rec:
             # default confs, plus the planner check that no operator of a
             # smoke query is placed on the CPU
@@ -186,7 +194,7 @@ class Smoke:
             conf = self.sess._tpu_conf()
             self.cache_dir = warmstore.xla_cache_dir(conf)
             rec.update({
-                "device": device,
+                "device": self.device,
                 "versions": {
                     "python": sys.version.split()[0],
                     "jax": jax.__version__,
@@ -385,7 +393,7 @@ class Smoke:
                         d = k.split(".", 1)[1]
                         per_dev[d] = per_dev.get(d, 0) + int(v)
             err = self.tpch.rows_rel_err(got, want)
-            rec.update({"devices": self.out["device"]["count"],
+            rec.update({"devices": self.device["count"],
                         "rows": len(got), "rel_err": err,
                         "input_bytes_per_device": per_dev})
             self.out["ici"] = rec
@@ -393,7 +401,7 @@ class Smoke:
                 self.fail("ici", f"ICI rows differ from the single-chip "
                                  f"answer (rel_err {err}, {len(got)} rows "
                                  f"vs {len(want)})")
-            n = self.out["device"]["count"]
+            n = self.device["count"]
             if len(per_dev) != n or min(per_dev.values()) == 0:
                 self.fail("ici", f"inputs did not reach all {n} devices: "
                                  f"{per_dev}")
@@ -411,16 +419,19 @@ class Smoke:
             if totals[k]:
                 self.fail("run", f"{k} = {totals[k]}: a device error was "
                                  f"retried or a batch re-ran on the CPU")
-        if self.out["device"]["platform"] == "tpu" \
+        if self.device["platform"] == "tpu" \
                 and self.out.get("pipeline_depth") != 2:
             # the path the platform selects only here must have engaged
             self.fail("run", f"pipeline depth "
                              f"{self.out.get('pipeline_depth')}, not 2")
-        ok = not self.failures
-        self.out = {"ok": ok, "device": self.out.pop("device"),
-                    "failures": self.failures, **self.out, "claim": None}
-        print(json.dumps(self.out), flush=True)
-        return 0 if ok else 1
+        print(json.dumps({"summary": {"failures": self.failures, **self.out,
+                                      "claim": None}}), flush=True)
+        return 1 if self.failures else 0
+
+
+def print_verdict(ok: bool, device: dict) -> None:
+    """The last line of stdout: these keys and no others."""
+    print(json.dumps({"ok": ok, "device": device}), flush=True)
 
 
 def _version(dist: str):
@@ -437,15 +448,23 @@ def run(sf: float = 1.0, queries=tuple(QUERIES),
     line never passes it."""
     t_start = time.perf_counter()
     smoke = Smoke(sf, list(queries), require_tpu)
-    smoke.start()
-    smoke.start_stats = smoke._stats()
-    smoke.load()
-    for q in smoke.queries:
-        smoke.query(q)
-    smoke.served()
-    if smoke.out["device"]["count"] > 1:
-        smoke.ici()
-    return smoke.finish(t_start)
+    smoke.start()  # no accelerator: exits here, no result
+    code = 1
+    try:
+        smoke.session()
+        smoke.start_stats = smoke._stats()
+        smoke.load()
+        for q in smoke.queries:
+            smoke.query(q)
+        smoke.served()
+        if smoke.device["count"] > 1:
+            smoke.ici()
+        code = smoke.finish(t_start)
+    finally:
+        # a phase that raised still ends the output with a verdict; the
+        # exception goes on to end the process with its traceback
+        print_verdict(code == 0, smoke.device)
+    return code
 
 
 def main() -> int:

@@ -154,6 +154,18 @@ def test_chip_smoke_refuses_the_cpu(procs):
     assert p.out.strip() == "", "no accelerator: no result"
 
 
+def test_chip_smoke_verdict_has_exactly_the_contract_keys(capsys):
+    sys.path.insert(0, REPO)
+    try:
+        import chip_smoke
+    finally:
+        sys.path.remove(REPO)
+    device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+    chip_smoke.print_verdict(True, device)
+    last = capsys.readouterr().out.splitlines()[-1]
+    assert json.loads(last) == {"ok": True, "device": device}
+
+
 def test_dryrun_never_touches_default_backend(procs):
     p = procs["dryrun"]
     assert p.rc == 0, (
