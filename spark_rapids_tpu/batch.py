@@ -435,6 +435,7 @@ def from_arrow(table, min_capacity: int = 1024, device=None) -> ColumnBatch:
     cap = bucket_capacity(n, min_capacity, has_strings=has_strings)
     fields: List[Field] = []
     cols: List[Column] = []
+    from .utils.metrics import upload
     for name, col in zip(table.column_names, table.columns):
         if isinstance(col, pa.ChunkedArray):
             col = col.combine_chunks() if col.num_chunks != 1 else col.chunk(0)
@@ -476,9 +477,11 @@ def from_arrow(table, min_capacity: int = 1024, device=None) -> ColumnBatch:
                 np_col = np_col.astype(dt.numpy_dtype, copy=False)
             data = _pad_to(np.ascontiguousarray(np_col), cap)
             valid_np = np.asarray(col.is_valid()) if col.null_count > 0 else None
-        jdata = jax.device_put(data, device)
-        jvalid = (jax.device_put(_pad_to(valid_np, cap), device)
-                  if valid_np is not None and col.null_count > 0 else None)
+        # one counted upload a column, issued as soon as the column is
+        # ready: its transfer overlaps the next column's conversion
+        jdata, jvalid = upload(
+            (data, _pad_to(valid_np, cap) if valid_np is not None
+             and col.null_count > 0 else None), device)
         cols.append(DeviceColumn(dt, jdata, jvalid))
     return ColumnBatch(Schema(fields), cols, n)
 
@@ -587,6 +590,12 @@ def to_arrow_async(batch: ColumnBatch):
 
 
 def _to_arrow_finish(batch: ColumnBatch, host: dict):
+    from .utils import tracing
+    with tracing.span(None, "result:arrow", "result"):
+        return _host_to_arrow(batch, host)
+
+
+def _host_to_arrow(batch: ColumnBatch, host: dict):
     import pyarrow as pa
     for i, col in enumerate(batch.columns):
         if isinstance(col, DictStringColumn) and ("dc", i) in host:

@@ -562,7 +562,7 @@ class ParquetSource:
         queue and leaking the thread + decoded batches.
         """
         if self.num_threads <= 0:
-            yield from self._read_all()
+            yield from decoded(self._read_all())
             return
         q: "queue.Queue" = queue.Queue(maxsize=max(1, prefetch_depth))
         stop = threading.Event()
@@ -579,21 +579,11 @@ class ParquetSource:
 
         import contextvars
 
-        from ..utils import tracing
         cctx = contextvars.copy_context()
 
         def producer():
             try:
-                it = self._read_all()
-                while True:
-                    # each decoded table is a "decode" span on this
-                    # thread's trace lane (the host phase of the scan)
-                    with tracing.span(None, "decode", "io") as sp:
-                        t = next(it, None)
-                        if t is not None:
-                            sp.set(rows=t.num_rows)
-                    if t is None:
-                        break
+                for t in decoded(self._read_all()):
                     if not _put(t):
                         return
                 _put(_END)
@@ -608,7 +598,7 @@ class ParquetSource:
         th.start()
         try:
             while True:
-                item = q.get()
+                item = next_prefetched(q)
                 if item is _END:
                     break
                 if isinstance(item, BaseException):
@@ -616,6 +606,30 @@ class ParquetSource:
                 yield item
         finally:
             stop.set()
+
+
+def decoded(tables: Iterator) -> Iterator:
+    """Pass a reader's tables through, each a ``scan:decode`` span on the
+    thread that decodes it (the host phase of the scan) whose seconds add
+    to ``QueryStats.decode_s``."""
+    from ..utils import tracing
+    from ..utils.metrics import QueryStats
+    while True:
+        with tracing.span(None, "scan:decode", "io") as sp:
+            t = next(tables, None)
+            if t is not None:
+                sp.set(rows=t.num_rows)
+        QueryStats.get().decode_s += sp.dur
+        if t is None:
+            return
+        yield t
+
+
+def next_prefetched(q: "queue.Queue"):
+    """The scan's wait for its prefetch thread, as a ``scan:wait`` span."""
+    from ..utils import tracing
+    with tracing.span(None, "scan:wait", "io"):
+        return q.get()
 
 
 def parquet_source(path, columns: Optional[List[str]] = None,

@@ -18,7 +18,8 @@ import threading
 from typing import Iterator, List, Optional
 
 from ..batch import Field, Schema, _arrow_to_logical, logical_to_arrow
-from .parquet import Predicate, _exact_filter_mask, expand_paths
+from .parquet import (Predicate, _exact_filter_mask, decoded, expand_paths,
+                      next_prefetched)
 
 __all__ = ["FileSource", "OrcSource", "JsonSource", "CsvSource"]
 
@@ -131,7 +132,7 @@ class FileSource:
 
     def __call__(self, prefetch_depth: int = 4) -> Iterator:
         if self.num_threads <= 0 or len(self.paths) <= 1:
-            yield from self._read_all()
+            yield from decoded(self._read_all())
             return
         # prefetch next file's decode while the device consumes the
         # current; depth sized by the scan from sql.pipeline.depth
@@ -141,19 +142,11 @@ class FileSource:
 
         import contextvars
 
-        from ..utils import tracing
         cctx = contextvars.copy_context()
 
         def producer():
             try:
-                it = self._read_all()
-                while True:
-                    with tracing.span(None, "decode", "io") as sp:
-                        t = next(it, None)
-                        if t is not None:
-                            sp.set(rows=t.num_rows)
-                    if t is None:
-                        break
+                for t in decoded(self._read_all()):
                     while not stop.is_set():
                         try:
                             q.put(t, timeout=0.1)
@@ -172,7 +165,7 @@ class FileSource:
         th.start()
         try:
             while True:
-                item = q.get()
+                item = next_prefetched(q)
                 if item is _END:
                     return
                 if isinstance(item, BaseException):

@@ -125,7 +125,19 @@ def concat_batches(batches: Sequence[ColumnBatch],
 
 def gather(batch: ColumnBatch, indices: jax.Array, num_rows: int,
            sel: Optional[jax.Array] = None) -> ColumnBatch:
-    """Row-gather into a new batch (indices beyond num_rows are padding)."""
+    """Row-gather into a new batch (indices beyond num_rows are padding).
+
+    Each ``x[indices]`` is an EAGER gather, a small jitted dispatch of
+    its own per array: the ``eager:gather`` span holds them, so the time
+    the host spends inside the JAX runtime here is the account's
+    ``dispatch`` and not the calling operator's own."""
+    from ..utils import tracing
+    with tracing.span(None, "eager:gather", "program"):
+        return _gather(batch, indices, num_rows, sel)
+
+
+def _gather(batch: ColumnBatch, indices: jax.Array, num_rows: int,
+            sel: Optional[jax.Array]) -> ColumnBatch:
     cols = []
     host_idx = None
     for f, c in zip(batch.schema, batch.columns):
@@ -244,7 +256,9 @@ def _concat_fn(caps: tuple, out_cap: int, col_kind: tuple, spec: tuple,
     n_b = len(caps)
     # (spec participates only as the lru_cache trace key)
 
-    @jax.jit
+    from ..plan.physical import program
+
+    @program("batch_concat")
     def f(entries, sels, num_rows_tuple):
         actives = []
         for bi in range(n_b):
@@ -280,7 +294,9 @@ def _concat_fn(caps: tuple, out_cap: int, col_kind: tuple, spec: tuple,
 def _compact_fn(cap: int, new_cap: int, spec: tuple, has_sel: bool):
     """One jitted program compacting EVERY device column of a batch."""
 
-    @jax.jit
+    from ..plan.physical import program
+
+    @program("batch_compact")
     def f(cols, sel, num_rows):
         active = jnp.arange(cap, dtype=jnp.int32) < num_rows
         if sel is not None:
@@ -392,7 +408,9 @@ def _slice_fn(cap: int, out_cap: int, spec: tuple):
     Data pads by out_cap first so dynamic_slice never clamps the start
     (a clamped start would bleed garbage into live rows)."""
 
-    @jax.jit
+    from ..plan.physical import program
+
+    @program("batch_slice")
     def f(cols, start):
         outs = []
         for (kind, _dt, _hv, extra), dv in zip(spec, cols):

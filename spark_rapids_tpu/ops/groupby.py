@@ -134,6 +134,7 @@ def sort_indices_for_keys(keys: Sequence[Value], active: jax.Array,
     return jnp.lexsort(tuple(arrays))
 
 
+@jax.named_scope("groupby_sort")
 def group_sort_indices(keys: Sequence[Value], active: jax.Array) -> jax.Array:
     """Permutation putting EQUAL keys adjacent; order between groups is
     arbitrary.  The grouping paths (group-by, join group-id encoding)
@@ -190,6 +191,7 @@ def _segment_starts(sorted_keys: Sequence[Value], sorted_active: jax.Array) -> j
     return starts
 
 
+@jax.named_scope("segmented_reduce")
 def _reduce_segment(data: jax.Array, valid: Optional[jax.Array], op: str,
                     seg_ids: jax.Array, mask: jax.Array, num_segments: int,
                     seg_start: jax.Array, seg_last: jax.Array) -> Value:
@@ -357,18 +359,22 @@ def group_reduce(keys: List[Value], contributions: List[Tuple[Value, str]],
             val_handles.append(("batched", h, vh, orig_dtype))
 
     reduced: dict = {}
-    if sum_f64:
-        out = jax.ops.segment_sum(
-            sum_f64[0] if len(sum_f64) == 1 else
-            jnp.stack(sum_f64, axis=1), seg_ids, num_segments=capacity)
-        for i in range(len(sum_f64)):
-            reduced[("f", i)] = out if len(sum_f64) == 1 else out[:, i]
-    if sum_i64:
-        out = jax.ops.segment_sum(
-            sum_i64[0] if len(sum_i64) == 1 else
-            jnp.stack(sum_i64, axis=1), seg_ids, num_segments=capacity)
-        for i in range(len(sum_i64)):
-            reduced[("i", i)] = out if len(sum_i64) == 1 else out[:, i]
+    # named in the device trace's op metadata (tools/trace_report.py
+    # --xplane groups device seconds by it): the kernel family that
+    # fills both cells' busy time
+    with jax.named_scope("segmented_reduce"):
+        if sum_f64:
+            out = jax.ops.segment_sum(
+                sum_f64[0] if len(sum_f64) == 1 else
+                jnp.stack(sum_f64, axis=1), seg_ids, num_segments=capacity)
+            for i in range(len(sum_f64)):
+                reduced[("f", i)] = out if len(sum_f64) == 1 else out[:, i]
+        if sum_i64:
+            out = jax.ops.segment_sum(
+                sum_i64[0] if len(sum_i64) == 1 else
+                jnp.stack(sum_i64, axis=1), seg_ids, num_segments=capacity)
+            for i in range(len(sum_i64)):
+                reduced[("i", i)] = out if len(sum_i64) == 1 else out[:, i]
 
     out_keys: List[Value] = []
     for h, vh, orig_dtype in key_handles:
@@ -421,6 +427,7 @@ def ungrouped_reduce(contributions: List[Tuple[Value, str]], active: jax.Array):
     return outs
 
 
+@jax.named_scope("grid_reduce")
 def grid_group_reduce(code_keys: List[Value], dims: List[int],
                       contributions: List[Tuple[Value, str]],
                       active: jax.Array):

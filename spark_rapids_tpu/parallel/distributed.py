@@ -125,8 +125,10 @@ def plan_distributed_agg(df, mesh, axis_name: str = "data",
     spec_in = tuple(P(axis_name) for _ in range(2 * n_cols + 1))
     n_out = 2 * len(partial.group_exprs) + 2 * len(ops) + 2
     spec_out = tuple(P(axis_name) for _ in range(n_out))
-    step_fn = jax.jit(jax.shard_map(step, mesh=mesh, in_specs=spec_in,
-                                 out_specs=spec_out))
+    from ..plan.physical import program
+    step_fn = program("ici_agg_step",
+                      jax.shard_map(step, mesh=mesh, in_specs=spec_in,
+                                    out_specs=spec_out))
 
     def feed(table, rows_per_device: Optional[int] = None):
         """Shard a host table row-wise across the mesh (pad per device).

@@ -679,8 +679,10 @@ def _execute_fragment(lowered, leaves: List[_Leaf], ctx, mesh, axis: str,
     n_out_cols = len(lowered.schema)
     in_specs = tuple(feed_specs)
     out_specs = tuple(P(axis) for _ in range(2 * n_out_cols + 1)) + (P(axis),)
-    fn = jax.jit(jax.shard_map(step, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs))
+    from ..plan.physical import program
+    fn = program("ici_fragment_step",
+                 jax.shard_map(step, mesh=mesh, in_specs=in_specs,
+                               out_specs=out_specs))
     # place the inputs on the mesh here, not inside the jit call, so the
     # bytes each device received are on record: a feed that landed whole
     # on one device shows in the fragment's iciInputBytes.<device id>

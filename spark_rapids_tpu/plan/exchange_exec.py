@@ -35,7 +35,7 @@ from ..batch import ColumnBatch, DeviceColumn, Field, Schema
 from ..exprs import EvalContext, Expression
 from ..ops import batch_utils
 from ..ops.hashing import spark_partition_id
-from .physical import ExecContext, TpuExec, _cached_program
+from .physical import ExecContext, TpuExec, _cached_program, program
 
 __all__ = ["ShuffleExchangeExec"]
 
@@ -100,7 +100,7 @@ class ShuffleExchangeExec(TpuExec):
             e.fingerprint() for e in keys)
 
         def build():
-            @jax.jit
+            @program("exchange_pid")
             def f(arrays, sel, num_rows):
                 cap = next(a[0].shape[0] for a in arrays if a is not None)
                 active = jnp.arange(cap, dtype=jnp.int32) < num_rows

@@ -18,7 +18,8 @@ from .. import types as T
 from ..batch import ColumnBatch, DeviceColumn, Field, HostStringColumn, Schema
 from ..exprs import EvalContext, Expression
 from ..ops import batch_utils, groupby
-from .physical import ExecContext, TpuExec
+from ..utils.metrics import upload
+from .physical import ExecContext, TpuExec, program
 
 __all__ = ["SortExec", "LimitExec", "UnionExec", "RangeExec", "ExpandExec",
            "plan_join"]
@@ -165,7 +166,7 @@ def _range_key_fn(key_expr, desc: bool, nulls_first: bool):
     fp = f"rangekey|{key_expr.fingerprint()}|{desc}|{nulls_first}"
 
     def build():
-        @jax.jit
+        @program("sort_range_key")
         def f(arrays):
             cap = next(a[0].shape[0] for a in arrays if a is not None)
             active = jnp.ones((cap,), dtype=bool)
@@ -221,7 +222,7 @@ def _sort_perm(key_exprs, desc, nf):
     fp = "|".join(e.fingerprint() for e in key_exprs) + str(desc) + str(nf)
 
     def build():
-        @jax.jit
+        @program("sort")
         def f(arrays, num_rows):
             cap = next(a[0].shape[0] for a in arrays if a is not None)
             active = jnp.arange(cap, dtype=jnp.int32) < num_rows
@@ -316,8 +317,8 @@ class SampleExec(TpuExec):
                 u = jax.random.uniform(key, (batch.capacity,))
                 keep = u < self.fraction
                 sel = keep if batch.sel is None else (batch.sel & keep)
-                yield ColumnBatch(batch.schema, batch.columns,
-                                  batch.num_rows, sel=sel)
+            yield ColumnBatch(batch.schema, batch.columns,
+                              batch.num_rows, sel=sel)
 
 
 class CacheExec(TpuExec):
@@ -471,7 +472,7 @@ class GenerateExec(TpuExec):
         fp = f"generate-gather|{ordinal}|{dts}"
 
         def build():
-            @jax.jit
+            @program("generate_gather")
             def f(arrays, parent):
                 out = []
                 for a in arrays:
@@ -566,8 +567,7 @@ class GenerateExec(TpuExec):
                             validp[:m_rows] = elem_valid[lo:hi]
                             cols.append(DeviceColumn(
                                 elem_dt,
-                                jax.device_put(data, ctx.device),
-                                jax.device_put(validp, ctx.device)))
+                                *upload((data, validp), ctx.device)))
                         elif gathered[i] is None:
                             taken = b.columns[i].array.slice(0, n).take(
                                 pa.array(parent_pad))
@@ -607,7 +607,7 @@ class ExpandExec(TpuExec):
         def proj_fn(pi: int):
             triples = self.projections[pi]
 
-            @jax.jit
+            @program("expand_project")
             def f(arrays, sel, num_rows):
                 cap = next(a[0].shape[0] for a in arrays if a is not None)
                 active = jnp.arange(cap, dtype=jnp.int32) < num_rows
@@ -635,7 +635,7 @@ class ExpandExec(TpuExec):
                             cols.append(batch.columns[host_src])
                         else:
                             cols.append(DeviceColumn(f_.dtype, val[0], val[1]))
-                    yield ColumnBatch(self._schema, cols, batch.num_rows, active)
+                yield ColumnBatch(self._schema, cols, batch.num_rows, active)
 
 
 def plan_join(plan, left: TpuExec, right: TpuExec, conf):

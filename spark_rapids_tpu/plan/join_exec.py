@@ -37,7 +37,7 @@ from ..ops import batch_utils
 from ..ops.groupby import group_sort_indices, _segment_starts
 from ..utils.metrics import current_region, fetch, region_scalars, \
     stage_scalars
-from .physical import ExecContext, TpuExec, _cached_program
+from .physical import ExecContext, TpuExec, _cached_program, program
 
 __all__ = ["SortMergeJoinExec"]
 
@@ -193,7 +193,7 @@ class SortMergeJoinExec(TpuExec):
         fp = self._fingerprint() + "|smjfilter"
 
         def build_stats():
-            @jax.jit
+            @program("smj_filter_stats")
             def f(b_arrays, n_build):
                 b_cap = next(a[0].shape[0] for a in b_arrays
                              if a is not None)
@@ -221,7 +221,7 @@ class SortMergeJoinExec(TpuExec):
 
         def values_fn():
             def build_vals():
-                @jax.jit
+                @program("smj_filter_vals")
                 def g(b_arrays, n_build):
                     b_cap = next(a[0].shape[0] for a in b_arrays
                                  if a is not None)
@@ -377,7 +377,7 @@ class SortMergeJoinExec(TpuExec):
                   + "|".join(e.fingerprint() for e in keys))
 
             def build():
-                @jax.jit
+                @program("join_subpid")
                 def f(arrays, sel, num_rows):
                     cap = next(a[0].shape[0] for a in arrays
                                if a is not None)
@@ -469,7 +469,7 @@ class SortMergeJoinExec(TpuExec):
         fp = self._fingerprint() + "|condexpand"
 
         def build_fn():
-            @jax.jit
+            @program("join_cond_expand")
             def f(offsets, counts, lo, matches, b_perm, out_cap_arr):
                 out_cap_ = out_cap_arr.shape[0]
                 pi_c = _expand_rows(offsets, counts, out_cap_)
@@ -498,7 +498,7 @@ class SortMergeJoinExec(TpuExec):
         cond = bind(self.condition, combined)
 
         def build_cond():
-            @jax.jit
+            @program("join_cond")
             def g(arrays, sel, pi, bi, p_cap_arr, b_cap_arr):
                 cap = next(a[0].shape[0] for a in arrays if a is not None)
                 act = sel
@@ -580,7 +580,7 @@ class SortMergeJoinExec(TpuExec):
         cond = bind(self.condition, batch.schema)
 
         def build():
-            @jax.jit
+            @program("join_residual")
             def f(arrays, sel, num_rows):
                 cap = next(a[0].shape[0] for a in arrays if a is not None)
                 active = jnp.arange(cap, dtype=jnp.int32) < num_rows
@@ -631,7 +631,7 @@ class SortMergeJoinExec(TpuExec):
         fp = self._fingerprint() + f"|ps{probe_side}"
 
         def build_fn():
-            @jax.jit
+            @program("join_match")
             def f(p_arrays, b_arrays, n_probe, n_build):
                 p_cap = next(a[0].shape[0] for a in p_arrays if a is not None)
                 b_cap = next(a[0].shape[0] for a in b_arrays if a is not None)
@@ -721,7 +721,7 @@ class SortMergeJoinExec(TpuExec):
         fp = self._fingerprint() + f"|expand{probe_side}"
 
         def build_fn():
-            @jax.jit
+            @program("join_expand")
             def f(offsets, counts, lo, matches, b_perm, out_cap_arr):
                 out_cap_ = out_cap_arr.shape[0]
                 pi_c = _expand_rows(offsets, counts, out_cap_)
@@ -752,7 +752,7 @@ class SortMergeJoinExec(TpuExec):
         fp = self._fingerprint() + "|unmatched"
 
         def build_fn():
-            @jax.jit
+            @program("join_unmatched")
             def f(lo, matches, b_perm, n_build):
                 b_cap = b_perm.shape[0]
                 hit_sorted = jnp.zeros((b_cap,), dtype=jnp.int32)
@@ -972,7 +972,7 @@ class BroadcastJoinExec(SortMergeJoinExec):
         fp = self._fingerprint() + f"|bfast{probe_side}"
 
         def build_sort():
-            @jax.jit
+            @program("bjoin_sort")
             def f(b_arrays, n_build):
                 b_cap = next(a[0].shape[0] for a in b_arrays
                              if a is not None)
@@ -1011,7 +1011,7 @@ class BroadcastJoinExec(SortMergeJoinExec):
         _, _, sorted_keys, b_perm, n_valid = cache
 
         def build_probe():
-            @jax.jit
+            @program("bjoin_probe")
             def g(p_arrays, sorted_keys, n_valid, n_probe):
                 p_cap = next(a[0].shape[0] for a in p_arrays
                              if a is not None)
@@ -1068,7 +1068,7 @@ class BroadcastJoinExec(SortMergeJoinExec):
         fp = self._fingerprint() + f"|csr{probe_side}|{D}"
 
         def build_csr():
-            @jax.jit
+            @program("bjoin_csr")
             def f(b_arrays, sel, kmin_s, n_build):
                 b_cap = next(a[0].shape[0] for a in b_arrays
                              if a is not None)
@@ -1102,7 +1102,7 @@ class BroadcastJoinExec(SortMergeJoinExec):
         _, _, counts, starts, b_perm = cache
 
         def build_probe():
-            @jax.jit
+            @program("bjoin_csr_probe")
             def g(p_arrays, counts, starts, kmin_s, n_probe):
                 p_cap = next(a[0].shape[0] for a in p_arrays
                              if a is not None)
@@ -1263,7 +1263,7 @@ class BroadcastJoinExec(SortMergeJoinExec):
                 return
 
         def build_stats():
-            @jax.jit
+            @program("bjoin_dense_stats")
             def f(b_arrays, sel, n_build):
                 b_cap = next(a[0].shape[0] for a in b_arrays
                              if a is not None)
@@ -1366,7 +1366,7 @@ class BroadcastJoinExec(SortMergeJoinExec):
         D = bucket_capacity(domain)
 
         def build_table():
-            @jax.jit
+            @program("bjoin_dense_table")
             def g(b_arrays, sel, kmin_s, n_build):
                 b_cap = next(a[0].shape[0] for a in b_arrays
                              if a is not None)
@@ -1422,7 +1422,7 @@ class BroadcastJoinExec(SortMergeJoinExec):
               + f"sel{int(has_sel)}")
 
         def build_probe():
-            @jax.jit
+            @program("bjoin_dense_probe")
             def h(p_arrays, table, payload, kmin_s, n_probe, sel):
                 p_cap = next(a[0].shape[0] for a in p_arrays
                              if a is not None)
@@ -1646,6 +1646,7 @@ class BroadcastJoinExec(SortMergeJoinExec):
             self._exec_ctx = None
 
 
+@jax.named_scope("join_expand_rows")
 def _expand_rows(offsets, counts, out_cap: int):
     """Output-slot -> probe-row map for count expansion, WITHOUT the
     searchsorted-over-output pass (measured ~35x slower than a gather on

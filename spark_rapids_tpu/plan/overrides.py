@@ -481,6 +481,24 @@ def _convert(meta: NodeMeta, conf: TpuConf) -> TpuExec:
 def apply_overrides(plan: L.LogicalPlan, conf: Optional[TpuConf] = None
                     ) -> TpuExec:
     conf = conf or TpuConf()
+    from ..utils import tracing
+    with tracing.span(None, "plan:overrides", "plan"):
+        phys, on_tpu = _place(plan, conf)
+    if not on_tpu:
+        return phys
+    from .coalesce import insert_coalesce
+    from .fusion import plan_regions
+    # region fusion runs LAST: it groups the final operator chains (incl.
+    # the coalesce nodes insert_coalesce just placed) into fused regions.
+    # Identity under sql.fusion.enabled=false — the per-op escape hatch.
+    with tracing.span(None, "plan:fusion", "plan"):
+        return plan_regions(insert_coalesce(phys, conf), conf)
+
+
+def _place(plan: L.LogicalPlan, conf: TpuConf):
+    """Pushdown, tagging, CBO and conversion to exec nodes: (tree,
+    whether it was placed on the TPU; False for the all-CPU tree of
+    explainonly / sql.enabled=false)."""
     from .optimizer import push_filters
     from .pushdown import optimize_scans
     plan = push_filters(plan)
@@ -510,13 +528,8 @@ def apply_overrides(plan: L.LogicalPlan, conf: Optional[TpuConf] = None
                 return RangeExec(p.start, p.end, p.step,
                                  conf["spark.rapids.tpu.sql.batchSizeRows"])
             return CpuOpExec(p, [all_cpu(c) for c in m.children])
-        return all_cpu(meta)
-    from .coalesce import insert_coalesce
-    from .fusion import plan_regions
-    # region fusion runs LAST: it groups the final operator chains (incl.
-    # the coalesce nodes insert_coalesce just placed) into fused regions.
-    # Identity under sql.fusion.enabled=false — the per-op escape hatch.
-    return plan_regions(insert_coalesce(_convert(meta, conf), conf), conf)
+        return all_cpu(meta), False
+    return _convert(meta, conf), True
 
 
 def explain_plan(plan: L.LogicalPlan, conf: Optional[TpuConf] = None) -> str:

@@ -15,6 +15,7 @@ chains do.
 
 from __future__ import annotations
 
+import functools
 import threading
 from typing import Callable, Dict, Iterator, List, Optional, Sequence, Tuple
 
@@ -28,8 +29,9 @@ from ..config import TpuConf
 from ..exprs import (AggregateExpression, Alias, BoundReference, EvalContext,
                      Expression)
 from ..ops import batch_utils, groupby
+from ..utils import tracing
 from ..utils.metrics import MetricSet, fetch, fetch_scalars, prestage, \
-    region_fetch, region_scalars
+    region_fetch, region_scalars, upload
 
 __all__ = ["ExecContext", "TpuExec", "ScanExec", "StageExec", "AggregateExec",
            "CollectExec"]
@@ -350,11 +352,76 @@ def _cached_program(fp: str, build: Callable[[], Callable]) -> Callable:
         return fn
 
 
-def install_program(fp: str, fn: Callable) -> None:
+# ---------------------------------------------------------------------------------
+# Program names.  Every function jitted under plan/, ops/batch_utils.py,
+# runtime/warmstore.py and parallel/ goes through :func:`program`, under
+# a name from this fixed vocabulary (the kind that heads its cache key,
+# where it has one).  The name is the jitted function's ``__name__``, so
+# it is the ``fun_name`` of jax's compile events (QueryStats' listener,
+# recorder.compile_note, the benchmark's ``slowest_compiles``), the
+# ``jit_<name>`` module on a device trace's ``XLA Modules`` line, and the
+# ``program:<name>`` span around every call.  Never a fingerprint or a
+# literal: those belong to the cache key.
+# ---------------------------------------------------------------------------------
+
+PROGRAM_NAMES = frozenset((
+    "stage", "stage_donate", "window",
+    "agg_ungrouped", "agg_ungrouped_fused", "agg_ungrouped_merge",
+    "agg_ungrouped_finalize", "agg_dense_stats", "agg_dense_update",
+    "agg_mdense_stats", "agg_mdense_update", "agg_mdense_violation",
+    "agg_grouped", "agg_grid", "agg_passthrough", "agg_sample",
+    "agg_bucket_pid", "agg_finalize", "agg_merge_grouped",
+    "sort", "sort_range_key", "generate_gather", "expand_project",
+    "exchange_pid", "batch_concat", "batch_compact", "batch_slice",
+    "smj_filter_stats", "smj_filter_vals", "join_subpid",
+    "join_cond_expand", "join_cond", "join_residual", "join_match",
+    "join_expand", "join_unmatched", "bjoin_sort", "bjoin_probe",
+    "bjoin_csr", "bjoin_csr_probe", "bjoin_dense_stats",
+    "bjoin_dense_table", "bjoin_dense_probe",
+    "ici_fragment_step", "ici_agg_step",
+))
+
+
+class _Program:
+    """A named program: every call is a ``program:<name>`` span (the
+    account's ``dispatch``: the driving thread inside the JAX runtime).
+    Everything else (``lower``, ...) is the wrapped callable's."""
+
+    __slots__ = ("name", "call", "_span_name")
+
+    def __init__(self, name: str, call: Callable):
+        if name not in PROGRAM_NAMES:
+            raise ValueError(f"program name {name!r} is not in "
+                             f"plan/physical.PROGRAM_NAMES")
+        self.name = name
+        self.call = call
+        self._span_name = "program:" + name
+
+    def __call__(self, *args, **kwargs):
+        with tracing.span(None, self._span_name, "program"):
+            return self.call(*args, **kwargs)
+
+    def __getattr__(self, attr):
+        return getattr(self.call, attr)
+
+
+def program(name: str, fn: Optional[Callable] = None, **jit_kwargs):
+    """``jax.jit(fn, **jit_kwargs)`` under ``name`` (one of
+    :data:`PROGRAM_NAMES`); without ``fn``, the decorator form."""
+    if fn is None:
+        return functools.partial(program, name, **jit_kwargs)
+    fn.__name__ = fn.__qualname__ = name
+    return _Program(name, jax.jit(fn, **jit_kwargs))
+
+
+def install_program(fp: str, name: str, fn: Callable) -> None:
     """Pre-install a program under a cache key (the warm-start prewarm
     lane's entry point: an AOT-compiled executable takes the slot the
-    live path would otherwise fill with a cold jit).  First-writer
-    wins — a live query that already compiled keeps its program."""
+    live path would otherwise fill with a cold jit), wrapped as the
+    live one is: ``name`` is its :data:`PROGRAM_NAMES` entry.
+    First-writer wins — a live query that already compiled keeps its
+    program."""
+    fn = _Program(name, fn)
     with _STAGE_CACHE_LOCK:
         if fp in _STAGE_CACHE:
             return
@@ -482,7 +549,7 @@ class StageExec(TpuExec):
         fp = self.fingerprint() + ("|ansi" if ansi else "")
         fn = _cached_program(
             "stage|" + fp,
-            lambda: jax.jit(self._build_fn(in_schema, ansi=ansi)))
+            lambda: program("stage", self._build_fn(in_schema, ansi=ansi)))
         # donation variant: single-consumer input batches hand their HBM
         # to XLA (output reuses input buffers → steady-state churn drops).
         # A separate cached executable — the donating and non-donating
@@ -495,7 +562,8 @@ class StageExec(TpuExec):
                 and donation_supported():
             fn_donate = _cached_program(
                 "stage-donate|" + fp,
-                lambda: jax.jit(self._build_fn(in_schema, ansi=ansi),
+                lambda: program("stage_donate",
+                                self._build_fn(in_schema, ansi=ansi),
                                 donate_argnums=(0, 1, 2)))
 
         # figure out host pass-through columns for the final projection
@@ -865,7 +933,7 @@ class AggregateExec(TpuExec):
             stage_fn = fused_stage._build_fn(child.output_schema)
 
             def build():
-                @jax.jit
+                @program("agg_ungrouped_fused")
                 def batch_partials(arrays, sel, num_rows):
                     out_arrays, active = stage_fn(arrays, (), sel, num_rows)
                     cap = next(a[0].shape[0] for a in arrays
@@ -880,7 +948,7 @@ class AggregateExec(TpuExec):
                   + "|" + self._fingerprint())
         else:
             def build():
-                @jax.jit
+                @program("agg_ungrouped")
                 def batch_partials(arrays, sel, num_rows):
                     cap = next(a[0].shape[0] for a in arrays
                                if a is not None)
@@ -909,7 +977,8 @@ class AggregateExec(TpuExec):
         # compute
         merge_fn = _cached_program(
             "agg-merge|" + self._fingerprint(),
-            lambda: jax.jit(lambda a, b: slf._merge_scalars(a, b, ops)))
+            lambda: program("agg_ungrouped_merge",
+                            lambda a, b: slf._merge_scalars(a, b, ops)))
 
         from ..runtime.pipeline import effective_depth, pipeline_batches
         acc: Optional[List] = None
@@ -1015,7 +1084,7 @@ class AggregateExec(TpuExec):
 
         fin = _cached_program(
             f"agg-fin|{self.mode}|" + self._fingerprint(),
-            lambda: jax.jit(_fin))
+            lambda: program("agg_ungrouped_finalize", _fin))
         res = fin(tuple(acc))
 
         cols: List = []
@@ -1091,7 +1160,7 @@ class AggregateExec(TpuExec):
         fp = "agg-dense|" + self._fingerprint()
 
         def build_stats():
-            @jax.jit
+            @program("agg_dense_stats")
             def f(arrays, sel, num_rows):
                 cap = next(a[0].shape[0] for a in arrays
                            if a is not None)
@@ -1145,7 +1214,7 @@ class AggregateExec(TpuExec):
             return accs
 
         def build_update():
-            @jax.jit
+            @program("agg_dense_update")
             def f(arrays, sel, num_rows, accs, present, kmin_s):
                 cap = next(a[0].shape[0] for a in arrays
                            if a is not None)
@@ -1336,7 +1405,7 @@ class AggregateExec(TpuExec):
         def build_stats():
             from ..ops.hashing import xxhash64_columns
 
-            @jax.jit
+            @program("agg_mdense_stats")
             def f(arrays, sel, num_rows):
                 cap = next(a[0].shape[0] for a in arrays
                            if a is not None)
@@ -1463,7 +1532,7 @@ class AggregateExec(TpuExec):
             return res
 
         def build_update():
-            @jax.jit
+            @program("agg_mdense_update")
             def f(arrays, sel, num_rows, accs, res, present, kmin_s):
                 cap = next(a[0].shape[0] for a in arrays
                            if a is not None)
@@ -1525,7 +1594,7 @@ class AggregateExec(TpuExec):
         ufn = _cached_program(fp + f"|update|{pidx}|{D}", build_update)
 
         def build_violation():
-            @jax.jit
+            @program("agg_mdense_violation")
             def f(res, present):
                 viol = jnp.zeros((), dtype=bool)
                 for (vmin, vmax, dmn, dmx) in res:
@@ -1709,7 +1778,7 @@ class AggregateExec(TpuExec):
             key_eval = slf._key_contributions
 
         def build():
-            @jax.jit
+            @program("agg_grouped")
             def batch_group(arrays, sel, num_rows):
                 cap = next(a[0].shape[0] for a in arrays
                            if a is not None)
@@ -1767,7 +1836,7 @@ class AggregateExec(TpuExec):
 
         def _grid_program(dims):
             def build_grid():
-                @jax.jit
+                @program("agg_grid")
                 def f(arrays, sel, num_rows):
                     cap = next(a[0].shape[0] for a in arrays
                                if a is not None)
@@ -1881,7 +1950,7 @@ class AggregateExec(TpuExec):
         first = True
 
         def build_pt():
-            @jax.jit
+            @program("agg_passthrough")
             def f(arrays, sel, num_rows):
                 cap = next(a[0].shape[0] for a in arrays if a is not None)
                 active = jnp.arange(cap, dtype=jnp.int32) < num_rows
@@ -2014,7 +2083,7 @@ class AggregateExec(TpuExec):
         scap = min(bucket_capacity(srows), batch.capacity)
 
         def build():
-            @jax.jit
+            @program("agg_sample")
             def f(arrays, sel, num_rows):
                 cap = next(a[0].shape[0] for a in arrays if a is not None)
                 active = jnp.arange(cap, dtype=jnp.int32) < num_rows
@@ -2107,9 +2176,7 @@ class AggregateExec(TpuExec):
                     d = StringDictionary()
                     self.string_dicts[gi] = d
                 codes, valid = d.encode(col.array)
-                jcodes = jax.device_put(codes, ctx.device)
-                jvalid = (jax.device_put(valid, ctx.device)
-                          if valid is not None else None)
+                jcodes, jvalid = upload((codes, valid), ctx.device)
                 col._enc_cache = (d, jcodes, jvalid)
             cols[ordn] = DeviceColumn(T.STRING, jcodes, jvalid)
             changed = True
@@ -2172,7 +2239,7 @@ class AggregateExec(TpuExec):
         fp = f"agg-bucket-pid|{n_keys}|{n_buckets}|" + self._fingerprint()
 
         def build():
-            @jax.jit
+            @program("agg_bucket_pid")
             def f(arrays, sel, num_rows):
                 from ..ops.hashing import xxhash64_columns
                 cap = next(a[0].shape[0] for a in arrays
@@ -2229,7 +2296,7 @@ class AggregateExec(TpuExec):
         agg_exprs = self.agg_exprs  # don't capture self in the cached fn
 
         def build():
-            @jax.jit
+            @program("agg_finalize")
             def fin(arrays):
                 outs = []
                 i = n_keys
@@ -2288,14 +2355,11 @@ class AggregateExec(TpuExec):
         return cols
 
 
-import functools
-
-
 @functools.lru_cache(maxsize=256)
 def _merge_fn(ops: tuple, n_keys: int):
     """Cached jitted merge for the concat-merge aggregation loop."""
 
-    @jax.jit
+    @program("agg_merge_grouped")
     def merge(arrays, sel, num_rows):
         cap = next(a[0].shape[0] for a in arrays
                    if a is not None)
@@ -2351,7 +2415,8 @@ class CollectExec(TpuExec):
             tables.extend(f() for f in pending)
         if not tables:
             return None
-        return pa.concat_tables(tables)
+        with tracing.span(None, "result:concat", "result"):
+            return pa.concat_tables(tables)
 
     def execute(self, ctx: ExecContext) -> Iterator[ColumnBatch]:
         yield from self.children[0].execute(ctx)

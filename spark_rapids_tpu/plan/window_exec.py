@@ -24,7 +24,7 @@ from ..exprs import EvalContext
 from ..ops import batch_utils
 from ..ops.window import SortedWindowContext
 from ..windowfns import WindowExpression
-from .physical import ExecContext, TpuExec, _cached_program
+from .physical import ExecContext, TpuExec, _cached_program, program
 
 __all__ = ["WindowExec"]
 
@@ -85,7 +85,7 @@ class WindowExec(TpuExec):
             if len(batches) > 1 else batch_utils.compact(batches[0])
         with m.time("opTime"):
             fn = _cached_program("window|" + self._fingerprint(),
-                                 lambda: jax.jit(self._build_fn()))
+                                 lambda: program("window", self._build_fn()))
 
             def run(b: ColumnBatch):
                 arrays = tuple(

@@ -27,7 +27,6 @@ from __future__ import annotations
 
 import queue
 import threading
-import time
 from typing import Callable, Iterable, Iterator, TypeVar
 
 __all__ = ["pipeline_map", "pipeline_batches", "effective_depth",
@@ -181,16 +180,15 @@ def pipeline_map(src: Iterable[T], fn: Callable[[T], U],
                 # items are ever live (queue + the one being produced)
                 if not slots.acquire(ctl):  # srtlint: ignore[release-paths] (cross-thread gate: the consumer loop releases per item and its finally stop()s the gate, freeing any held slot)
                     return  # stopped or cancelled
-                t0 = time.perf_counter()
+                sp = tracing.span(label, "pipeline:stage", "pipeline")
                 try:
-                    item = next(it)
+                    with sp:
+                        item = next(it)
+                        out = fn(item)
                 except StopIteration:
                     q.put(_END)
                     return
-                out = fn(item)
-                dt = time.perf_counter() - t0
-                QueryStats.get().pipeline_stage_s += dt
-                tracing.record(label, "pipeline:stage", "pipeline", t0, dt)
+                QueryStats.get().pipeline_stage_s += sp.dur
                 q.put(out)
         except BaseException as e:  # surfaced on the consumer side
             q.put(e)
@@ -213,11 +211,9 @@ def pipeline_map(src: Iterable[T], fn: Callable[[T], U],
                 # comes back for more: staged batches + the one in the
                 # consumer's hands never exceed `depth` (strict HBM bound)
                 slots.release()
-            t0 = time.perf_counter()
-            item = q.get()
-            dt = time.perf_counter() - t0
-            QueryStats.get().h2d_wait_s += dt
-            tracing.record(label, "pipeline:wait", "pipeline", t0, dt)
+            with tracing.span(label, "pipeline:wait", "pipeline") as sp:
+                item = q.get()
+            QueryStats.get().h2d_wait_s += sp.dur
             if item is _END:
                 return
             if item is _CANCELLED:

@@ -6,7 +6,7 @@ concurrency level), with wait time surfaced in task metrics.  The TPU
 analog: there are no CUDA streams to oversubscribe, but concurrent Python
 threads submitting XLA programs still contend for HBM; the semaphore bounds
 them and records the wait in :class:`..utils.metrics.TaskMetrics` and —
-when a query trace is active — as a ``semaphore:wait`` span.
+when a query trace is active — as an ``admit:semaphore`` span.
 
 Service-era requirements (service/scheduler.py):
 
@@ -26,7 +26,6 @@ from __future__ import annotations
 
 import contextlib
 import threading
-import time
 
 __all__ = ["TpuSemaphore", "get_semaphore"]
 
@@ -98,9 +97,9 @@ class TpuSemaphore:
             # wake this wait the instant the query is cancelled (or its
             # deadline timer fires) — event-driven, not polled
             tok = ctl.add_waker(self._notify)
-        t0 = time.perf_counter()
+        sp = tracing.span(None, "admit:semaphore", "scheduler")
         try:
-            with self._cv:
+            with sp, self._cv:
                 while self._in_use >= self._permits:
                     if ctl is not None:
                         ctl.check()
@@ -111,9 +110,7 @@ class TpuSemaphore:
         finally:
             if tok is not None:
                 ctl.remove_waker(tok)
-            dt = time.perf_counter() - t0
-            TaskMetrics.get().semaphore_wait_s += dt
-            tracing.record(None, "semaphore:wait", "scheduler", t0, dt)
+            TaskMetrics.get().semaphore_wait_s += sp.dur
         try:
             yield
         finally:

@@ -672,12 +672,15 @@ def _aot_compile(stage, in_schema, sig: dict):
     sel = sds(sig["sel"]) if sig.get("sel") else None
     nr = jax.ShapeDtypeStruct((), np.dtype("int32"))
     build = stage._build_fn(in_schema, ansi=bool(sig.get("ansi")))
+    from ..plan.physical import program
     if sig.get("donate"):
-        jitted = jax.jit(build, donate_argnums=(0, 1, 2))
+        jitted = program("stage_donate", build, donate_argnums=(0, 1, 2))
     else:
-        jitted = jax.jit(build)
+        jitted = program("stage", build)
     compiled = jitted.lower(arrays, extras, sel, nr).compile()
-    return _AotProgram(compiled, jitted)
+    # install_program wraps the pair in the program's span: the
+    # fallback is the bare jit, not to span it twice
+    return _AotProgram(compiled, jitted.call)
 
 
 def _walk_stages(node):
@@ -698,14 +701,15 @@ def _prewarm_entry(session, prepared, tables, conf, ent: dict) -> int:
     compiled = 0
     for stage in _walk_stages(stmt.phys):
         fp = stage.fingerprint() + ("|ansi" if ansi else "")
-        for prefix in ("stage|", "stage-donate|"):
+        for prefix, name in (("stage|", "stage"),
+                             ("stage-donate|", "stage_donate")):
             key = prefix + fp
             rec = programs.get(key)
             if rec is None or physical.has_program(key):
                 continue
             fn = _aot_compile(stage, stage.children[0].output_schema,
                               rec["sig"])
-            physical.install_program(key, fn)
+            physical.install_program(key, name, fn)
             compiled += 1
     return compiled
 

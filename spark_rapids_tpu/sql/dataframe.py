@@ -362,13 +362,14 @@ class DataFrame:
             from ..service import cancel
             with cancel.scope(cancel.QueryControl(label="collect",
                                                   deadline_s=timeout)):
-                t = self._executed()
-        else:
-            t = self._executed()
-        if t is None:
-            return []
-        cols = [t.column(i).to_pylist() for i in range(t.num_columns)]
-        return [tuple(c[i] for c in cols) for i in range(t.num_rows)]
+                return self._collected()
+        return self._collected()
+
+    def _collected(self) -> List[tuple]:
+        # the query's scope opens HERE, so its wall is the caller's:
+        # planning, execution and the rows below
+        with self.session._query_scope():
+            return self.session._rows(self._executed())
 
     def submit(self, **kw):
         """Async execution through the session's query scheduler:

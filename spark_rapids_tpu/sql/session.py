@@ -9,6 +9,7 @@ machinery mirrors the plugin's "could not run on TPU because ..." output
 from __future__ import annotations
 
 import contextlib
+import contextvars
 import threading
 from typing import Any, Dict, Iterable, Optional
 
@@ -18,6 +19,23 @@ from ..plan.physical import CollectExec, ExecContext
 from .dataframe import DataFrame
 
 __all__ = ["Session"]
+
+
+class _QueryScope:
+    """What one query's scope holds for whoever runs inside it."""
+
+    __slots__ = ("conf", "stats", "trace", "ctxs", "tid")
+
+    def __init__(self, conf, stats, trace):
+        self.conf = conf
+        self.stats = stats
+        self.trace = trace
+        self.ctxs = []  # ExecContexts of the executions the scope ran
+        self.tid = threading.get_ident()
+
+
+_SCOPE: "contextvars.ContextVar[Optional[_QueryScope]]" = \
+    contextvars.ContextVar("srt_query_scope", default=None)
 
 
 class _RuntimeConf:
@@ -305,21 +323,39 @@ class Session:
         if ctx.conf["spark.rapids.tpu.shuffle.mode"] != "ICI":
             return phys
         from ..parallel.spmd import distribute_plan
-        return distribute_plan(phys, ctx, self.ici_mesh())
+        from ..utils import tracing
+        with tracing.span(None, "plan:distribute", "plan"):
+            return distribute_plan(phys, ctx, self.ici_mesh())
+
+    def _resolve_subqueries(self, plan: L.LogicalPlan):
+        """Subqueries rewritten to their values.  Each one EXECUTES
+        inside the ``plan:subqueries`` span and inside this query's
+        scope: its spans are charged to their own terms of this query's
+        account, and what is left is the rewrite's own work."""
+        from ..plan.subquery import resolve_subqueries
+        from ..utils import tracing
+        with tracing.span(None, "plan:subqueries", "plan"):
+            return resolve_subqueries(plan, self._collect_rows)
+
+    @staticmethod
+    def _rows(t) -> list:
+        """An arrow table as python rows (``result:rows``)."""
+        if t is None:
+            return []
+        from ..utils import tracing
+        with tracing.span(None, "result:rows", "result"):
+            cols = [t.column(i).to_pylist() for i in range(t.num_columns)]
+            return [tuple(c[i] for c in cols) for i in range(t.num_rows)]
 
     def _collect_rows(self, plan: L.LogicalPlan):
         """Execute a (sub)plan to host rows — the subquery resolver's
         executor (plans passed here are already subquery-free)."""
-        t = self._execute_resolved(plan)
-        if t is None:
-            return []
-        cols = [t.column(i).to_pylist() for i in range(t.num_columns)]
-        return [tuple(c[i] for c in cols) for i in range(t.num_rows)]
+        return self._rows(self._execute_resolved(plan))
 
     def _execute(self, plan: L.LogicalPlan):
-        from ..plan.subquery import resolve_subqueries
-        plan = resolve_subqueries(plan, self._collect_rows)
-        return self._execute_resolved(plan)
+        with self._query_scope():
+            plan = self._resolve_subqueries(plan)
+            return self._execute_resolved(plan)
 
     # -- query service ------------------------------------------------------------
     def scheduler(self):
@@ -403,6 +439,45 @@ class Session:
                      or conf["spark.rapids.tpu.recorder.enabled"]),
             max_events=conf["spark.rapids.tpu.sql.trace.maxEvents"])
 
+    @contextlib.contextmanager
+    def _query_scope(self):
+        """The per-query scope stack: query-scoped QueryStats, the
+        fault budget, the cancellation control, the QueryTrace and the
+        host-time account (utils/tracing.account), whose driving thread
+        is this one.  Opened ONCE per query, at the entry point
+        (``DataFrame.collect`` / ``_executed``, or an ``_execute*`` path
+        called directly), so planning and result materialisation are
+        inside the query's wall; whatever opens it again on the same
+        thread (the ``_execute*`` path under the entry point, a scalar
+        subquery's ``_collect_rows``) joins the scope that is open."""
+        from ..utils import tracing
+        from ..utils.metrics import QueryStats
+        outer = _SCOPE.get()
+        if outer is not None and outer.tid == threading.get_ident():
+            yield outer
+            return
+        conf = self._tpu_conf()
+        with QueryStats.scoped() as stats, self._fault_scope(conf), \
+                self._control_scope(conf), self._trace_scope(conf) as tr:
+            qs = _QueryScope(conf, stats, tr)
+            tok = _SCOPE.set(qs)
+            self._note_scheduler(tr)
+            try:
+                with tracing.account(stats):
+                    yield qs
+            except BaseException as e:
+                self._trace_status(tr, e)
+                raise
+            finally:
+                try:
+                    _SCOPE.reset(tok)
+                except ValueError:  # generator-held scope, closed out of order
+                    _SCOPE.set(None)
+                # the trace finishes (and auto-dumps) even for an
+                # aborted query, carrying its cancelled/deadline status;
+                # the account has closed into ``stats`` by now
+                self._finish_trace(qs)
+
     def _note_scheduler(self, tr) -> None:
         """Fold the scheduler's per-query accounting into the trace:
         a ``scheduler:queue_wait`` span (rendered at the head of the
@@ -467,7 +542,8 @@ class Session:
         else:
             tr.set_status("error")
 
-    def _finish_trace(self, tr, ctx, stats) -> None:
+    def _finish_trace(self, qs: _QueryScope) -> None:
+        tr, stats, conf = qs.trace, qs.stats, qs.conf
         if tr is None:
             return
         if tr.status == "ok" and stats.degraded_batches:
@@ -476,9 +552,11 @@ class Session:
             # accurate trace says so (the degraded:cpu marks carry the
             # per-operator detail)
             tr.set_status("degraded")
-        tr.finish(metrics=ctx.metrics, stats=stats.snapshot())
+        metrics = {}
+        for ctx in qs.ctxs:
+            metrics.update(ctx.metrics)
+        tr.finish(metrics=metrics, stats=stats.snapshot())
         self._last_trace = tr
-        conf = ctx.conf
         trace_dir = conf["spark.rapids.tpu.sql.trace.dir"]
         if trace_dir and conf["spark.rapids.tpu.sql.trace.enabled"]:
             # the every-query dump stays opt-in via sql.trace.enabled;
@@ -521,66 +599,41 @@ class Session:
         Concatenates sel-masked batches BEFORE compacting: one host sync
         total instead of one per batch."""
         from ..ops import batch_utils
-        from ..plan.physical import ExecContext
-        from ..plan.subquery import resolve_subqueries
         from ..runtime.semaphore import get_semaphore
-        from ..utils.metrics import QueryStats
-        plan = resolve_subqueries(plan, self._collect_rows)
-        conf = self._tpu_conf()
-        phys = self._plan_physical(plan)
-        ctx = ExecContext(conf, device=self.device)
-        with QueryStats.scoped() as stats, self._fault_scope(conf), \
-                self._control_scope(conf), self._trace_scope(conf) as tr:
-            try:
-                with get_semaphore(conf).acquire():
-                    phys = self._distribute_if_ici(phys, ctx)
-                    if tr is not None:
-                        tr.register_plan(phys)
-                    self._note_scheduler(tr)
-                    batches = [b for b in phys.execute(ctx)
-                               if b.num_rows > 0]
-                    if not batches:
-                        out = None
-                    else:
-                        whole = batches[0] if len(batches) == 1 else \
-                            batch_utils.concat_batches(batches)
-                        out = batch_utils.compact(whole)
-            except BaseException as e:
-                self._trace_status(tr, e)
-                raise
-            finally:
-                # the trace finishes (and auto-dumps) even for an
-                # aborted query, carrying its cancelled/deadline status
-                self._finish_trace(tr, ctx, stats)
-            return out
+        with self._query_scope() as qs:
+            plan = self._resolve_subqueries(plan)
+            phys = self._plan_physical(plan)
+            ctx = ExecContext(qs.conf, device=self.device)
+            qs.ctxs.append(ctx)
+            with get_semaphore(qs.conf).acquire():
+                phys = self._distribute_if_ici(phys, ctx)
+                if qs.trace is not None:
+                    qs.trace.register_plan(phys)
+                batches = [b for b in phys.execute(ctx)
+                           if b.num_rows > 0]
+                if not batches:
+                    return None
+                whole = batches[0] if len(batches) == 1 else \
+                    batch_utils.concat_batches(batches)
+                return batch_utils.compact(whole)
 
     def _execute_resolved(self, plan: L.LogicalPlan):
         from ..runtime.semaphore import get_semaphore
-        from ..utils.metrics import QueryStats
-        conf = self._tpu_conf()
-        phys = self._plan_physical(plan)
-        ctx = ExecContext(conf, device=self.device)
-        # expose the last query's per-operator metrics + plan for
-        # debugging/profiling (sess.last_exec_context().metrics,
-        # sess.profiled_explain())
-        self._last_ctx = ctx
-        self._last_phys = phys
-        with QueryStats.scoped() as stats, self._fault_scope(conf), \
-                self._control_scope(conf), self._trace_scope(conf) as tr:
-            try:
-                with get_semaphore(conf).acquire():
-                    phys = self._distribute_if_ici(phys, ctx)
-                    self._last_phys = phys
-                    if tr is not None:
-                        tr.register_plan(phys)
-                    self._note_scheduler(tr)
-                    out = CollectExec(phys).collect_arrow(ctx)
-            except BaseException as e:
-                self._trace_status(tr, e)
-                raise
-            finally:
-                self._finish_trace(tr, ctx, stats)
-            return out
+        with self._query_scope() as qs:
+            phys = self._plan_physical(plan)
+            ctx = ExecContext(qs.conf, device=self.device)
+            qs.ctxs.append(ctx)
+            # expose the last query's per-operator metrics + plan for
+            # debugging/profiling (sess.last_exec_context().metrics,
+            # sess.profiled_explain())
+            self._last_ctx = ctx
+            self._last_phys = phys
+            with get_semaphore(qs.conf).acquire():
+                phys = self._distribute_if_ici(phys, ctx)
+                self._last_phys = phys
+                if qs.trace is not None:
+                    qs.trace.register_plan(phys)
+                return CollectExec(phys).collect_arrow(ctx)
 
     def last_exec_context(self):
         """ExecContext of the most recent collect (per-operator MetricSet
@@ -590,49 +643,50 @@ class Session:
     def _execute_batches(self, plan: L.LogicalPlan):
         """Stream the result as pyarrow Tables, one per output batch —
         the write path's entry so results never materialize wholesale."""
-        conf = self._tpu_conf()
-        phys = self._plan_physical(plan)
-        return self._execute_planned_stream(phys, conf)
+        return self._stream(lambda: self._plan_physical(plan))
 
     def _stream_plan(self, plan: L.LogicalPlan):
         """Plan + stream a logical plan (subqueries resolved) — the
         network front door's FRESH-submit path (server/endpoint.py):
         result batches reach the consumer as their D2H fetches complete
         instead of after a wholesale collect."""
-        from ..plan.subquery import resolve_subqueries
-        plan = resolve_subqueries(plan, self._collect_rows)
-        return self._execute_batches(plan)
+        return self._stream(lambda: self._plan_physical(
+            self._resolve_subqueries(plan)))
 
     def _execute_planned_stream(self, phys, conf=None):
-        """Stream pyarrow tables from an ALREADY-PLANNED physical tree,
-        under the full per-query scope stack (stats/fault/control/trace +
-        semaphore).  Logical planning and overrides are SKIPPED — this is
-        the prepared-statement fast path (server/prepared.py plans once,
+        """Stream pyarrow tables from an ALREADY-PLANNED physical tree.
+        Logical planning and overrides are SKIPPED — this is the
+        prepared-statement fast path (server/prepared.py plans once,
         clones the tree per execution, and re-runs it here with freshly
-        bound parameters).  D2H fetches ride the async pipeline depth
-        (runtime/pipeline.stream_arrow), so incremental consumers — the
-        wire, the write path — see batch N while batch N+1 dispatches."""
+        bound parameters)."""
+        return self._stream(lambda: phys, conf)
+
+    def _stream(self, planned, conf=None):
+        """Stream pyarrow tables from the physical tree ``planned()``
+        returns, under the full per-query scope stack (stats / fault /
+        control / trace / account + semaphore); ``planned`` runs inside
+        the scope, so planning is in the query's wall.  D2H fetches ride
+        the async pipeline depth (runtime/pipeline.stream_arrow), so
+        incremental consumers — the wire, the write path — see batch N
+        while batch N+1 dispatches.  While the consumer holds a table
+        the account's clock stands still: the query's wall is the
+        engine's share of the stream."""
         from ..runtime.pipeline import stream_arrow
         from ..runtime.semaphore import get_semaphore
-        from ..utils.metrics import QueryStats
-        if conf is None:
-            conf = self._tpu_conf()
-        ctx = ExecContext(conf, device=self.device)
-        with QueryStats.scoped() as stats, self._fault_scope(conf), \
-                self._control_scope(conf), self._trace_scope(conf) as tr:
-            try:
-                with get_semaphore(conf).acquire():
-                    phys = self._distribute_if_ici(phys, ctx)
-                    if tr is not None:
-                        tr.register_plan(phys)
-                    self._note_scheduler(tr)
-                    for t in stream_arrow(ctx, phys.execute(ctx)):
+        from ..utils import tracing
+        with self._query_scope() as qs:
+            if conf is None:
+                conf = qs.conf
+            phys = planned()
+            ctx = ExecContext(conf, device=self.device)
+            qs.ctxs.append(ctx)
+            with get_semaphore(conf).acquire():
+                phys = self._distribute_if_ici(phys, ctx)
+                if qs.trace is not None:
+                    qs.trace.register_plan(phys)
+                for t in stream_arrow(ctx, phys.execute(ctx)):
+                    with tracing.suspended():
                         yield t
-            except BaseException as e:
-                self._trace_status(tr, e)
-                raise
-            finally:
-                self._finish_trace(tr, ctx, stats)
 
     def _explain(self, plan: L.LogicalPlan) -> str:
         from ..plan.overrides import explain_plan

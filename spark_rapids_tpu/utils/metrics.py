@@ -1,17 +1,18 @@
-"""Operator metrics + trace annotations.
+"""Operator metrics + the counted device boundaries.
 
 Two-tier design copied from the reference (SURVEY.md §5.1): per-operator SQL
 metrics (GpuExec.scala:49-141 ``GpuMetric`` with ESSENTIAL/MODERATE/DEBUG
 levels) and task-level counters (GpuTaskMetrics.scala).  NVTX ranges
-(NvtxWithMetrics.scala:34) become ``jax.profiler.TraceAnnotation`` so the
-ranges land in XLA/TPU profiler timelines.
+(NvtxWithMetrics.scala:34) are the spans of ``utils/tracing.py``: every
+timer here is one, so it lands in XLA/TPU profiler timelines at every
+metrics level.
 """
 
 from __future__ import annotations
 
 import contextlib
 import contextvars
-import time
+import sys
 from collections import defaultdict
 from typing import Dict
 
@@ -19,7 +20,7 @@ import jax
 
 from . import tracing
 
-__all__ = ["MetricSet", "TaskMetrics", "QueryStats", "trace_range",
+__all__ = ["MetricSet", "TaskMetrics", "QueryStats", "upload",
            "fetch", "fetch_async", "fetch_scalars", "prestage",
            "sync_budget", "FetchFuture", "RegionPrologue", "region_scope",
            "region_enter", "region_exit", "current_region",
@@ -74,8 +75,30 @@ class QueryStats:
         self.fetch_wait_s = 0.0
         self.compiles = 0
         self.compile_s = 0.0
+        # host→device uploads through :func:`upload` (one per column of
+        # a batch a scan stages): calls, device bytes as padded, seconds
+        # inside jax.device_put, summed over the threads that upload
         self.uploads = 0
         self.upload_bytes = 0
+        self.upload_s = 0.0
+        # seconds inside ``scan:decode`` spans (io/), summed over the
+        # threads that decode: can pass the query's wall
+        self.decode_s = 0.0
+        # the query's host-time account (utils/tracing.account): nine
+        # disjoint terms of the DRIVING thread's time, by span self
+        # time, that sum to ``query_wall_s``.  Unlike fetch_wait_s /
+        # h2d_wait_s / pipeline_stage_s above and below, which sum the
+        # waits of every thread, these are shares of one wall
+        self.acct_plan_s = 0.0
+        self.acct_admit_s = 0.0
+        self.acct_compile_s = 0.0
+        self.acct_h2d_wait_s = 0.0
+        self.acct_fetch_wait_s = 0.0
+        self.acct_dispatch_s = 0.0
+        self.acct_result_s = 0.0
+        self.acct_host_exec_s = 0.0
+        self.acct_unattributed_s = 0.0
+        self.query_wall_s = 0.0
         # bytes entering shuffle exchanges (device batch sizes at the
         # staging barrier) — BASELINE.json's shuffle-GB/s metric input
         self.shuffle_bytes = 0
@@ -250,8 +273,12 @@ class QueryStats:
                 s = cls.get()
                 s.compiles += 1
                 s.compile_s += duration
+                # measured by jax, on the thread that compiled: recorded
+                # after the fact, under the name the program was given
                 tracing.record(None, "compile", "compile",
-                               time.perf_counter() - duration, duration)
+                               tracing._pc() - duration, duration,
+                               fun_name=kw.get("fun_name"))
+                tracing.charge("compile", duration)
                 # a finished compile is PROGRESS: the watchdog must not
                 # mistake a query grinding through a compile sequence
                 # for a hung one
@@ -289,41 +316,6 @@ class QueryStats:
                     else now[k] - before.get(k, 0)) for k in now}
 
 
-import os as _os
-
-_TRACE_SYNCS = bool(_os.environ.get("SRT_SYNC_TRACE"))
-SYNC_TRACE: list = []  # [(call-site, seconds)] when SRT_SYNC_TRACE is set
-# hard cap on the debug list: a long bench/serve run under SRT_SYNC_TRACE
-# must not grow host memory without bound — entries beyond the cap are
-# counted, not stored (sync_trace_dropped()).
-SYNC_TRACE_MAX = int(_os.environ.get("SRT_SYNC_TRACE_MAX", "10000"))
-_SYNC_TRACE_DROPPED = [0]
-
-
-def sync_trace_dropped() -> int:
-    """Entries dropped from SYNC_TRACE after it hit SYNC_TRACE_MAX."""
-    return _SYNC_TRACE_DROPPED[0]
-
-
-def _export_sync_trace_drops() -> None:
-    """Scrape-time provider: the SYNC_TRACE debug list's drop count is
-    visible on the ops surface instead of silently lost."""
-    from . import telemetry
-    telemetry.gauge_set("sync_trace_dropped", float(sync_trace_dropped()))
-
-
-from . import telemetry as _telemetry  # noqa: E402 (after the state it exports)
-
-_telemetry.register_provider(_export_sync_trace_drops)
-
-
-def _sync_trace_append(entry) -> None:
-    if len(SYNC_TRACE) < SYNC_TRACE_MAX:
-        SYNC_TRACE.append(entry)
-    else:
-        _SYNC_TRACE_DROPPED[0] += 1
-
-
 def _tree_nbytes(host) -> int:
     import numpy as np
     total = 0
@@ -335,31 +327,33 @@ def _tree_nbytes(host) -> int:
     return total
 
 
-def _call_site(extra_frames: int = 0) -> str:
-    import traceback
-    drop = 2 + extra_frames  # _call_site + the helper that asked for it
-    return "|".join(
-        f"{f.filename.rsplit('/', 1)[-1]}:{f.lineno}"
-        for f in traceback.extract_stack(limit=6 + drop)[:-drop])
+def _call_site() -> str:
+    """``file:line|file:line|...`` of the frames that asked for a fetch,
+    outermost first (no source lookup: cheap enough for every fetch of a
+    traced query)."""
+    f = sys._getframe(2)  # past _call_site and the fetch helper
+    parts = []
+    while f is not None and len(parts) < 6:
+        parts.append(f"{f.f_code.co_filename.rsplit('/', 1)[-1]}:"
+                     f"{f.f_lineno}")
+        f = f.f_back
+    return "|".join(reversed(parts))
 
 
-def _resolve_tree(tree, site=None, tag: str = ""):
+def _resolve_tree(tree, site=None, blocking: bool = True):
     """The ONE ``jax.device_get`` call site for sync AND async fetches:
-    times the wait (``fetch_wait_s``), accounts bytes, and — under
-    SRT_SYNC_TRACE — appends the attributed call site to SYNC_TRACE."""
+    a ``fetch:blocking`` / ``fetch:async`` span whose seconds add to
+    ``fetch_wait_s`` (every thread's) and whose event carries the bytes
+    and, when a QueryTrace is active, the call site that asked."""
     s = QueryStats.get()
-    t0 = time.perf_counter()
-    host = jax.device_get(tree)
-    dt = time.perf_counter() - t0
-    nbytes = _tree_nbytes(host)
-    s.fetch_wait_s += dt
+    with tracing.span(None, "fetch:blocking" if blocking
+                      else "fetch:async", "fetch") as sp:
+        host = jax.device_get(tree)
+        nbytes = _tree_nbytes(host)
+        if site is not None:
+            sp.set(bytes=nbytes, blocking=blocking, site=site)
+    s.fetch_wait_s += sp.dur
     s.fetch_bytes += nbytes
-    tracing.record(None, "fetch", "fetch", t0, dt,
-                   bytes=nbytes, blocking=not tag)
-    if _TRACE_SYNCS:
-        if site is None:
-            site = _call_site(extra_frames=1)
-        _sync_trace_append(((tag + site) if tag else site, round(dt, 4)))
     return host
 
 
@@ -373,19 +367,36 @@ def fetch(tree):
     """
     s = QueryStats.get()
     s.blocking_fetches += 1
-    host = _resolve_tree(tree, site=_call_site() if _TRACE_SYNCS else None)
+    host = _resolve_tree(
+        tree, site=_call_site() if tracing.active() is not None else None)
     _check_budget()
     return host
 
 
+def upload(tree, device=None):
+    """The engine's ONE counted host→device transfer: ``jax.device_put``
+    of a pytree of host arrays under a ``scan:upload`` span, counted in
+    ``QueryStats.uploads`` / ``upload_bytes`` (device bytes, as padded)
+    / ``upload_s``."""
+    s = QueryStats.get()
+    with tracing.span(None, "scan:upload", "io") as sp:
+        out = jax.device_put(tree, device)
+    s.uploads += 1
+    s.upload_bytes += sum(
+        leaf.nbytes for leaf in jax.tree_util.tree_leaves(out))
+    s.upload_s += sp.dur
+    return out
+
+
 def _start_copies(tree) -> None:
-    for leaf in jax.tree_util.tree_leaves(tree):
-        start = getattr(leaf, "copy_to_host_async", None)
-        if start is not None:
-            try:
-                start()
-            except Exception:  # fault-ok (async-copy hint only; the blocking get still works)
-                pass
+    with tracing.span(None, "fetch:start_copies", "fetch"):
+        for leaf in jax.tree_util.tree_leaves(tree):
+            start = getattr(leaf, "copy_to_host_async", None)
+            if start is not None:
+                try:
+                    start()
+                except Exception:  # fault-ok (async-copy hint only; the blocking get still works)
+                    pass
 
 
 class FetchFuture:
@@ -395,8 +406,8 @@ class FetchFuture:
     finished yet — the copy overlaps the next batch's dispatch instead
     of stalling the pull loop.  Resolution
     routes through the same accounting as :func:`fetch` (bytes, wait
-    time, SRT_SYNC_TRACE site) but counts as an *async* fetch, excluded
-    from the blocking-fetch budget.
+    time, call site) but counts as an *async* fetch, excluded from the
+    blocking-fetch budget.
     """
 
     __slots__ = ("_tree", "_site", "_host", "_done")
@@ -410,7 +421,7 @@ class FetchFuture:
     def result(self):
         if not self._done:
             self._host = _resolve_tree(self._tree, site=self._site,
-                                       tag="async|")
+                                       blocking=False)
             self._tree = None  # drop device refs once resolved
             self._done = True
         return self._host
@@ -423,7 +434,7 @@ def fetch_async(tree) -> FetchFuture:
     ride this so the copy overlaps the next batch's dispatch."""
     s = QueryStats.get()
     s.async_fetches += 1
-    site = _call_site() if _TRACE_SYNCS else None
+    site = _call_site() if tracing.active() is not None else None
     _start_copies(tree)
     return FetchFuture(tree, site)
 
@@ -642,9 +653,10 @@ class MetricSet:
     """Named counters/timers for one operator instance.
 
     ``level`` mirrors spark.rapids.tpu.sql.metrics.level (GpuMetric's
-    ESSENTIAL/MODERATE/DEBUG): ESSENTIAL records counters only (timers are
-    no-ops), MODERATE (default) adds wall-clock timers, DEBUG additionally
-    emits jax profiler trace ranges so operator spans land in TPU profiles.
+    ESSENTIAL/MODERATE/DEBUG): ESSENTIAL records counters only (timers
+    keep their span and add no value), MODERATE (default) adds wall-clock
+    timers, DEBUG additionally makes operators count what costs a fetch
+    (plan/join_exec.py).
     """
 
     def __init__(self, op_id: str, level: str = "MODERATE"):
@@ -672,25 +684,14 @@ class MetricSet:
         for name, fut in pending:
             self.values[name] += int(fut.result())  # wait-ok (deferred metric; the copy is already behind the dispatch front)
 
-    @contextlib.contextmanager
-    def time(self, name: str):
+    def time(self, name: str) -> "_TimedSpan":
         """Time a named phase of this operator.  This is the span API for
         exec-node timing (the srtlint span-timing pass rejects raw clock
-        reads in the operator layer): the measurement lands in the metric
-        value AND — when a query trace is active — as a phase span under
-        the operator (decode/H2D/dispatch/fetch attribution)."""
-        if self.level == "ESSENTIAL":
-            yield
-            return
-        t0 = time.perf_counter()
-        if self.level == "DEBUG":
-            with trace_range(f"{self.op_id}:{name}"):
-                yield
-        else:
-            yield
-        dt = time.perf_counter() - t0
-        self.values[name] += dt
-        tracing.record(self.op_id, name, "phase", t0, dt)
+        reads in the operator layer): an ``op:<name>`` span at every
+        level (profiler annotation, phase event under the operator, the
+        account's ``host_exec``), whose seconds also land in the metric
+        value unless the level is ESSENTIAL."""
+        return _TimedSpan(self, name)
 
     def __getitem__(self, name: str) -> float:
         self._resolve()
@@ -700,6 +701,22 @@ class MetricSet:
         self._resolve()
         inner = ", ".join(f"{k}={v:.4g}" for k, v in sorted(self.values.items()))
         return f"MetricSet({self.op_id}: {inner})"
+
+
+class _TimedSpan(tracing._Span):
+    """``MetricSet.time``'s span: closes into the operator's metric."""
+
+    __slots__ = ("_mset",)
+
+    def __init__(self, mset: MetricSet, name: str):
+        super().__init__(mset.op_id, name, "phase", "op:" + name)
+        self._mset = mset
+
+    def __exit__(self, et=None, ev=None, tb=None):
+        super().__exit__(et, ev, tb)
+        if self._mset.level != "ESSENTIAL":
+            self._mset.values[self._name] += self.dur
+        return False
 
 
 class TaskMetrics:
@@ -736,10 +753,3 @@ class TaskMetrics:
         # instance (there is exactly one task-metrics object per process)
         cls.get().reset_counts()
         return cls._current
-
-
-@contextlib.contextmanager
-def trace_range(name: str):
-    """Profiler trace annotation (NVTX range analog)."""
-    with jax.profiler.TraceAnnotation(name):
-        yield

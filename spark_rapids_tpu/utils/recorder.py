@@ -65,8 +65,29 @@ _pc = time.perf_counter
 # trace_report --why, and the perf_anomalies_total counter all share.
 # ---------------------------------------------------------------------------------
 
-TERMS = ("queue_wait", "compile", "h2d", "dispatch", "fetch_wait",
+# ``queue_wait`` is the scheduler's, before the query's wall starts.  The
+# nine from ``plan`` to ``unattributed`` are the query's host-time account
+# (utils/tracing.account, ``QueryStats.acct_*_s``): disjoint shares of the
+# driving thread's time that sum to the wall.  ``shuffle`` / ``spill`` /
+# ``stream_spool`` are span seconds of any thread, kept beside the account
+# for the verdicts that name them (they overlap ``host_exec``).
+TERMS = ("queue_wait", "plan", "admit", "compile", "h2d", "dispatch",
+         "fetch_wait", "host_exec", "result", "unattributed",
          "shuffle", "spill", "stream_spool")
+
+# account term -> (QueryStats field, the all-thread stat an older dump
+# without the account carries for it)
+_ACCOUNT_FIELDS = (
+    ("plan", "acct_plan_s", None),
+    ("admit", "acct_admit_s", None),
+    ("compile", "acct_compile_s", "compile_s"),
+    ("h2d", "acct_h2d_wait_s", "h2d_wait_s"),
+    ("dispatch", "acct_dispatch_s", None),
+    ("fetch_wait", "acct_fetch_wait_s", "fetch_wait_s"),
+    ("host_exec", "acct_host_exec_s", None),
+    ("result", "acct_result_s", None),
+    ("unattributed", "acct_unattributed_s", None),
+)
 
 # a term is anomalous when it exceeds BOTH a ratio over the fingerprint's
 # EWMA baseline and an absolute floor (sub-50ms jitter is not a verdict)
@@ -94,61 +115,39 @@ _CONF_TRACE_DIR = "spark.rapids.tpu.sql.trace.dir"
 # Term decomposition (shared with tools/explain_slow.py)
 # ---------------------------------------------------------------------------------
 
-def _busy_union(intervals: List[Tuple[float, float]]) -> float:
-    """Total covered seconds of possibly-nested/overlapping intervals."""
-    if not intervals:
-        return 0.0
-    intervals.sort()
-    total = 0.0
-    cur_s, cur_e = intervals[0]
-    for s, e in intervals[1:]:
-        if s > cur_e:
-            total += cur_e - cur_s
-            cur_s, cur_e = s, e
-        elif e > cur_e:
-            cur_e = e
-    return total + (cur_e - cur_s)
-
-
 def decompose(attrs: Dict[str, object],
               events) -> Dict[str, float]:
     """Decompose one query into the canonical wait terms (seconds).
 
-    ``attrs`` is the trace's root attribute dict (the QueryStats
-    snapshot absorbed at finish is authoritative for the accounted
-    waits); ``events`` is an iterable of ``(name, cat, ts_s, dur_s,
-    tid)`` tuples covering what the stats don't break out (operator
-    busy time per thread, shuffle/server span seconds)."""
+    ``attrs`` is the trace's root attribute dict: the QueryStats
+    snapshot absorbed at finish carries the query's host-time account,
+    whose nine terms sum to ``query_wall_s``; ``events`` is an iterable
+    of ``(name, cat, ts_s, dur_s, tid)`` tuples for what the account
+    does not break out (shuffle / spill / server span seconds)."""
     def att(key):
         try:
             return max(0.0, float(attrs.get(key, 0.0) or 0.0))
         except (TypeError, ValueError):
             return 0.0
 
-    dispatch: Dict[int, List[Tuple[float, float]]] = {}
     shuffle = spill = stream = 0.0
     for name, cat, ts, dur, tid in events:
         if dur <= 0.0:
             continue
-        if cat == "operator":
-            dispatch.setdefault(tid, []).append((ts, ts + dur))
-        elif cat == "shuffle":
+        if cat == "shuffle":
             shuffle += dur
         elif cat == "server":
             stream += dur
         if "spill" in name:
             spill += dur
-    return {
-        "queue_wait": att("queue_wait_s"),
-        "compile": att("compile_s"),
-        "h2d": att("h2d_wait_s"),
-        "dispatch": round(sum(_busy_union(v) for v in dispatch.values()),
-                          6),
-        "fetch_wait": att("fetch_wait_s"),
-        "shuffle": round(shuffle, 6),
-        "spill": round(spill, 6),
-        "stream_spool": round(stream, 6),
-    }
+    closed = "query_wall_s" in attrs
+    terms = {"queue_wait": att("queue_wait_s")}
+    for term, field, older in _ACCOUNT_FIELDS:
+        terms[term] = att(field if closed or older is None else older)
+    terms["shuffle"] = round(shuffle, 6)
+    terms["spill"] = round(spill, 6)
+    terms["stream_spool"] = round(stream, 6)
+    return terms
 
 
 def _trace_events(tr):

@@ -148,9 +148,6 @@ METRICS = (
     ("trace_events_dropped_total", "counter", "",
      "Trace events dropped past sql.trace.maxEvents — a truncated "
      "trace is visibly truncated."),
-    ("sync_trace_dropped", "gauge", "",
-     "Entries dropped from the SRT_SYNC_TRACE debug list after "
-     "SYNC_TRACE_MAX."),
     # -- per-query accounting folded from QueryStats at scope exit -----------------
     ("query_blocking_fetches_total", "counter", "",
      "Blocking device-to-host fetches across all finished queries."),
@@ -169,6 +166,38 @@ METRICS = (
      "Host-to-device uploads issued by finished queries."),
     ("query_upload_bytes_total", "counter", "",
      "Host-to-device bytes uploaded by finished queries."),
+    ("query_upload_seconds_total", "counter", "",
+     "Seconds inside jax.device_put (scan:upload spans), summed over "
+     "the threads that upload."),
+    ("query_decode_seconds_total", "counter", "",
+     "Seconds inside scan:decode spans, summed over the threads that "
+     "decode."),
+    # the host-time account (utils/tracing.account): disjoint shares of
+    # the driving thread's time; the nine terms sum to the wall
+    ("query_wall_seconds_total", "counter", "",
+     "Wall seconds of finished queries, entry point to rows."),
+    ("query_acct_plan_seconds_total", "counter", "",
+     "Account: planning (plan:* spans' self time)."),
+    ("query_acct_admit_seconds_total", "counter", "",
+     "Account: waiting for a device permit (admit:semaphore)."),
+    ("query_acct_compile_seconds_total", "counter", "",
+     "Account: backend compiles on the driving thread."),
+    ("query_acct_h2d_wait_seconds_total", "counter", "",
+     "Account: the driving thread blocked on a staged batch "
+     "(pipeline:wait, scan:wait)."),
+    ("query_acct_fetch_wait_seconds_total", "counter", "",
+     "Account: the driving thread blocked inside device_get."),
+    ("query_acct_dispatch_seconds_total", "counter", "",
+     "Account: the driving thread inside the JAX runtime and not "
+     "fetching (program:*, eager:gather, scan:upload, "
+     "fetch:start_copies)."),
+    ("query_acct_result_seconds_total", "counter", "",
+     "Account: result materialisation (result:* spans)."),
+    ("query_acct_host_exec_seconds_total", "counter", "",
+     "Account: the program's own Python inside operators (op:* self "
+     "time)."),
+    ("query_acct_unattributed_seconds_total", "counter", "",
+     "Account: wall under no span at all."),
     ("query_shuffle_bytes_total", "counter", "",
      "Bytes entering shuffle exchanges."),
     ("query_h2d_wait_seconds_total", "counter", "",
@@ -316,6 +345,18 @@ _QS_FOLD = (
     ("compile_s", "query_compile_seconds_total"),
     ("uploads", "query_uploads_total"),
     ("upload_bytes", "query_upload_bytes_total"),
+    ("upload_s", "query_upload_seconds_total"),
+    ("decode_s", "query_decode_seconds_total"),
+    ("query_wall_s", "query_wall_seconds_total"),
+    ("acct_plan_s", "query_acct_plan_seconds_total"),
+    ("acct_admit_s", "query_acct_admit_seconds_total"),
+    ("acct_compile_s", "query_acct_compile_seconds_total"),
+    ("acct_h2d_wait_s", "query_acct_h2d_wait_seconds_total"),
+    ("acct_fetch_wait_s", "query_acct_fetch_wait_seconds_total"),
+    ("acct_dispatch_s", "query_acct_dispatch_seconds_total"),
+    ("acct_result_s", "query_acct_result_seconds_total"),
+    ("acct_host_exec_s", "query_acct_host_exec_seconds_total"),
+    ("acct_unattributed_s", "query_acct_unattributed_seconds_total"),
     ("shuffle_bytes", "query_shuffle_bytes_total"),
     ("h2d_wait_s", "query_h2d_wait_seconds_total"),
     ("donated_batches", "query_donated_batches_total"),

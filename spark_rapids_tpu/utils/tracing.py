@@ -12,8 +12,8 @@ overlapped decode / H2D staging / dispatch / D2H phases (runtime/pipeline):
   * **phase spans** under each operator for the engine's data-movement
     phases: decode (io layer), H2D staging (``scanTime``), dispatch
     (``opTime``), pipeline stage/wait (runtime/pipeline), and D2H fetch
-    (utils/metrics ``fetch``/``fetch_async``) — today's ``trace_range``
-    and ``QueryStats`` accounting absorbed into span attributes;
+    (utils/metrics ``fetch``/``fetch_async``), under the fixed
+    ``<layer>:<name>`` vocabulary of :data:`SPANS`;
   * a **Chrome-trace-event JSON exporter** (loads in Perfetto /
     ``chrome://tracing``) so a query's overlap structure is visually
     inspectable, plus a ``spanTree`` extension key carrying the
@@ -21,9 +21,16 @@ overlapped decode / H2D staging / dispatch / D2H phases (runtime/pipeline):
 
 Everything is contextvar-scoped: two concurrent queries trace
 independently, and the pipeline/io worker threads join their query's
-trace by running in a copied context.  When no trace is active every
-entry point is a single ContextVar read returning a no-op — the
-tracing-off path adds no allocation to the pull loop.
+trace by running in a copied context.
+
+ONE span primitive serves three readers.  Every :func:`span` enters a
+``jax.profiler.TraceAnnotation`` under its vocabulary name, so whenever
+any profiler session is live the program's spans sit in the same
+``.xplane.pb`` as ``XLA Ops``, on its clock; it adds an event to the
+active :class:`QueryTrace`, if any; and on the query's DRIVING thread
+(the one that opened the scope) it charges its self time to one term of
+the query's host-time account (:func:`account`), which closes into
+``QueryStats.acct_*`` once per query.
 
 This module is the ONE place exec-node timing may read the clock;
 srtlint's ``span-timing`` pass rejects raw ``time.perf_counter()`` in the
@@ -39,10 +46,13 @@ import threading
 import time
 from typing import Dict, Iterator, List, Optional
 
+from jax.profiler import TraceAnnotation as _Annotation
+
 from ..service import cancel as _cancel
 
 __all__ = ["QueryTrace", "active", "query_trace", "span", "record", "mark",
-           "instrument_batches", "render_profiled", "NULL_SPAN",
+           "instrument_batches", "render_profiled", "SPANS",
+           "ACCOUNT_TERMS", "account", "charge", "suspended",
            "merge_chrome", "write_merged", "trace_context",
            "shard_record", "shard_paths"]
 
@@ -78,33 +88,195 @@ MARKS = (
 )
 
 
-class _NullSpan:
-    """No-op span: the tracing-off fast path allocates nothing."""
+# ---------------------------------------------------------------------------------
+# Span vocabulary and the host-time account.  A span's name is
+# ``<layer>:<name>``: exactly one colon, and never an object id, a
+# literal or a fingerprint (an ``op_id`` is ``ClassName@<id(self)>`` and
+# differs in every process: it rides in the QueryTrace event, not in the
+# name).  The second column is the account term the span's SELF time is
+# charged to on the driving thread; ``op:`` and ``program:`` are
+# families (``op:<exec class or MetricSet timer>``, ``program:<name
+# given to plan/physical.program>``).  docs/observability.md renders
+# this table; tests hold emitted names to it.
+# ---------------------------------------------------------------------------------
 
-    __slots__ = ()
+ACCOUNT_TERMS = ("plan", "admit", "compile", "h2d_wait", "fetch_wait",
+                 "dispatch", "result", "host_exec", "unattributed")
 
-    def __enter__(self):
-        return self
+SPANS = (
+    ("plan:subqueries", "plan",
+     "sql/session.py: resolve_subqueries' own work (a subquery's "
+     "execution nests inside and is charged to its own terms)."),
+    ("plan:overrides", "plan",
+     "plan/overrides.py apply_overrides: pushdown, tagging, CBO, "
+     "conversion to exec nodes."),
+    ("plan:fusion", "plan",
+     "plan/overrides.py apply_overrides: insert_coalesce + "
+     "plan/fusion.py plan_regions."),
+    ("plan:distribute", "plan",
+     "sql/session.py _distribute_if_ici (shuffle.mode=ICI)."),
+    ("admit:semaphore", "admit",
+     "runtime/semaphore.py acquire: waiting for a device permit."),
+    ("scan:decode", "host_exec",
+     "io/parquet.py, io/sources.py: one decoded table (QueryStats."
+     "decode_s sums it over threads)."),
+    ("scan:wait", "h2d_wait",
+     "io/parquet.py, io/sources.py: the scan blocked on its prefetch "
+     "thread's next decoded table."),
+    ("scan:upload", "dispatch",
+     "utils/metrics.py upload: jax.device_put of host arrays "
+     "(QueryStats.uploads / upload_bytes / upload_s)."),
+    ("pipeline:wait", "h2d_wait",
+     "runtime/pipeline.py: the consumer blocked on a staged batch "
+     "(QueryStats.h2d_wait_s sums it over threads)."),
+    ("pipeline:stage", "host_exec",
+     "runtime/pipeline.py: the worker producing one staged batch "
+     "(QueryStats.pipeline_stage_s)."),
+    ("fetch:blocking", "fetch_wait",
+     "utils/metrics.py fetch: jax.device_get behind the dispatch front "
+     "(QueryStats.fetch_wait_s sums it over threads)."),
+    ("fetch:async", "fetch_wait",
+     "utils/metrics.py FetchFuture.result: what is left of a copy "
+     "started earlier."),
+    ("fetch:start_copies", "dispatch",
+     "utils/metrics.py _start_copies: copy_to_host_async on every leaf."),
+    ("eager:gather", "dispatch",
+     "ops/batch_utils.py gather: one eager x[indices] per array of a "
+     "batch, each a jitted dispatch of its own (sort, top-k, window)."),
+    ("result:arrow", "result",
+     "batch.py _to_arrow_finish: host arrays to a pyarrow table."),
+    ("result:concat", "result",
+     "plan/physical.py CollectExec: pa.concat_tables."),
+    ("result:rows", "result",
+     "sql/dataframe.py collect, sql/session.py _collect_rows: arrow "
+     "table to python rows."),
+    ("shuffle:write", "host_exec", "parallel/host_shuffle.py."),
+    ("shuffle:read", "host_exec", "parallel/host_shuffle.py."),
+    ("dcn:fetch", "host_exec", "parallel/dcn.py fragment fetch."),
+    ("ici:fragment", "host_exec", "parallel/spmd.py mesh fragment."),
+    ("op:", "host_exec",
+     "instrument_batches (one pull through an exec node) and "
+     "MetricSet.time (op:opTime, op:scanTime, op:buildTime): the "
+     "program's own Python inside an operator."),
+    ("program:", "dispatch",
+     "plan/physical.py program(): one call of a jitted program."),
+)
 
-    def __exit__(self, *exc):
-        return False
+_TERM_OF = {name: term for name, term, _ in SPANS
+            if not name.endswith(":")}
+_FAMILY_TERM = {name: term for name, term, _ in SPANS
+                if name.endswith(":")}
 
-    def set(self, **attrs):
-        return self
+
+def _term_of(name: str) -> Optional[str]:
+    """The account term of a span name (None: transparent, its self time
+    stays its parent's).  Family members are memoised: the vocabulary is
+    fixed, so the table stays small."""
+    try:
+        return _TERM_OF[name]
+    except KeyError:
+        term = _FAMILY_TERM.get(name[:name.find(":") + 1])
+        _TERM_OF[name] = term
+        return term
 
 
-NULL_SPAN = _NullSpan()
+class _Account:
+    """One query's host-time account, kept on the driving thread.
+
+    Self time without a stack: ``child`` is the time that spans closed
+    so far cover under the span now open.  A span saves it on entry and
+    zeroes it; on exit its self time is its duration less ``child``, and
+    ``child`` becomes the saved value plus its whole duration."""
+
+    __slots__ = ("tid", "t0", "child", "excluded", "terms")
+
+    def __init__(self):
+        self.tid = threading.get_ident()
+        self.child = 0.0
+        self.excluded = 0.0
+        self.terms = dict.fromkeys(ACCOUNT_TERMS[:-1], 0.0)
+        self.t0 = _pc()
+
+
+_ACCT: "contextvars.ContextVar[Optional[_Account]]" = \
+    contextvars.ContextVar("srt_query_account", default=None)
+
+
+def _my_account() -> Optional[_Account]:
+    acct = _ACCT.get()
+    if acct is not None and acct.tid == threading.get_ident():
+        return acct
+    return None
+
+
+@contextlib.contextmanager
+def account(stats):
+    """Open the query's host-time account on THIS thread, the driving
+    thread; on exit write the nine terms and the wall into ``stats``
+    (``QueryStats.acct_*_s``, ``query_wall_s``).  One per query: a
+    nested sub-execution (a scalar subquery) runs inside its parent's
+    scope (``Session._query_scope``), so its spans are charged here and
+    its wall is not counted twice."""
+    acct = _Account()
+    tok = _ACCT.set(acct)
+    try:
+        yield
+    finally:
+        wall = max(0.0, _pc() - acct.t0 - acct.excluded)
+        try:
+            _ACCT.reset(tok)
+        except ValueError:  # generator-held scope closed out of order
+            _ACCT.set(None)
+        rest = wall
+        for term, v in acct.terms.items():
+            setattr(stats, f"acct_{term}_s",
+                    getattr(stats, f"acct_{term}_s") + v)
+            rest -= v
+        stats.acct_unattributed_s += max(0.0, rest)
+        stats.query_wall_s += wall
+
+
+def charge(term: str, dur: float) -> None:
+    """Charge an interval that someone else measured on this thread (a
+    backend compile, reported by jax.monitoring when it ends) to
+    ``term``, and take it out of the open span's self time."""
+    acct = _my_account()
+    if acct is not None:
+        acct.terms[term] += dur
+        acct.child += dur
+
+
+@contextlib.contextmanager
+def suspended():
+    """Stop the account's clock while a streaming execution's consumer
+    holds the thread (the ``yield`` of a generator-shaped entry point):
+    that time is neither the query's wall nor any span's."""
+    acct = _my_account()
+    if acct is None:
+        yield
+        return
+    t0 = _pc()
+    try:
+        yield
+    finally:
+        dt = _pc() - t0
+        acct.excluded += dt
+        acct.child += dt
 
 
 class _Span:
-    """A live timed span; records one event on exit."""
+    """A live timed span: a profiler annotation, one QueryTrace event on
+    exit, and on the driving thread a charge to the query's account.
+    ``dur`` holds its seconds once it has closed."""
 
-    __slots__ = ("_op", "_name", "_cat", "_args", "_t0")
+    __slots__ = ("_op", "_name", "_cat", "_ann_name", "_args", "_t0",
+                 "_ann", "_acct", "_saved", "dur")
 
-    def __init__(self, op_id, name, cat):
+    def __init__(self, op_id, name, cat, ann=None):
         self._op = op_id
         self._name = name
         self._cat = cat
+        self._ann_name = ann or name
         self._args = None
 
     def set(self, **attrs):
@@ -114,14 +286,32 @@ class _Span:
         return self
 
     def __enter__(self):
+        acct = self._acct = _my_account()
         self._t0 = _pc()
+        if acct is not None:
+            self._saved = acct.child
+            acct.child = 0.0
+        self._ann = _Annotation(self._ann_name)
+        self._ann.__enter__()
         return self
 
-    def __exit__(self, *exc):
-        tr = _ACTIVE.get()
-        if tr is not None:
-            tr.add_event(self._op, self._name, self._cat, self._t0,
-                         _pc() - self._t0, self._args)
+    def __exit__(self, et=None, ev=None, tb=None):
+        self._ann.__exit__(et, ev, tb)
+        dur = self.dur = _pc() - self._t0
+        acct = self._acct
+        if acct is not None:
+            term = _term_of(self._ann_name)
+            if term is None:
+                acct.child += self._saved
+            else:
+                acct.terms[term] += dur - acct.child
+                acct.child = self._saved + dur
+        # a pull that only found its stream ended leaves no event
+        if et is not StopIteration:
+            tr = _ACTIVE.get()
+            if tr is not None:
+                tr.add_event(self._op, self._name, self._cat, self._t0,
+                             dur, self._args)
         return False
 
 
@@ -374,20 +564,21 @@ def query_trace(label: str, enabled: bool = True,
             tr.t_end = _pc()
 
 
-def span(op_id: Optional[str], name: str, cat: str = "phase"):
-    """A timed span context manager, attributed to ``op_id`` (None for
-    query-level work).  Returns the shared no-op span when no trace is
-    active — the off path is one ContextVar read."""
-    if _ACTIVE.get() is None:
-        return NULL_SPAN
-    return _Span(op_id, name, cat)
+def span(op_id: Optional[str], name: str, cat: str = "phase",
+         ann: Optional[str] = None) -> _Span:
+    """THE timed span: a context manager attributed to ``op_id`` (None
+    for query-level work), live at every metrics level and with or
+    without a QueryTrace.  ``name`` is the QueryTrace event's name and,
+    unless ``ann`` gives another, the profiler annotation's: one of
+    :data:`SPANS`."""
+    return _Span(op_id, name, cat, ann)
 
 
 def record(op_id: Optional[str], name: str, cat: str, t0: float,
            dur: float, **args) -> None:
-    """Record an already-measured interval (perf_counter timebase) —
-    for call sites that must time regardless of tracing (QueryStats
-    accounting) and should not read the clock twice."""
+    """Record an interval that someone else measured (perf_counter
+    timebase): the compile listener's, the scheduler's queue wait.
+    Everything the program times itself is a live :func:`span`."""
     tr = _ACTIVE.get()
     if tr is not None:
         tr.add_event(op_id, name, cat, t0, dur, args or None)
@@ -495,6 +686,7 @@ def instrument_batches(op_id: str, op_name: str, metrics,
     ``outputRows`` / ``outputBatches`` / ``outputBytes`` / ``produceTimeS``
     counters accumulate into the operator's MetricSet — the profiled
     EXPLAIN surface, populated for EVERY operator with no opt-out."""
+    ann = "op:" + op_name
     try:
         while True:
             # the engine's universal cancellation checkpoint: every
@@ -503,13 +695,15 @@ def instrument_batches(op_id: str, op_name: str, metrics,
             # on whatever thread is driving it (one ContextVar read when
             # no control is installed)
             _cancel.check()
-            t0 = _pc()
+            sp = _Span(op_id, op_name, "operator", ann)
             try:
-                b = next(it)
+                with sp:
+                    b = next(it)
+                    rows = getattr(b, "num_rows", 0)
+                    if _ACTIVE.get() is not None:
+                        sp.set(rows=rows)
             except StopIteration:
                 return
-            dt = _pc() - t0
-            rows = getattr(b, "num_rows", 0)
             if metrics is not None:
                 v = metrics.values
                 v["outputRows"] += rows
@@ -517,11 +711,7 @@ def instrument_batches(op_id: str, op_name: str, metrics,
                 size_fn = getattr(b, "device_size_bytes", None)
                 if size_fn is not None:
                     v["outputBytes"] += size_fn()
-                v["produceTimeS"] += dt
-            tr = _ACTIVE.get()
-            if tr is not None:
-                tr.add_event(op_id, op_name, "operator", t0, dt,
-                             {"rows": rows})
+                v["produceTimeS"] += sp.dur
             yield b
     finally:
         close = getattr(it, "close", None)

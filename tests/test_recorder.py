@@ -69,37 +69,45 @@ def _ctr(name, label=None):
 # ---------------------------------------------------------------------------------
 
 class TestDecompose:
-    def test_busy_union_merges_overlaps(self):
-        assert recorder._busy_union(
-            [(0.0, 1.0), (0.5, 2.0), (3.0, 4.0)]) == pytest.approx(3.0)
-        assert recorder._busy_union([]) == 0.0
-        # fully nested intervals count once
-        assert recorder._busy_union(
-            [(0.0, 4.0), (1.0, 2.0)]) == pytest.approx(4.0)
-
-    def test_terms_from_attrs_and_events(self):
-        attrs = {"queue_wait_s": 0.2, "compile_s": 0.3,
-                 "h2d_wait_s": 0.1, "fetch_wait_s": 0.05}
+    def test_account_terms_come_from_the_stats_and_close(self):
+        # the snapshot's host-time account is authoritative: operator
+        # events are not re-added (they nest the waits), and the nine
+        # account terms sum to the wall
+        acct = {"acct_plan_s": 0.01, "acct_admit_s": 0.02,
+                "acct_compile_s": 0.3, "acct_h2d_wait_s": 0.1,
+                "acct_fetch_wait_s": 0.05, "acct_dispatch_s": 0.2,
+                "acct_result_s": 0.03, "acct_host_exec_s": 0.25,
+                "acct_unattributed_s": 0.04}
+        attrs = dict(acct, query_wall_s=1.0, queue_wait_s=0.2,
+                     # the all-thread sums are NOT the account's terms
+                     h2d_wait_s=1.9, fetch_wait_s=0.8, compile_s=0.3)
         events = [
-            # two overlapping operator spans on lane 1 -> union 1.5
             ("op:filter", "operator", 0.0, 1.0, 1),
             ("op:agg", "operator", 0.5, 1.0, 1),
-            # a second lane adds its own busy time
-            ("op:scan", "operator", 0.0, 0.5, 2),
             ("dcn:fetch", "shuffle", 0.0, 0.4, 3),
             ("spill:restore", "memory", 0.0, 0.25, 3),
             ("server:stream", "server", 0.0, 0.15, 4),
         ]
         t = recorder.decompose(attrs, events)
         assert t["queue_wait"] == pytest.approx(0.2)
-        assert t["compile"] == pytest.approx(0.3)
         assert t["h2d"] == pytest.approx(0.1)
         assert t["fetch_wait"] == pytest.approx(0.05)
-        assert t["dispatch"] == pytest.approx(2.0)
+        assert t["dispatch"] == pytest.approx(0.2)
         assert t["shuffle"] == pytest.approx(0.4)
         assert t["spill"] == pytest.approx(0.25)
         assert t["stream_spool"] == pytest.approx(0.15)
         assert set(t) == set(recorder.TERMS)
+        account = [term for term, _, _ in recorder._ACCOUNT_FIELDS]
+        assert sum(t[k] for k in account) == pytest.approx(1.0)
+
+    def test_a_dump_without_the_account_falls_back_to_the_stats(self):
+        attrs = {"queue_wait_s": 0.2, "compile_s": 0.3,
+                 "h2d_wait_s": 0.1, "fetch_wait_s": 0.05}
+        t = recorder.decompose(attrs, [("op:agg", "operator", 0.0, 1.0, 1)])
+        assert t["compile"] == pytest.approx(0.3)
+        assert t["h2d"] == pytest.approx(0.1)
+        assert t["fetch_wait"] == pytest.approx(0.05)
+        assert t["dispatch"] == 0.0  # no union of nested operator spans
 
     def test_garbage_attrs_are_zero(self):
         t = recorder.decompose({"compile_s": "not-a-number",

@@ -98,12 +98,14 @@ def test_counters_track_fetches(sess):
     assert s.fetch_bytes > 0
 
 
-def test_async_fetch_excluded_from_budget_but_traced(monkeypatch):
+def test_async_fetch_excluded_from_budget_but_traced():
     """fetch_async resolves outside the blocking budget yet through the
-    same accounting: bytes, wait time, and SRT_SYNC_TRACE attribution."""
-    monkeypatch.setattr(M, "_TRACE_SYNCS", True)
-    M.SYNC_TRACE.clear()
-    with sync_budget(0, "async-only"):  # zero BLOCKING fetches allowed
+    same accounting: bytes, wait time, and the fetch span's call-site
+    attribute when a QueryTrace is active."""
+    from spark_rapids_tpu.utils import tracing
+    with sync_budget(0, "async-only"), \
+            tracing.query_trace("async-only") as tr:
+        # zero BLOCKING fetches allowed
         fut = M.fetch_async(jnp.arange(1024, dtype=jnp.int64))
         vals = fut.result()
         assert vals.shape == (1024,)
@@ -113,11 +115,13 @@ def test_async_fetch_excluded_from_budget_but_traced(monkeypatch):
     assert s.async_fetches == 1
     assert s.fetch_bytes >= 1024 * 8
     assert s.fetch_wait_s >= 0.0
-    # traced with the async tag and the fetch_async call site
-    assert len(M.SYNC_TRACE) == 1
-    site, _dt = M.SYNC_TRACE[0]
-    assert site.startswith("async|")
-    assert "test_sync_budget" in site
+    # one fetch span, async, carrying the fetch_async call site
+    fetches = [e for e in tr.events if e[2] == "fetch"
+               and e[1] != "fetch:start_copies"]
+    assert [e[1] for e in fetches] == ["fetch:async"]
+    args = fetches[0][6]
+    assert args["blocking"] is False and args["bytes"] >= 1024 * 8
+    assert "test_sync_budget" in args["site"]
     # resolving twice must not double-count
     fut.result()
     assert QueryStats.get().async_fetches == 1

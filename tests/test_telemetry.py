@@ -613,15 +613,6 @@ class TestDropAccountingAndOverhead:
         a = trace_report.analyze(tr.to_chrome())
         assert "TRUNCATED" in trace_report.format_report(a)
 
-    def test_sync_trace_drop_gauge(self, monkeypatch):
-        from spark_rapids_tpu.utils import metrics as M
-        monkeypatch.setattr(M, "_SYNC_TRACE_DROPPED", [0])
-        monkeypatch.setattr(M, "SYNC_TRACE_MAX", 1)
-        monkeypatch.setattr(M, "SYNC_TRACE", ["x"])
-        M._sync_trace_append(("y", 0.1))
-        snap = telemetry.snapshot()
-        assert snap["sync_trace_dropped"][""] == 1.0
-
     @pytest.mark.parametrize("iters", [4])
     def test_disabled_telemetry_costs_nothing_measurable(self, session,
                                                          iters):

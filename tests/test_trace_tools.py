@@ -529,3 +529,86 @@ class TestDebugSlowSurfaces:
             in out
         assert cap.capture_id in out
         assert "compile" in out
+
+
+# ---------------------------------------------------------------------------------
+# trace_report --xplane: the reduction, on a hand-made event list
+# ---------------------------------------------------------------------------------
+
+class TestXplaneReduce:
+    DEV, HOST = "/device:TPU:0", "/host:CPU"
+    MS = 1_000_000
+
+    def _rows(self):
+        ms, dev, host = self.MS, self.DEV, self.HOST
+        seg = "jit(agg_grouped)/jit(main)/segmented_reduce/scatter-add"
+        srt = "jit(agg_grouped)/jit(main)/groupby_sort/sort"
+        body = "jit(join_expand)/jit(main)/while/body/add"
+        return [
+            # two programs; the id in brackets differs per launch
+            (dev, "XLA Modules", "jit_agg_grouped(7)", 0, 40 * ms, ""),
+            (dev, "XLA Modules", "jit_agg_grouped(9)", 100 * ms, 20 * ms, ""),
+            (dev, "XLA Modules", "jit_join_expand(3)", 60 * ms, 10 * ms, ""),
+            # ops: a while holding a nested fusion counts its own time only
+            (dev, "XLA Ops", "fusion.1", 0, 30 * ms, seg),
+            (dev, "XLA Ops", "sort.2", 30 * ms, 10 * ms, srt),
+            (dev, "XLA Ops", "while.3", 60 * ms, 10 * ms,
+             "jit(join_expand)/jit(main)/while"),
+            (dev, "XLA Ops", "fusion.4", 62 * ms, 4 * ms, body),
+            (dev, "XLA Ops", "fusion.5", 100 * ms, 20 * ms, seg),
+            # host spans: the 40..60 gap sits under a fetch inside an op
+            # pull; the 70..100 gap under planning only
+            (host, "t1", "op:SortExec", 35 * ms, 30 * ms, ""),
+            (host, "t1", "fetch:blocking", 41 * ms, 18 * ms, ""),
+            (host, "t1", "plan:overrides", 72 * ms, 26 * ms, ""),
+            (host, "t1", "bench:q3:run", 0, 120 * ms, ""),   # two colons
+            (host, "t1", "$profiler.py:91 trace", 0, 120 * ms, ""),
+        ]
+
+    def test_programs_scopes_and_gaps(self):
+        r = trace_report.reduce_xplane(self._rows(), top=5)
+        assert dict(r["device_s_by_program"]) == pytest.approx(
+            {"jit_agg_grouped": 0.060, "jit_join_expand": 0.010})
+        scopes = dict(r["device_s_by_scope"])
+        assert scopes["segmented_reduce"] == pytest.approx(0.050)
+        assert scopes["groupby_sort"] == pytest.approx(0.010)
+        assert scopes["(none)"] == pytest.approx(0.006)   # the while's own
+        assert scopes["body"] == pytest.approx(0.004)
+        assert r["busy_s"] == pytest.approx(0.070)
+        assert r["span_s"] == pytest.approx(0.120)
+        assert [(round(g["seconds"], 3), g["span"])
+                for g in r["idle_gaps"]] == [
+            (0.030, "plan:overrides"), (0.020, "fetch:blocking")]
+        assert r["program_spans"] == 3
+        out = trace_report.format_xplane(r)
+        assert "jit_agg_grouped" in out and "segmented_reduce" in out
+
+    def test_a_host_only_trace_reads_as_no_device(self):
+        rows = [r for r in self._rows() if r[0] == self.HOST]
+        r = trace_report.reduce_xplane(rows)
+        assert r["busy_s"] == 0 and not r["device_s_by_program"]
+        assert "not a device's trace" in trace_report.format_xplane(r)
+
+    def test_scope_of(self):
+        assert trace_report.scope_of(
+            "jit(f)/jit(main)/segmented_reduce/scatter-add") \
+            == "segmented_reduce"
+        assert trace_report.scope_of("jit(f)/jit(main)/add") == "(none)"
+        assert trace_report.scope_of("") == "(none)"
+
+    def test_main_reads_a_profiler_directory(self, sess, tmp_path, capsys):
+        import jax
+        df = sess.create_dataframe({"k": np.arange(2000) % 5,
+                                    "v": np.arange(2000) * 0.5})
+        q = df.group_by("k").agg(F.sum(F.col("v")).alias("s"))
+        q.collect()
+        with jax.profiler.trace(str(tmp_path)):
+            q.collect()
+        assert trace_report.main(["--xplane", str(tmp_path)]) == 0
+        out = capsys.readouterr().out
+        # on the CPU backend there is no device plane, but the engine's
+        # spans are in the trace under their vocabulary names
+        assert "program span(s) on the host planes" in out
+        names = {ev.name for _, _, ev in
+                 trace_report.xplane_events(str(tmp_path))}
+        assert "plan:overrides" in names and "result:rows" in names

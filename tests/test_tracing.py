@@ -208,8 +208,11 @@ def test_tracing_off_stays_on_fast_path(fresh_session):
     # no trace captured, no active trace leaked
     assert fresh_session.last_trace() is None
     assert tracing.active() is None
-    # the off-path primitives are allocation-free no-ops
-    assert tracing.span("x", "y") is tracing.NULL_SPAN
+    # with no trace a span still times (the account and the profiler
+    # read it) and leaves no event; record/mark are no-ops
+    with tracing.span("x", "op:y") as sp:
+        pass
+    assert sp.dur >= 0.0
     tracing.record("x", "y", "phase", 0.0, 1.0)  # no-op, no error
     tracing.mark("x", "y")
 
@@ -325,22 +328,3 @@ def test_querystats_nested_scopes_fold_outward():
             assert inner.blocking_fetches == 1
             assert outer.blocking_fetches == 0
         assert outer.blocking_fetches == 1
-
-
-# ---------------------------------------------------------------------------------
-# SYNC_TRACE cap
-# ---------------------------------------------------------------------------------
-
-def test_sync_trace_capped(monkeypatch):
-    import jax.numpy as jnp
-
-    from spark_rapids_tpu.utils import metrics as M
-
-    monkeypatch.setattr(M, "_TRACE_SYNCS", True)
-    monkeypatch.setattr(M, "SYNC_TRACE_MAX", 3)
-    monkeypatch.setattr(M, "SYNC_TRACE", [])
-    monkeypatch.setattr(M, "_SYNC_TRACE_DROPPED", [0])
-    for _ in range(7):
-        M.fetch(jnp.arange(4))
-    assert len(M.SYNC_TRACE) == 3
-    assert M.sync_trace_dropped() == 4

@@ -36,13 +36,26 @@ statement fingerprint's EWMA baseline, the dominant anomalous term
 named — the same analysis ``tools/explain_slow.py`` runs standalone
 (traces sealed by ``utils/recorder.py`` carry it pre-stamped).
 
+A profiler trace (``--xplane <dir>``): the engine's spans enter a
+``jax.profiler.TraceAnnotation`` under their vocabulary names
+(``utils/tracing.SPANS``), so ``with jax.profiler.trace(d):
+df.collect()`` puts them in the same ``.xplane.pb`` as the device's
+operations, on one clock.  ``--xplane d`` reads the newest trace under
+``d`` with ``jax.profiler.ProfileData`` and prints device seconds by
+jitted program (the ``XLA Modules`` line: ``jit_<program name>``), by
+named scope (``segmented_reduce``, ``groupby_sort``, ...), and the
+longest device-idle gaps, each labelled with the innermost program span
+whose interval covers its middle.
+
 Usage: ``python tools/trace_report.py [--stitch] [--why] TRACE.json [...]``
+       ``python tools/trace_report.py --xplane TRACE_DIR [--top N]``
 """
 
 from __future__ import annotations
 
 import json
 import os
+import re
 import sys
 from typing import Dict, List
 
@@ -619,7 +632,183 @@ def why_file(data: dict) -> str:
         for sub in subs)
 
 
+# ---------------------------------------------------------------------------------
+# --xplane: a jax.profiler trace directory (device operations AND the
+# engine's spans, on the profiler's clock)
+# ---------------------------------------------------------------------------------
+
+MODULES_LINE = "XLA Modules"
+OPS_LINE = "XLA Ops"
+_SPAN_NAME = re.compile(r"[A-Za-z_][\w.#-]*:[\w.#-]+")
+# where a device operation's trace event says which jax op it came from
+# (``jit(agg_grouped)/jit(main)/segmented_reduce/scatter-add``): a stat
+# of the event on some backends, else the ``op_name`` of the HLO line
+# the TPU's trace names the event by
+_SCOPE_STATS = ("tf_op", "long_name")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def _is_device_plane(name: str) -> bool:
+    return name.startswith("/device:") and "CPU" not in name.upper()
+
+
+def xplane_events(trace_dir: str):
+    """``(plane name, line name, event)`` of the newest ``.xplane.pb``
+    under ``trace_dir``, with nothing but JAX."""
+    import glob
+
+    from jax.profiler import ProfileData
+    found = sorted(glob.glob(os.path.join(
+        trace_dir, "**", "*.xplane.pb"), recursive=True),
+        key=os.path.getmtime)
+    if not found:
+        raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
+    for plane in ProfileData.from_file(found[-1]).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                yield plane.name, line.name, ev
+
+
+def _scope_path(ev) -> str:
+    try:
+        stats = dict(ev.stats)
+    except Exception:  # an event without readable stats has no scope
+        return ""
+    for key in _SCOPE_STATS:
+        v = stats.get(key)
+        if isinstance(v, str) and v:
+            return v
+    found = _OP_NAME.search(ev.name)
+    return found.group(1) if found else ""
+
+
+def xplane_rows(trace_dir: str) -> List[tuple]:
+    """The trace as plain rows ``(plane, line, name, start_ns, dur_ns,
+    scope path)``: what :func:`reduce_xplane` works on, so that a
+    hand-made list tests it."""
+    rows = []
+    for plane, line, ev in xplane_events(trace_dir):
+        device = _is_device_plane(plane)
+        if not device and not _SPAN_NAME.fullmatch(ev.name):
+            continue
+        rows.append((plane, line, ev.name, int(ev.start_ns),
+                     int(ev.duration_ns),
+                     _scope_path(ev) if device and line == OPS_LINE
+                     else ""))
+    return rows
+
+
+def scope_of(path: str) -> str:
+    """The innermost named scope of a jax op path: its components less
+    the ``jit(...)`` wrappers and the primitive's own name."""
+    parts = [c for c in path.split("/")[:-1]
+             if c and not c.startswith(("jit(", "pjit("))]
+    return parts[-1] if parts else "(none)"
+
+
+def _merged(intervals) -> List[List[int]]:
+    out: List[List[int]] = []
+    for lo, hi in sorted(intervals):
+        if out and lo <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], hi)
+        else:
+            out.append([lo, hi])
+    return out
+
+
+def reduce_xplane(rows, top: int = 10) -> dict:
+    """Device seconds by program and by named scope, and the ``top``
+    longest device-idle gaps by the program span that covers them.
+
+    - a program's seconds are its events' on the ``XLA Modules`` line of
+      a device plane, under the module's name less its ``(<id>)``;
+    - a scope's seconds are those of the ``XLA Ops`` events whose op
+      path names it innermost, each event's OWN time (a ``while`` less
+      the operations nested in it);
+    - busy is the union of the ``XLA Ops`` intervals of one device, an
+      idle gap the space between two of them; a gap's label is the
+      shortest host span of the ``<layer>:<name>`` form that covers its
+      middle, else ``(no span)``."""
+    by_program: Dict[str, float] = {}
+    by_scope: Dict[str, float] = {}
+    ops: Dict[str, list] = {}
+    spans = []
+    for plane, line, name, start, dur, path in rows:
+        if not _is_device_plane(plane):
+            if _SPAN_NAME.fullmatch(name):
+                spans.append((start, start + dur, name))
+            continue
+        if line == MODULES_LINE:
+            prog = re.sub(r"\(\d+\)$", "", name)
+            by_program[prog] = by_program.get(prog, 0.0) + dur / 1e9
+        elif line == OPS_LINE:
+            ops.setdefault(plane, []).append((start, start + dur, path))
+    busy_ns = span_ns = 0
+    gaps = []
+    for plane, evs in sorted(ops.items()):
+        # own time: an enclosing op (a while) less what nests in it
+        open_: List[list] = []  # [end, path, own ns]
+        for lo, hi, path in sorted(evs, key=lambda e: (e[0], -e[1])):
+            while open_ and open_[-1][0] <= lo:
+                end, p, own = open_.pop()
+                by_scope[scope_of(p)] = by_scope.get(scope_of(p), 0.0) \
+                    + own / 1e9
+            if open_:
+                open_[-1][2] -= hi - lo
+            open_.append([hi, path, hi - lo])
+        for end, p, own in open_:
+            by_scope[scope_of(p)] = by_scope.get(scope_of(p), 0.0) \
+                + own / 1e9
+        merged = _merged((lo, hi) for lo, hi, _ in evs)
+        busy_ns += sum(hi - lo for lo, hi in merged)
+        span_ns += merged[-1][1] - merged[0][0]
+        for (_, a), (b, _) in zip(merged, merged[1:]):
+            gaps.append((b - a, a, b, plane))
+    gaps.sort(reverse=True)
+    idle = []
+    for length, a, b, plane in gaps[:top]:
+        mid = (a + b) // 2
+        cover = [(hi - lo, name) for lo, hi, name in spans
+                 if lo <= mid < hi]
+        idle.append({"seconds": length / 1e9, "plane": plane,
+                     "span": min(cover)[1] if cover else "(no span)"})
+    order = lambda d: sorted(d.items(), key=lambda kv: -kv[1])[:top]
+    return {"busy_s": busy_ns / 1e9, "span_s": span_ns / 1e9,
+            "idle_s": (span_ns - busy_ns) / 1e9,
+            "device_s_by_program": order(by_program),
+            "device_s_by_scope": order(by_scope),
+            "idle_gaps": idle,
+            "program_spans": len(spans)}
+
+
+def format_xplane(r: dict) -> str:
+    lines = [f"device busy {r['busy_s']:.3f} s of {r['span_s']:.3f} s "
+             f"(idle {r['idle_s']:.3f} s); {r['program_spans']} program "
+             f"span(s) on the host planes",
+             "device seconds by program (XLA Modules):"]
+    lines += [f"  {v:9.4f}  {k}" for k, v in r["device_s_by_program"]] \
+        or ["  (no XLA Modules line: not a device's trace)"]
+    lines.append("device seconds by named scope (XLA Ops, own time):")
+    lines += [f"  {v:9.4f}  {k}" for k, v in r["device_s_by_scope"]] \
+        or ["  (no XLA Ops line)"]
+    lines.append("longest device-idle gaps, by the program span over "
+                 "their middle:")
+    lines += [f"  {g['seconds']:9.4f}  {g['span']}"
+              for g in r["idle_gaps"]] or ["  (none)"]
+    return "\n".join(lines)
+
+
 def main(argv: List[str]) -> int:
+    if "--xplane" in argv:
+        i = argv.index("--xplane")
+        top = int(argv[argv.index("--top") + 1]) if "--top" in argv \
+            else 10
+        if i + 1 >= len(argv):
+            print(__doc__, file=sys.stderr)
+            return 2
+        print(format_xplane(reduce_xplane(xplane_rows(argv[i + 1]),
+                                          top=top)))
+        return 0
     do_stitch = False
     do_why = False
     paths: List[str] = []
