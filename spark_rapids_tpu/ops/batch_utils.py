@@ -7,6 +7,9 @@ without reading device data; the selection masks ride along.  Compaction
 (gathering live rows to the front) is the one place a device→host sync may
 happen, because the new ``num_rows`` must become a static Python int — the
 same boundary where the reference synchronizes to build output batches.
+On the device a compaction is one program (``_compact_program``): it finds
+the source row of each output slot once and gathers every array by it, so
+its cost follows the slots it fills, not the padded rows it reads.
 """
 
 from __future__ import annotations
@@ -189,13 +192,11 @@ def compact(batch: ColumnBatch, align_host_strings: bool = False,
             n_live = fetch_scalars(jnp.sum(active))[0]
     elif needs_mask:
         host_mask = fetch(active)
-    # stable compaction WITHOUT a sort: every live row's destination is
-    # cumsum(active)-1, so one cumsum + a per-column scatter (mode=drop
-    # swallows dead rows) packs the batch — and the WHOLE compact (all
-    # device columns) runs as ONE cached jitted program: the previous
-    # eager version compiled a tiny cumsum/where/scatter program per
-    # column per shape (a third of q13's 84 cold compiles) and paid a
-    # dispatch per op.
+    # stable compaction WITHOUT a sort: the (j+1)-th live row is the
+    # first row where cumsum(active) reaches j+1, so one cumsum gives the
+    # source row of every output slot and each column is one gather —
+    # and the WHOLE compact (all device columns) runs as ONE cached
+    # jitted program, not a dispatch per column.
     new_cap = bucket_capacity(max(n_live, min_capacity))
     dev_inputs = []   # (data, valid) in column order, None for host cols
     spec = []
@@ -210,9 +211,10 @@ def compact(batch: ColumnBatch, align_host_strings: bool = False,
             dev_inputs.append((c.data, c.valid))
             spec.append(("d", c.data.dtype.name, c.valid is not None,
                          tuple(c.data.shape[1:])))
-    outs = _compact_fn(batch.capacity, new_cap, tuple(spec),
-                       batch.sel is not None)(
-        tuple(dev_inputs), batch.sel, np.int32(batch.num_rows))
+    outs = _compact_program(
+        _compact_form(batch.capacity, new_cap), batch.capacity, new_cap,
+        tuple(spec), batch.sel is not None)(
+            tuple(dev_inputs), batch.sel, np.int32(batch.num_rows))
     cols = []
     oi = 0
     for (kind, _dt, _hv, _extra), c, f in zip(spec, batch.columns,
@@ -290,30 +292,67 @@ def _concat_fn(caps: tuple, out_cap: int, col_kind: tuple, spec: tuple,
     return f
 
 
+# The two ways _compact_program finds ``src`` (the source row of each
+# output slot), each under its own program name so a device trace, the
+# ``program:<name>`` events and the compile ledger say which one ran.
+COMPACT_FORMS = ("batch_compact", "batch_compact_scatter")
+
+# What the two cost on one TPU v5e, in ns (PERF.md section 6, PR 27's
+# grid): a step of the binary search is a new_cap-long gather out of a
+# cap-long table, dearer once the table is past 2^23 rows (32 MB); the
+# scatter walks every source row once, live or not.
+_SEARCH_STEP_NS = 7.8
+_SEARCH_STEP_NS_PAST_2_23 = 19.0
+_SCATTER_ROW_NS = 5.2
+
+
+def _compact_form(cap: int, new_cap: int) -> str:
+    """Which form compacts ``cap`` rows into ``new_cap`` slots: search
+    where the slots are few against the rows (an aggregation's 100
+    groups out of a 16.7M-slot table), one scatter where most rows stay
+    (a filter, a join's output).  A pure function of what is static at
+    trace time; the arrays do not enter, both forms gather each alike."""
+    step_ns = _SEARCH_STEP_NS if cap <= 1 << 23 \
+        else _SEARCH_STEP_NS_PAST_2_23
+    search_ns = new_cap * cap.bit_length() * step_ns
+    return COMPACT_FORMS[search_ns >= cap * _SCATTER_ROW_NS]
+
+
 @functools.lru_cache(maxsize=512)
-def _compact_fn(cap: int, new_cap: int, spec: tuple, has_sel: bool):
-    """One jitted program compacting EVERY device column of a batch."""
+def _compact_program(form: str, cap: int, new_cap: int, spec: tuple,
+                     has_sel: bool):
+    """One jitted program compacting EVERY device column of a batch:
+    the source row of each output slot is found ONCE (``src[j]`` = position of the (j+1)-th live row, ``cap`` for the
+    slots past the last), then every array is gathered by it; the slots
+    past the live rows read zeros and ``False``."""
 
     from ..plan.physical import program
 
-    @program("batch_compact")
+    @program(form)
     def f(cols, sel, num_rows):
         active = jnp.arange(cap, dtype=jnp.int32) < num_rows
         if sel is not None:
             active = active & sel
-        dest = jnp.cumsum(active.astype(jnp.int32)) - 1
-        scatter_idx = jnp.where(active, dest, new_cap)
+        csum = jnp.cumsum(active.astype(jnp.int32))
+        if form == "batch_compact":
+            # log2(cap) steps of a new_cap-long gather
+            src = jnp.searchsorted(
+                csum, jnp.arange(1, new_cap + 1, dtype=jnp.int32),
+                side="left")
+        else:
+            # one cap-long scatter for the whole batch
+            src = jnp.full((new_cap,), cap, dtype=jnp.int32).at[
+                jnp.where(active, csum - 1, new_cap)].set(
+                    jnp.arange(cap, dtype=jnp.int32), mode="drop")
         outs = []
-        for (kind, _dt, _hv, extra), dv in zip(spec, cols):
+        for (kind, _dt, _hv, _extra), dv in zip(spec, cols):
             if kind == "h":
                 continue
             data, valid = dv
-            od = jnp.zeros((new_cap,) + extra, dtype=data.dtype).at[
-                scatter_idx].set(data, mode="drop")
+            od = jnp.take(data, src, axis=0, mode="fill", fill_value=0)
             ov = None
             if valid is not None:
-                ov = jnp.zeros((new_cap,), dtype=bool).at[
-                    scatter_idx].set(valid, mode="drop")
+                ov = jnp.take(valid, src, mode="fill", fill_value=False)
             outs.append((od, ov))
         return tuple(outs)
 
@@ -324,8 +363,8 @@ def compact_packed(batch: ColumnBatch,
                    bound: Optional[int] = None) -> ColumnBatch:
     """Compact a batch whose LIVE ROWS ARE ALREADY FRONT-PACKED (the
     selection mask is a prefix mask, e.g. group_reduce outputs): one mask
-    sum + a slice, instead of compact()'s full lexsort + gather — on this
-    hardware a 2M-row sort pass costs ~100ms.
+    sum + a slice, instead of compact()'s cumsum over every padded row,
+    search for the live ones and gather.
 
     With ``bound`` (a static upper limit on live rows, e.g. the dense-grid
     group count), the compaction is SYNC-FREE: a static slice to the

@@ -372,7 +372,8 @@ PROGRAM_NAMES = frozenset((
     "agg_grouped", "agg_grid", "agg_passthrough", "agg_sample",
     "agg_bucket_pid", "agg_finalize", "agg_merge_grouped",
     "sort", "sort_range_key", "generate_gather", "expand_project",
-    "exchange_pid", "batch_concat", "batch_compact", "batch_slice",
+    "exchange_pid", "batch_concat", "batch_compact",
+    "batch_compact_scatter", "batch_slice",
     "smj_filter_stats", "smj_filter_vals", "join_subpid",
     "join_cond_expand", "join_cond", "join_residual", "join_match",
     "join_expand", "join_unmatched", "bjoin_sort", "bjoin_probe",
