@@ -533,9 +533,10 @@ ICI_DEVICES = register(
 ICI_BUCKET_ROWS = register(
     "spark.rapids.tpu.shuffle.ici.bucketRows", 0,
     "Per-destination send-bucket rows for an ICI all_to_all exchange "
-    "(0 = auto: the sender's full shard capacity, which can never "
-    "overflow but costs n_devices x shard HBM on the receive side). Set "
-    "explicitly at scale; overflow is detected and raised, never dropped.")
+    "(0 = auto: the capacity-ladder rung over the rows counted for the "
+    "fullest bucket, read once per level of exchanges, which can never "
+    "overflow). An explicit value is held to; rows counted past it are "
+    "detected and raised, never dropped.")
 
 ICI_JOIN_OUT_ROWS = register(
     "spark.rapids.tpu.shuffle.ici.joinOutputRows", 0,

@@ -39,7 +39,9 @@ def bucketize(pids: jax.Array, active: jax.Array, n_parts: int,
     """
     capacity = pids.shape[0]
     pid_sortable = jnp.where(active, pids, n_parts)  # inactive rows last
-    perm = jnp.argsort(pid_sortable, stable=True)
+    # a bucket's rows in any order (an unstable sort compiles in 15 s for
+    # the TPU, a stable one in 39 s at 1M rows)
+    perm = jnp.argsort(pid_sortable, stable=False)
     s_pid = pid_sortable[perm]
     s_active = s_pid < n_parts
     # position of each (sorted) row within its partition

@@ -3,26 +3,48 @@
 The reference serves *every* exchange in *every* plan through its shuffle
 manager (RapidsShuffleInternalManagerBase.scala:1046,
 GpuShuffleExchangeExecBase.scala:266-383).  The TPU-native equivalent is not
-a transport: a plan *fragment* containing exchanges is lowered into ONE
-jitted ``shard_map`` program where each ShuffleExchangeExec becomes a
-bucketize + ``lax.all_to_all`` over ICI (parallel/exchange.py), and the
-operators between exchanges (fused stages, partial/final aggregates,
-shuffled sort-merge joins) run per device shard with static shapes.
+a transport: a plan *fragment* containing exchanges is lowered onto the
+mesh, where each ShuffleExchangeExec becomes a bucketize +
+``lax.all_to_all`` over ICI (parallel/exchange.py), and the operators
+between exchanges (fused stages, partial/final aggregates, shuffled
+sort-merge joins) run per device shard with static shapes.
 
 Dataflow per query:
 
   1. ``distribute_plan`` finds the topmost lowerable subtree that contains
      at least one exchange (the *fragment*).
-  2. Non-lowerable subtrees under it become *leaves*: materialized to host
-     Arrow via the normal single-process executor, then sharded row-wise
-     across the mesh (strings ride as fragment-wide dictionary codes).
-  3. The fragment is traced into one SPMD step and executed on the mesh;
-     overflow of any fixed-capacity exchange bucket or join expansion is
-     detected and raised (the caller can raise the capacity confs), never
-     silently dropped.
-  4. The gathered result replaces the fragment as an in-memory scan; the
-     remaining plan (global sort, limit, writes, ...) runs on the normal
-     executor.  Repeat until no lowerable fragment remains.
+  2. Non-lowerable subtrees under it become *leaves*: run by the normal
+     single-process executor and brought to host Arrow (``ici:materialize``),
+     then padded to ``n_devices x cap`` rows (``cap`` the capacity-ladder
+     rung over a device's share of the rows; a broadcast build side rides
+     whole, replicated) and placed on the mesh through the counted
+     ``utils/metrics.upload`` (``ici:feed``).  Strings ride as
+     fragment-wide dictionary codes.
+  3. The fragment runs as a short series of ``shard_map`` *steps*
+     (``ici:step``), one per level of exchanges.  A step ends where an
+     exchange's bucket size is not known yet: it leaves that exchange's
+     input rows on the mesh with their destinations and returns the rows
+     counted per destination; the host reads the counts (one small
+     blocking fetch a step), sizes the send bucket to the capacity-ladder
+     rung over the fullest one, and the next step starts with the
+     all_to_all.  So a bucket holds what is sent, not the sender's whole
+     capacity, and capacities shrink with the rows instead of growing
+     ``n_devices`` times per exchange.  Every step is one program
+     (``ici_fragment_step``) kept in the process-wide program cache under
+     what it is traced from: the plan fingerprints of the operators it
+     runs, every static capacity, and the mesh.  The second run of a query
+     traces and compiles nothing.
+  4. An explicit ``shuffle.ici.bucketRows`` that the counted rows pass, or
+     a join expansion past its static capacity, is detected and raised,
+     never silently dropped; ``distribute_plan`` retries the fragment at
+     4x those capacities (a different cache key, not a different
+     mechanism).
+  5. The last step's outputs are cut, on the mesh, to the ladder rung over
+     the fullest device's last live row (``ici_fragment_gather``) and
+     brought to a host table through the counted fetch (``ici:gather``).
+     It replaces the fragment as an in-memory scan; the remaining plan
+     (global sort, limit, writes, ...) runs on the normal executor.
+     Repeat until no lowerable fragment remains.
 
 Unsupported-but-present exchanges are a hard error unless
 ``spark.rapids.tpu.shuffle.ici.fallback`` is set — a user asking for ICI
@@ -31,8 +53,9 @@ must never silently get single-process shuffle (round-2 verdict, weak #2).
 
 from __future__ import annotations
 
+import contextlib
 import logging
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List
 
 import numpy as np
 
@@ -49,12 +72,29 @@ class NotLowerable(Exception):
 # Lowered-node tree
 # ---------------------------------------------------------------------------------
 
-class _Leaf:
-    """A subtree materialized on host and sharded across the mesh."""
+def _children(node) -> tuple:
+    if isinstance(node, _Join):
+        return (node.left, node.right)
+    child = getattr(node, "child", None)
+    return () if child is None else (child,)
 
-    def __init__(self, phys, index: int):
-        self.phys = phys
-        self.schema = phys.output_schema
+
+def _schema_sig(schema) -> str:
+    return ",".join(f"{f.name}:{f.dtype}" for f in schema)
+
+
+# A lowered node keeps what its ``emit`` traces (bound expressions, schemas,
+# capacities) and never the physical operator it was made from: a step's
+# program lives on in the process-wide program cache, and an operator
+# reaches the whole plan under it, scans over host tables included.
+
+class _Leaf:
+    """A subtree materialized on host and sharded across the mesh.  The
+    subtree itself rides beside its leaf in ``_lower``'s ``leaves`` list,
+    as ``(leaf, plan)``."""
+
+    def __init__(self, schema, index: int):
+        self.schema = schema
         self.index = index        # position in the feed argument list
         self.cap = None           # per-device rows, set after materialize
         # replicated leaves (broadcast build sides) feed every device the
@@ -64,6 +104,10 @@ class _Leaf:
     def resolve(self):
         assert self.cap is not None, "leaf not materialized"
 
+    def fingerprint(self) -> str:
+        return (f"leaf{self.index}[{self.cap}{'r' if self.replicated else ''}]"
+                f"({_schema_sig(self.schema)})")
+
     def emit(self, env):
         arrays, active = env[self.index]
         return list(arrays), active
@@ -71,7 +115,8 @@ class _Leaf:
 
 class _Stage:
     def __init__(self, stage, child):
-        self.stage = stage
+        self.steps = stage.steps
+        self._sig = stage.fingerprint()
         self.child = child
         self.schema = stage.output_schema
         self.cap = None
@@ -80,13 +125,15 @@ class _Stage:
         self.child.resolve()
         self.cap = self.child.cap
 
+    def fingerprint(self) -> str:
+        return f"stage({self._sig})<{self.child.fingerprint()}>"
+
     def emit(self, env):
-        import jax.numpy as jnp
         from ..exprs import EvalContext
         arrays, active = self.child.emit(env)
         cap = active.shape[0]
         cur = list(arrays)
-        for kind, payload in self.stage.steps:
+        for kind, payload in self.steps:
             ectx = EvalContext(cur, cap, active=active)
             if kind == "filter":
                 d, v = payload.eval(ectx)
@@ -109,45 +156,86 @@ class _Exchange:
     """ShuffleExchangeExec → bucketize + all_to_all over the mesh axis.
 
     Partitioning is by device (pid = murmur3(keys) % n_devices), preserving
-    the invariant every consumer relies on: equal keys are colocated."""
+    the invariant every consumer relies on: equal keys are colocated.
+
+    It runs in two halves, a step apart.  ``emit_send`` ends the step
+    below: the child's rows stay on the mesh with their destinations and
+    the rows per destination go to the host, which sizes the send bucket
+    (``size``).  ``emit`` opens the step above with the all_to_all."""
+
+    OVERFLOW = "exchange bucket (spark.rapids.tpu.shuffle.ici.bucketRows)"
 
     def __init__(self, exch, child, n_dev: int, axis: str, bucket_rows: int,
                  cap_scale: int = 1):
-        self.exch = exch
+        self.key_exprs = exch.key_exprs
         self.child = child
         self.schema = exch.output_schema
         self.n_dev = n_dev
         self.axis = axis
-        self._bucket_rows = bucket_rows
-        self._cap_scale = cap_scale
-        self.bucket_cap = None
+        # an explicit bucketRows (times the overflow-retry escalation of
+        # distribute_plan) is held to; 0 sizes the bucket from the count
+        self._fixed_rows = bucket_rows * cap_scale
+        self.index = None      # among the fragment's exchanges, emit order
+        self.bucket_cap = None  # set by size(), once the send half ran
         self.cap = None
 
-    def resolve(self):
-        self.child.resolve()
-        # auto: a device holds at most child.cap active rows, so a bucket
-        # of child.cap can never overflow (memory-heavy but always correct;
-        # set shuffle.ici.bucketRows to bound it at scale).  cap_scale > 1
-        # is the overflow-retry escalation (distribute_plan).
-        self.bucket_cap = (self._bucket_rows * self._cap_scale
-                           if self._bucket_rows > 0 else self.child.cap)
+    @property
+    def staged(self) -> bool:
+        """The send half ran: rows and destinations are on the mesh."""
+        return self.bucket_cap is not None
+
+    def size(self, need: int) -> None:
+        """``need``: the most rows any device sends to one destination."""
+        from ..batch import bucket_capacity
+        if self._fixed_rows > 0:
+            if need > self._fixed_rows:
+                raise ICICapacityOverflow(
+                    f"{self.OVERFLOW}: {need - self._fixed_rows} rows")
+            self.bucket_cap = self._fixed_rows
+        else:
+            self.bucket_cap = bucket_capacity(max(1, need), min_capacity=8)
         self.cap = self.n_dev * self.bucket_cap
 
-    def emit(self, env):
+    def resolve(self):
+        # reached from the step above only: the child resolved in its own
+        assert self.staged, "exchange not sized"
+
+    def send_fingerprint(self) -> str:
+        keys = ";".join(e.fingerprint() for e in self.key_exprs)
+        return f"send{self.n_dev}({keys})<{self.child.fingerprint()}>"
+
+    def fingerprint(self) -> str:
+        return (f"recv{self.index}[{self.child.cap}->{self.n_dev}x"
+                f"{self.bucket_cap}]({_schema_sig(self.schema)})")
+
+    def emit_send(self, env):
+        """((flat data/validity arrays, active, pids), rows per
+        destination) of this device's shard."""
+        import jax
         import jax.numpy as jnp
         from ..exprs import EvalContext
-        from .exchange import bucketize, exchange
+        from ..ops.hashing import spark_partition_id
         arrays, active = self.child.emit(env)
         cap = active.shape[0]
         ectx = EvalContext(list(arrays), cap, active=active)
-        kvs = [e.eval(ectx) for e in self.exch.key_exprs]
-        from ..ops.hashing import spark_partition_id
+        kvs = [e.eval(ectx) for e in self.key_exprs]
         pids = spark_partition_id(kvs, self.n_dev)
         flat = []
         for d, v in arrays:
             flat.append(d)
             flat.append(jnp.ones_like(d, dtype=jnp.bool_) if v is None else v)
-        bucketed, sent, overflow = bucketize(
+        counts = jax.ops.segment_sum(
+            active.astype(jnp.int32), jnp.where(active, pids, self.n_dev),
+            num_segments=self.n_dev + 1)[:self.n_dev]
+        return (tuple(flat), active, pids), counts
+
+    def emit(self, env):
+        import jax.numpy as jnp
+        from .exchange import bucketize, exchange
+        flat, active, pids = env["staged"][self.index]
+        # the bucket was sized from the count (or checked against it), so
+        # bucketize's own overflow count is zero
+        bucketed, sent, _ = bucketize(
             pids, active, self.n_dev, self.bucket_cap, flat)
         recv, recv_counts = exchange(self.axis, bucketed, sent)
         total = self.n_dev * self.bucket_cap
@@ -156,9 +244,6 @@ class _Exchange:
         out = []
         for i in range(0, len(recv), 2):
             out.append((recv[i].reshape(total), recv[i + 1].reshape(total)))
-        env["overflow"].append(("exchange bucket "
-                                "(spark.rapids.tpu.shuffle.ici.bucketRows)",
-                                overflow))
         return out, out_active
 
 
@@ -166,7 +251,7 @@ class _Aggregate:
     """AggregateExec partial/final under shard_map (grouped)."""
 
     def __init__(self, agg, child):
-        self.agg = agg
+        self.agg = agg._detached()
         self.child = child
         self.schema = agg.output_schema
         self.cap = None
@@ -175,8 +260,10 @@ class _Aggregate:
         self.child.resolve()
         self.cap = self.child.cap
 
+    def fingerprint(self) -> str:
+        return f"agg({self.agg._fingerprint()})<{self.child.fingerprint()}>"
+
     def emit(self, env):
-        import jax.numpy as jnp
         from ..exprs import EvalContext
         from ..ops import groupby
         arrays, active = self.child.emit(env)
@@ -209,12 +296,22 @@ class _Aggregate:
 class _Join:
     """Shuffled sort-merge equi-join, static shapes (local per device)."""
 
+    OVERFLOW = "join expansion (spark.rapids.tpu.shuffle.ici.joinOutputRows)"
+
     def __init__(self, join, left, right, out_rows: int,
                  cap_scale: int = 1):
-        self.join = join
+        from ..exprs import bind
+        from ..plan.join_exec import bound_join_keys
+        self.how = join.how
+        self.using = tuple(join.using)
         self.left = left
         self.right = right
         self.schema = join.output_schema
+        self.keys = bound_join_keys(join.plan, left.schema, right.schema)
+        self.condition = (None if join.condition is None
+                          else bind(join.condition, self.schema))
+        cond = "" if self.condition is None else self.condition.fingerprint()
+        self._sig = f"{join._fingerprint()}|{cond}|{','.join(self.using)}"
         self._out_rows = out_rows
         self._cap_scale = cap_scale
         self.cap = None
@@ -222,7 +319,7 @@ class _Join:
     def resolve(self):
         self.left.resolve()
         self.right.resolve()
-        if self.join.how in ("semi", "anti"):
+        if self.how in ("semi", "anti"):
             self.cap = self.left.cap
         else:
             from ..batch import bucket_capacity
@@ -231,18 +328,24 @@ class _Join:
                 (self._out_rows if self._out_rows > 0 else auto)
                 * self._cap_scale)
 
+    @property
+    def expands(self) -> bool:
+        """Whether the join has an expansion whose overflow it reports."""
+        return self.how not in ("semi", "anti")
+
+    def fingerprint(self) -> str:
+        return (f"join[{self.cap}]({self._sig})<{self.left.fingerprint()};"
+                f"{self.right.fingerprint()}>")
+
     def emit(self, env):
         import jax.numpy as jnp
-        from ..exprs import EvalContext, bind, promote_physical
+        from ..exprs import EvalContext, promote_physical
         from ..ops.groupby import _segment_starts, group_sort_indices
-        from ..plan.join_exec import bound_join_keys
 
-        join = self.join
-        how = join.how
+        how = self.how
         l_arrays, l_active = self.left.emit(env)
         r_arrays, r_active = self.right.emit(env)
-        lk, rk, common = bound_join_keys(
-            join.plan, self.left.schema, self.right.schema)
+        lk, rk, common = self.keys
 
         if how == "right":
             probe_arrays, probe_active, pk = r_arrays, r_active, rk
@@ -285,7 +388,8 @@ class _Join:
         gid = gid.at[perm].set(jnp.where(s_ok, gid_sorted, BIG))
         p_gid = jnp.where(p_ok, gid[:p_cap], -1)
         b_gid = jnp.where(b_ok, gid[p_cap:], BIG)
-        b_perm = jnp.argsort(b_gid)
+        # build rows of one key in any order: every match is emitted
+        b_perm = jnp.argsort(b_gid, stable=False)
         b_gid_sorted = b_gid[b_perm]
         lo = jnp.searchsorted(b_gid_sorted, p_gid, side="left").astype(
             jnp.int32)
@@ -302,10 +406,9 @@ class _Join:
                 env, how, probe_arrays, probe_active, build_arrays,
                 build_active, lo, matches, b_perm, p_cap, b_cap)
 
-        if join.condition is not None:
-            cond = bind(join.condition, self.schema)
+        if self.condition is not None:
             cctx = EvalContext(list(out), active.shape[0], active=active)
-            d, v = cond.eval(cctx)
+            d, v = self.condition.eval(cctx)
             keep = d if v is None else (d & v)
             active = active & keep
         return out, active
@@ -350,9 +453,7 @@ class _Join:
             bi = jnp.where(un_slot >= 0, un_slot, bi)
             in_range = in_range | (un_slot >= 0)
             grand_total = total + extra
-        env["overflow"].append((
-            "join expansion (spark.rapids.tpu.shuffle.ici.joinOutputRows)",
-            jnp.maximum(grand_total - out_cap, 0)))
+        env["overflow"].append(jnp.maximum(grand_total - out_cap, 0))
 
         def gather(arrays, idx):
             safe = jnp.clip(idx, 0, arrays[0][0].shape[0] - 1)
@@ -368,8 +469,7 @@ class _Join:
         b_cols = gather(build_arrays, bi)
         # assemble in output-schema order: left fields (using-keys coalesced
         # for right/full), then right fields minus using
-        join = self.join
-        using = set(join.using)
+        using = set(self.using)
         if how == "right":
             lcols, lsch = b_cols, self.left.schema
             rcols, rsch = p_cols, self.right.schema
@@ -396,16 +496,23 @@ class _Join:
 # ---------------------------------------------------------------------------------
 
 class ICICapacityOverflow(RuntimeError):
-    """A fixed-capacity exchange bucket or join expansion overflowed.
+    """An explicit exchange bucket (shuffle.ici.bucketRows) or a join
+    expansion's static capacity is too small for the rows counted.
     distribute_plan catches this and transparently retries the fragment
     at the next capacity bucket (shuffle.ici.overflowRetries) before
     surfacing it — the reference's split-retry idea (SURVEY §3.4)
     applied to static SPMD capacities."""
 
+    def __init__(self, detail: str):
+        super().__init__(
+            f"ICI fragment capacity overflow — would drop rows; raise the "
+            f"named conf and retry: {detail}")
 
-def _lower(node, leaves: List[_Leaf], conf, n_dev: int, axis: str,
+
+def _lower(node, leaves: List[tuple], conf, n_dev: int, axis: str,
            depth_has_exchange: List[bool], cap_scale: int = 1):
-    """Recursively lower ``node``; non-lowerable subtrees become leaves.
+    """Recursively lower ``node``; non-lowerable subtrees become leaves,
+    each added to ``leaves`` as ``(leaf, subtree)``.
 
     Raises NotLowerable only for conditions that poison the whole fragment
     (a schema no device representation exists for)."""
@@ -516,7 +623,7 @@ def _lower(node, leaves: List[_Leaf], conf, n_dev: int, axis: str,
     return _make_leaf(node, leaves)
 
 
-def _make_leaf(phys, leaves: List[_Leaf]) -> _Leaf:
+def _make_leaf(phys, leaves: List[tuple]) -> _Leaf:
     if _contains_exchange(phys):
         # materializing this subtree would execute its exchanges on the
         # single-process path under mode=ICI; refuse, so _find_fragment
@@ -526,8 +633,8 @@ def _make_leaf(phys, leaves: List[_Leaf]) -> _Leaf:
             f"{type(phys).__name__} subtree contains an exchange and "
             f"cannot be a materialized leaf")
     _check_device_schema(phys.output_schema)
-    leaf = _Leaf(phys, len(leaves))
-    leaves.append(leaf)
+    leaf = _Leaf(phys.output_schema, len(leaves))
+    leaves.append((leaf, phys))
     return leaf
 
 
@@ -555,7 +662,7 @@ def _find_fragment(node, conf, n_dev, axis, cap_scale: int = 1):
     """Topmost node whose subtree lowers AND contains >=1 exchange.
     Returns (node, lowered_root, leaves) or None."""
     try:
-        leaves: List[_Leaf] = []
+        leaves: List[tuple] = []
         has_exch = [False]
         lowered = _lower(node, leaves, conf, n_dev, axis, has_exch,
                          cap_scale)
@@ -575,30 +682,35 @@ def _find_fragment(node, conf, n_dev, axis, cap_scale: int = 1):
 # Fragment execution
 # ---------------------------------------------------------------------------------
 
-def _materialize_leaf(leaf: _Leaf, ctx, n_dev: int, string_dict):
-    """Run the leaf subtree single-process, shard row-wise: returns
-    (per-column (data, valid) numpy arrays padded to n_dev*cap, rows)."""
+def _pad_leaf(leaf: _Leaf, table, n_dev: int, string_dict) -> tuple:
+    """The leaf's feed from its rows as a host Arrow table (None for no
+    rows): per column the data and validity arrays, then the live-row
+    mask.  Device ``k`` holds the ``k``-th ``share`` of the rows
+    (an even share, so every device has work) at the head of its ``cap``
+    slots; a replicated leaf rides whole, once.  Sets ``leaf.cap``."""
     from ..batch import Schema, bucket_capacity
     from ..cpu.exec import arrow_to_values
-    from ..plan.physical import CollectExec
-    table = CollectExec(leaf.phys).collect_arrow(ctx)
     rows = 0 if table is None else table.num_rows
-    if leaf.replicated:
-        # broadcast build side: every device receives the whole table
-        cap = bucket_capacity(max(1, rows), min_capacity=8)
-        total = cap
-    else:
-        cap = bucket_capacity(max(1, -(-rows // n_dev)), min_capacity=8)
-        total = n_dev * cap
+    shards = 1 if leaf.replicated else n_dev
+    share = max(1, -(-rows // shards))
+    cap = bucket_capacity(share, min_capacity=8)
+    total = shards * cap
     leaf.cap = cap
-    cols = []
+
+    def place(src, dtype):
+        dst = np.zeros(total, dtype=dtype)
+        for k in range(shards):
+            part = src[k * share:(k + 1) * share]
+            dst[k * cap:k * cap + len(part)] = part
+        return dst
+
+    live = np.ones(rows, dtype=bool)
+    feed = []
     for i, f in enumerate(leaf.schema):
         if rows == 0:
-            if f.dtype.is_string:
-                data = np.zeros(total, dtype=np.int32)
-            else:
-                data = np.zeros(total, dtype=f.dtype.numpy_dtype)
-            cols.append((data, np.zeros(total, dtype=bool)))
+            dtype = np.int32 if f.dtype.is_string else f.dtype.numpy_dtype
+            feed += [np.zeros(total, dtype=dtype),
+                     np.zeros(total, dtype=bool)]
             continue
         if f.dtype.is_string:
             codes, valid = string_dict.encode(table.column(i))
@@ -606,122 +718,235 @@ def _materialize_leaf(leaf: _Leaf, ctx, n_dev: int, string_dict):
         else:
             (d, v), = arrow_to_values(table.select([i]),
                                       Schema([f]))
-        pad_d = np.zeros(total, dtype=d.dtype)
-        pad_d[:rows] = d
-        pad_v = np.zeros(total, dtype=bool)
-        pad_v[:rows] = True if v is None else v
-        cols.append((pad_d, pad_v))
-    return cols, rows
+        feed += [place(d, d.dtype), place(live if v is None else v, bool)]
+    feed.append(place(live, bool))
+    return tuple(feed)
 
 
-def _execute_fragment(lowered, leaves: List[_Leaf], ctx, mesh, axis: str,
-                      metrics):
-    """Trace + run the fragment on the mesh; return a host Arrow table.
-    ``metrics`` is the fragment root's MetricSet."""
+def _emit_order(node, stop_at_staged: bool = True):
+    """The lowered nodes in the order ``emit`` reaches them.  A staged
+    exchange opens its step: what is under it ran in a step before."""
+    if not (stop_at_staged and isinstance(node, _Exchange) and node.staged):
+        for c in _children(node):
+            yield from _emit_order(c, stop_at_staged)
+    yield node
+
+
+def _frontier(node, out: List[_Exchange]) -> bool:
+    """Collect the exchanges whose send half can run now: not staged yet,
+    and no such exchange under them.  Returns whether ``node`` holds one."""
+    if isinstance(node, _Exchange) and node.staged:
+        return False
+    below = False
+    for c in _children(node):
+        below = _frontier(c, out) or below
+    if isinstance(node, _Exchange):
+        if not below:
+            out.append(node)
+        return True
+    return below
+
+
+def _mesh_key(mesh, axis: str) -> str:
+    devices = list(mesh.devices.flat)
+    return (f"{axis}@{devices[0].platform}:"
+            + ",".join(str(d.id) for d in devices))
+
+
+def _step_program(roots, final: bool, mesh, axis: str):
+    """One step of the fragment as a cached mesh program, with the nodes
+    it reads its inputs from and the labels of its overflow counts.
+
+    ``roots`` are the frontier exchanges whose send halves end the step,
+    or, for the ``final`` step, the fragment's root alone."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from ..batch import ColumnBatch, DeviceColumn, HostStringColumn
-    from ..batch import to_arrow
-    from ..ops import batch_utils
-    from ..ops.strings import StringDictionary
+    from ..plan.physical import _cached_program, program
 
-    n_dev = int(np.prod(mesh.devices.shape))
-    sdict = StringDictionary()
-    feeds = []      # flat arg arrays (global)
-    feed_specs = []  # P(axis) sharded / P() replicated, aligned with feeds
-    leaf_slots = []  # (n_cols,) per leaf
-    for leaf in leaves:
-        cols, rows = _materialize_leaf(leaf, ctx, n_dev, sdict)
-        spec = P() if leaf.replicated else P(axis)
-        n_feed = 1 if leaf.replicated else n_dev
-        for d, v in cols:
-            feeds.append(d)
-            feeds.append(v)
-            feed_specs += [spec, spec]
-        feeds.append((np.arange(n_feed * leaf.cap, dtype=np.int64)
-                      < rows))
-        feed_specs.append(spec)
-        leaf_slots.append(len(cols))
-    lowered.resolve()
+    tops = roots if final else [e.child for e in roots]
+    for top in tops:
+        top.resolve()
+    nodes = [n for top in tops for n in _emit_order(top)]
+    in_leaves = [n for n in nodes if isinstance(n, _Leaf)]
+    in_staged = [n for n in nodes if isinstance(n, _Exchange)]
+    labels = [n.OVERFLOW for n in nodes
+              if isinstance(n, _Join) and n.expands]
+    fp = ";".join(r.fingerprint() if final else r.send_fingerprint()
+                  for r in roots)
 
-    overflow_labels: List[str] = []
-
-    def step(*args):
-        env: Dict = {"overflow": []}
-        pos = 0
-        for li, leaf in enumerate(leaves):
-            n_cols = leaf_slots[li]
-            arrays = []
-            for c in range(n_cols):
-                arrays.append((args[pos], args[pos + 1]))
-                pos += 2
-            active = args[pos]
-            pos += 1
-            env[leaf.index] = (arrays, active)
-        out, active = lowered.emit(env)
-        flat = []
-        for d, v in out:
-            flat.append(d)
-            flat.append(jnp.ones_like(active) if v is None else v)
-        # runs at trace time: record stage labels in emit order so host
-        # code can attribute per-stage overflow counts
-        overflow_labels.clear()
-        overflow_labels.extend(lbl for lbl, _ in env["overflow"])
-        if env["overflow"]:
-            ov = jnp.stack([jnp.asarray(o, dtype=jnp.int64)
-                            for _, o in env["overflow"]])
+    def step(leaf_args, staged_args):
+        env: Dict = {"overflow": [], "staged": staged_args}
+        for index, feed in leaf_args.items():
+            env[index] = ([(feed[i], feed[i + 1])
+                           for i in range(0, len(feed) - 1, 2)], feed[-1])
+        if final:
+            out, active = roots[0].emit(env)
+            flat = []
+            for d, v in out:
+                flat.append(d)
+                flat.append(jnp.ones_like(active) if v is None else v)
+            result = (tuple(flat), active)
+            # rows up to the last live one: what the gather has to keep
+            rows = jnp.arange(1, active.shape[0] + 1, dtype=jnp.int32)
+            sized = jnp.max(jnp.where(active, rows, 0)).reshape(1, 1)
         else:
-            ov = jnp.zeros((1,), dtype=jnp.int64)
-        return tuple(flat) + (active, ov)
+            sends = [e.emit_send(env) for e in roots]
+            result = tuple(send for send, _ in sends)
+            sized = jnp.stack([counts for _, counts in sends])
+        overflow = jnp.stack([jnp.asarray(o, dtype=jnp.int64)
+                              for o in env["overflow"]]
+                             or [jnp.zeros((), dtype=jnp.int64)])
+        return result, sized, overflow
 
-    n_out_cols = len(lowered.schema)
-    in_specs = tuple(feed_specs)
-    out_specs = tuple(P(axis) for _ in range(2 * n_out_cols + 1)) + (P(axis),)
-    from ..plan.physical import program
-    fn = program("ici_fragment_step",
-                 jax.shard_map(step, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs))
-    # place the inputs on the mesh here, not inside the jit call, so the
-    # bytes each device received are on record: a feed that landed whole
-    # on one device shows in the fragment's iciInputBytes.<device id>
-    from jax.sharding import NamedSharding
-    feeds = [jax.device_put(a, NamedSharding(mesh, spec))
-             for a, spec in zip(feeds, in_specs)]
-    for a in feeds:
-        for shard in a.addressable_shards:
-            metrics.add(f"iciInputBytes.{shard.device.id}",
-                        shard.data.nbytes)
-    outs = fn(*feeds)
-    ov = np.asarray(outs[-1])
-    if ov.sum() > 0:
-        # shard_map concatenates each device's (k,) overflow stack along
-        # axis 0: reshape to (n_dev, k) and sum per stage for attribution
-        k = max(1, len(overflow_labels))
-        per_stage = ov.reshape(n_dev, k).sum(axis=0)
-        detail = "; ".join(
-            f"{lbl}: {int(c)} rows" for lbl, c in
-            zip(overflow_labels, per_stage) if c > 0)
-        raise ICICapacityOverflow(
-            f"ICI fragment capacity overflow — would drop rows; raise the "
-            f"named conf and retry: {detail}")
-    active = outs[-2]
+    def build():
+        leaf_specs = {n.index: P() if n.replicated else P(axis)
+                      for n in in_leaves}
+        return program("ici_fragment_step", jax.shard_map(
+            step, mesh=mesh, in_specs=(leaf_specs, P(axis)),
+            out_specs=P(axis)))
+
+    fn = _cached_program(
+        f"ici-step|{_mesh_key(mesh, axis)}|{'final' if final else 'send'}|"
+        + fp, build)
+    return fn, in_leaves, in_staged, labels
+
+
+def _run_steps(lowered, feeds: Dict, mesh, axis: str, n_dev: int, stats):
+    """The fragment, a step per level of exchanges: returns the root's
+    (flat output arrays, live-row mask) on the mesh and the rows to keep
+    of each device's shard."""
+    from ..utils.metrics import fetch
+    for i, e in enumerate(n for n in _emit_order(lowered, False)
+                          if isinstance(n, _Exchange)):
+        e.index = i
+    staged: Dict = {}
+    while True:
+        frontier: List[_Exchange] = []
+        _frontier(lowered, frontier)
+        final = not frontier
+        roots = [lowered] if final else frontier
+        fn, in_leaves, in_staged, labels = _step_program(
+            roots, final, mesh, axis)
+        result, sized, overflow = fn(
+            {n.index: feeds.pop(n.index) for n in in_leaves},
+            {n.index: staged.pop(n.index) for n in in_staged})
+        # the step's one blocking fetch: the wait for the device is here
+        sized, overflow = fetch((sized, overflow))
+        if overflow.sum() > 0:
+            # shard_map concatenates each device's (k,) overflow counts
+            # along axis 0: (n_dev, k), summed per expansion
+            per_join = overflow.reshape(n_dev, -1).sum(axis=0)
+            detail = "; ".join(f"{lbl}: {int(c)} rows" for lbl, c in
+                               zip(labels, per_join) if c > 0)
+            raise ICICapacityOverflow(detail)
+        if final:
+            return result, int(sized.max())
+        # (n_dev senders, exchanges of the step, n_dev destinations)
+        need = sized.reshape(n_dev, len(roots), n_dev).max(axis=(0, 2))
+        for e, send, rows in zip(roots, result, need):
+            e.size(int(rows))
+            staged[e.index] = send
+            stats.ici_exchange_bytes += (
+                n_dev * n_dev * e.bucket_cap
+                * sum(a.dtype.itemsize for a in send[0]))
+
+
+def _gather(lowered, result, keep_rows: int, mesh, axis: str, n_dev: int,
+            string_dict):
+    """The root's outputs as a host Arrow table, each device's shard cut
+    on the mesh to the ladder rung over ``keep_rows`` first."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from ..batch import (ColumnBatch, DeviceColumn, HostStringColumn,
+                         bucket_capacity, to_arrow)
+    from ..plan.physical import _cached_program, program
+    from ..utils.metrics import fetch
+
+    flat, active = result
+    cap = int(active.shape[0]) // n_dev
+    new_cap = bucket_capacity(max(1, keep_rows), min_capacity=8)
+    if new_cap < cap:
+        def build():
+            return program("ici_fragment_gather", jax.shard_map(
+                lambda tree: jax.tree_util.tree_map(
+                    lambda a: a[:new_cap], tree),
+                mesh=mesh, in_specs=P(axis), out_specs=P(axis)))
+        sig = ",".join(str(a.dtype) for a in flat)
+        flat, active = _cached_program(
+            f"ici-gather|{_mesh_key(mesh, axis)}|{cap}->{new_cap}|{sig}",
+            build)((flat, active))
     global_cap = int(active.shape[0])
     cols = []
     for i, f in enumerate(lowered.schema):
-        d = outs[2 * i]
-        v = outs[2 * i + 1]
+        d, v = flat[2 * i], flat[2 * i + 1]
         if f.dtype.is_string:
-            host_d = np.asarray(d)
-            host_v = np.asarray(v)
-            arr = sdict.decode(host_d, host_v)
-            cols.append(HostStringColumn(arr, capacity=global_cap))
+            host_d, host_v = fetch((d, v))
+            cols.append(HostStringColumn(string_dict.decode(host_d, host_v),
+                                         capacity=global_cap))
         else:
             cols.append(DeviceColumn(
                 f.dtype, jnp.asarray(d).astype(f.dtype.numpy_dtype), v))
-    batch = ColumnBatch(lowered.schema, cols, global_cap, active)
-    return to_arrow(batch)
+    return to_arrow(ColumnBatch(lowered.schema, cols, global_cap, active))
+
+
+@contextlib.contextmanager
+def _phase(stats, what: str):
+    """An ``ici:<what>`` span whose seconds add to ``stats.ici_<what>_s``
+    (the driving thread's: a fragment runs on the thread that plans)."""
+    from ..utils import tracing
+    sp = tracing.span(None, "ici:" + what, "ici")
+    try:
+        with sp:
+            yield sp
+    finally:
+        field = f"ici_{what}_s"
+        setattr(stats, field, getattr(stats, field) + sp.dur)
+
+
+def _execute_fragment(lowered, leaves: List[tuple], ctx, mesh, axis: str,
+                      metrics):
+    """Run the fragment on the mesh; return a host Arrow table.
+    ``leaves`` are ``_lower``'s ``(leaf, subtree)`` pairs, ``metrics`` is
+    the fragment root's MetricSet."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from ..ops.strings import StringDictionary
+    from ..plan.physical import CollectExec
+    from ..utils.metrics import QueryStats, upload
+
+    n_dev = int(np.prod(mesh.devices.shape))
+    stats = QueryStats.get()
+    stats.ici_fragments += 1
+    sdict = StringDictionary()
+    with _phase(stats, "materialize"):
+        tables = [CollectExec(plan).collect_arrow(ctx)
+                  for _leaf, plan in leaves]
+    with _phase(stats, "feed"):
+        feeds = {}
+        for (leaf, _plan), table in zip(leaves, tables):
+            # placed on the mesh here, not inside the step's call, so the
+            # bytes each device received are on record: a feed that
+            # landed whole on one device shows in iciInputBytes.<device>
+            feed = upload(
+                _pad_leaf(leaf, table, n_dev, sdict),
+                NamedSharding(mesh, P() if leaf.replicated else P(axis)))
+            for a in feed:
+                for shard in a.addressable_shards:
+                    metrics.add(f"iciInputBytes.{shard.device.id}",
+                                shard.data.nbytes)
+                    stats.ici_feed_bytes += shard.data.nbytes
+            feeds[leaf.index] = feed
+        del tables
+    with _phase(stats, "step"):
+        result, keep_rows = _run_steps(lowered, feeds, mesh, axis, n_dev,
+                                       stats)
+    with _phase(stats, "gather"):
+        return _gather(lowered, result, keep_rows, mesh, axis, n_dev, sdict)
 
 
 # ---------------------------------------------------------------------------------
@@ -771,6 +996,8 @@ def distribute_plan(phys, ctx, mesh, axis: str = "data"):
                 # their sources, which is safe — scans and captured
                 # fragment tables replay identically)
                 scale *= 4
+                from ..utils.metrics import QueryStats
+                QueryStats.get().ici_overflow_retries += 1
                 log.warning(
                     "ICI: capacity overflow, retrying fragment at "
                     "%dx capacities (attempt %d/%d)",

@@ -379,7 +379,7 @@ PROGRAM_NAMES = frozenset((
     "join_expand", "join_unmatched", "bjoin_sort", "bjoin_probe",
     "bjoin_csr", "bjoin_csr_probe", "bjoin_dense_stats",
     "bjoin_dense_table", "bjoin_dense_probe",
-    "ici_fragment_step", "ici_agg_step",
+    "ici_fragment_step", "ici_fragment_gather", "ici_agg_step",
 ))
 
 

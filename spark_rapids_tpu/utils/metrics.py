@@ -84,6 +84,23 @@ class QueryStats:
         # seconds inside ``scan:decode`` spans (io/), summed over the
         # threads that decode: can pass the query's wall
         self.decode_s = 0.0
+        # mesh fragments (parallel/spmd.py, shuffle.mode=ICI): fragments
+        # run, the seconds of their four phases (``ici:materialize`` /
+        # ``ici:feed`` / ``ici:step`` / ``ici:gather`` spans, on the
+        # driving thread; like decode_s and upload_s they overlap the
+        # account's terms and add to nothing), the bytes placed on the
+        # mesh as padded (every device's copy of a replicated leaf), the
+        # bytes the all_to_alls move (from the static bucket shapes:
+        # n_dev * n_dev * bucket rows * row width an exchange), and the
+        # re-runs at 4x capacities after an overflow
+        self.ici_fragments = 0
+        self.ici_materialize_s = 0.0
+        self.ici_feed_s = 0.0
+        self.ici_step_s = 0.0
+        self.ici_gather_s = 0.0
+        self.ici_feed_bytes = 0
+        self.ici_exchange_bytes = 0
+        self.ici_overflow_retries = 0
         # the query's host-time account (utils/tracing.account): nine
         # disjoint terms of the DRIVING thread's time, by span self
         # time, that sum to ``query_wall_s``.  Unlike fetch_wait_s /

@@ -172,6 +172,30 @@ METRICS = (
     ("query_decode_seconds_total", "counter", "",
      "Seconds inside scan:decode spans, summed over the threads that "
      "decode."),
+    # mesh fragments (parallel/spmd.py, shuffle.mode=ICI)
+    ("query_ici_fragments_total", "counter", "",
+     "Plan fragments run on the ICI mesh by finished queries."),
+    ("query_ici_materialize_seconds_total", "counter", "",
+     "Seconds inside ici:materialize spans: a fragment's leaves run "
+     "single-process and brought to the host."),
+    ("query_ici_feed_seconds_total", "counter", "",
+     "Seconds inside ici:feed spans: leaves padded and placed on the "
+     "mesh."),
+    ("query_ici_step_seconds_total", "counter", "",
+     "Seconds inside ici:step spans: a fragment's mesh programs and the "
+     "fetches of their row counts."),
+    ("query_ici_gather_seconds_total", "counter", "",
+     "Seconds inside ici:gather spans: a fragment's outputs to a host "
+     "table."),
+    ("query_ici_feed_bytes_total", "counter", "",
+     "Bytes placed on the mesh as padded (every device's copy of a "
+     "replicated leaf)."),
+    ("query_ici_exchange_bytes_total", "counter", "",
+     "Bytes the ICI all_to_alls move, from the static bucket shapes "
+     "(n_dev * n_dev * bucket rows * row width an exchange)."),
+    ("query_ici_overflow_retries_total", "counter", "",
+     "Fragments re-run at 4x capacities after a bucket or join "
+     "expansion overflowed."),
     # the host-time account (utils/tracing.account): disjoint shares of
     # the driving thread's time; the nine terms sum to the wall
     ("query_wall_seconds_total", "counter", "",
@@ -347,6 +371,14 @@ _QS_FOLD = (
     ("upload_bytes", "query_upload_bytes_total"),
     ("upload_s", "query_upload_seconds_total"),
     ("decode_s", "query_decode_seconds_total"),
+    ("ici_fragments", "query_ici_fragments_total"),
+    ("ici_materialize_s", "query_ici_materialize_seconds_total"),
+    ("ici_feed_s", "query_ici_feed_seconds_total"),
+    ("ici_step_s", "query_ici_step_seconds_total"),
+    ("ici_gather_s", "query_ici_gather_seconds_total"),
+    ("ici_feed_bytes", "query_ici_feed_bytes_total"),
+    ("ici_exchange_bytes", "query_ici_exchange_bytes_total"),
+    ("ici_overflow_retries", "query_ici_overflow_retries_total"),
     ("query_wall_s", "query_wall_seconds_total"),
     ("acct_plan_s", "query_acct_plan_seconds_total"),
     ("acct_admit_s", "query_acct_admit_seconds_total"),

@@ -153,7 +153,27 @@ SPANS = (
     ("shuffle:write", "host_exec", "parallel/host_shuffle.py."),
     ("shuffle:read", "host_exec", "parallel/host_shuffle.py."),
     ("dcn:fetch", "host_exec", "parallel/dcn.py fragment fetch."),
-    ("ici:fragment", "host_exec", "parallel/spmd.py mesh fragment."),
+    ("ici:fragment", "host_exec",
+     "parallel/spmd.py: one run of a mesh fragment, the parent of the "
+     "four below: what is left is lowering and retry bookkeeping."),
+    ("ici:materialize", "host_exec",
+     "parallel/spmd.py: the fragment's leaves run single-process and "
+     "brought to host Arrow (the executor's spans nest inside and keep "
+     "their own terms; QueryStats.ici_materialize_s)."),
+    ("ici:feed", "dispatch",
+     "parallel/spmd.py: leaves padded to the mesh's static capacity and "
+     "placed on it (scan:upload nests inside; QueryStats.ici_feed_s, "
+     "ici_feed_bytes)."),
+    ("ici:step", "dispatch",
+     "parallel/spmd.py: the fragment's mesh programs, one per level of "
+     "exchanges (program:ici_fragment_step and the fetch:blocking of "
+     "each step's row counts nest inside; QueryStats.ici_step_s, "
+     "ici_exchange_bytes)."),
+    ("ici:gather", "host_exec",
+     "parallel/spmd.py: the fragment's outputs cut to their live rows "
+     "on the mesh and brought to a host table (program:"
+     "ici_fragment_gather, fetch:blocking and result:arrow nest inside; "
+     "QueryStats.ici_gather_s)."),
     ("op:", "host_exec",
      "instrument_batches (one pull through an exec node) and "
      "MetricSet.time (op:opTime, op:scanTime, op:buildTime): the "

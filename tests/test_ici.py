@@ -362,3 +362,174 @@ def test_ici_existence_join_runs_single_process(shuffle_only, rng):
                    | (F.col("l_price") > 900.0))
     got, want = _both_modes(df, sess)
     _assert_rows_equal(got, want)
+
+
+# ---------------------------------------------------------------------------------
+# the mesh layer in the tracing: the account, the counters, the program cache
+# ---------------------------------------------------------------------------------
+
+def _mesh_query(sess, rng):
+    orders, items = _tables(rng)
+    do = sess.create_dataframe(orders)
+    dl = sess.create_dataframe(items)
+    return (do.join(dl, [("o_orderkey", "l_orderkey")], "inner")
+            .group_by("o_custkey")
+            .agg(F.sum(F.col("l_price")).alias("rev")))
+
+
+def _run_recording_fragments(df, monkeypatch):
+    """Collect ``df`` under ICI; returns (rows, the query's QueryStats,
+    [(lowered root, rows of the gathered table)] per fragment run)."""
+    from spark_rapids_tpu.parallel import spmd
+    from spark_rapids_tpu.utils.metrics import QueryStats
+    ran = []
+    inner = spmd._execute_fragment
+
+    def recording(lowered, *args, **kw):
+        table = inner(lowered, *args, **kw)
+        ran.append((lowered, table.num_rows))
+        return table
+    monkeypatch.setattr(spmd, "_execute_fragment", recording)
+    with QueryStats.scoped() as st:
+        rows = df.collect()
+    return rows, st, ran
+
+
+def _exchanges(lowered):
+    from spark_rapids_tpu.parallel import spmd
+    return [n for n in spmd._emit_order(lowered, False)
+            if isinstance(n, spmd._Exchange)]
+
+
+def _row_bytes(schema):
+    # every column rides as its data and a validity byte
+    return sum((4 if f.dtype.is_string else f.dtype.numpy_dtype.itemsize)
+               + 1 for f in schema)
+
+
+def test_ici_account_closes_over_a_mesh_query(shuffle_only, rng,
+                                              monkeypatch):
+    from spark_rapids_tpu.utils import tracing
+    sess = shuffle_only
+    df = _mesh_query(sess, rng)
+    sess.conf.set("spark.rapids.tpu.shuffle.mode", "ICI")
+    try:
+        df.collect()  # compiles land in the first run's account
+        rows, st, ran = _run_recording_fragments(df, monkeypatch)
+    finally:
+        sess.conf.set("spark.rapids.tpu.shuffle.mode", "CACHE_ONLY")
+    assert rows and len(ran) == 1 and st.ici_fragments == 1
+    terms = [getattr(st, f"acct_{t}_s") for t in tracing.ACCOUNT_TERMS]
+    assert all(v >= 0.0 for v in terms)
+    assert sum(terms) == pytest.approx(st.query_wall_s, rel=0.01)
+    phases = [st.ici_materialize_s, st.ici_feed_s, st.ici_step_s,
+              st.ici_gather_s]
+    assert all(v > 0.0 for v in phases)
+    # the four are inside the wall, and the account holds their waits and
+    # dispatches under the terms it has
+    assert sum(phases) <= st.query_wall_s
+    assert st.acct_fetch_wait_s > 0 and st.acct_dispatch_s > 0
+    assert st.compiles == 0 and st.ici_overflow_retries == 0
+    # what the all_to_alls move, from the static shapes: every device
+    # holds n_dev buckets of bucket_cap rows for each of its n_dev peers
+    import jax
+    n_dev = len(jax.devices())
+    exchanges = _exchanges(ran[0][0])
+    assert len(exchanges) == 3  # the join's two sides, the aggregate's
+    assert st.ici_exchange_bytes == sum(
+        n_dev * n_dev * e.bucket_cap * _row_bytes(e.schema)
+        for e in exchanges)
+    # a bucket holds the rows counted, not the sender's whole capacity
+    assert all(e.bucket_cap < e.child.cap for e in exchanges)
+    names = {e[1] for e in sess.last_trace().events}
+    assert {"ici:fragment", "ici:materialize", "ici:feed", "ici:step",
+            "ici:gather", "program:ici_fragment_step",
+            "program:ici_fragment_gather"} <= names
+
+
+def test_ici_upload_and_fetch_counters_cover_the_feed_and_the_gather(
+        shuffle_only, rng, monkeypatch):
+    sess = shuffle_only
+    df = _mesh_query(sess, rng)
+    sess.conf.set("spark.rapids.tpu.shuffle.mode", "ICI")
+    try:
+        rows, st, ran = _run_recording_fragments(df, monkeypatch)
+    finally:
+        sess.conf.set("spark.rapids.tpu.shuffle.mode", "CACHE_ONLY")
+    lowered, gathered_rows = ran[0]
+    per_device = {}
+    for mset in sess.last_exec_context().metrics.values():
+        for name, v in mset.values.items():
+            if name.startswith("iciInputBytes."):
+                per_device[name] = v
+    import jax
+    assert len(per_device) == len(jax.devices())
+    assert st.ici_feed_bytes == sum(per_device.values()) > 0
+    # the leaves' own scans upload too: the feed is part of the count
+    assert st.upload_bytes >= st.ici_feed_bytes
+    assert st.uploads >= 2  # one placement per leaf, at least
+    # a step a level of exchanges and the last one each read the mesh
+    # once, the gather once; the leaves' collects come on top
+    assert st.blocking_fetches >= 2 + 1 + 1
+    assert gathered_rows == len(rows) > 0
+    assert st.fetch_bytes >= gathered_rows * _row_bytes(lowered.schema)
+
+
+def test_ici_cached_step_programs_hold_no_plan(shuffle_only, rng):
+    """A step's program outlives its query in the process-wide program
+    cache: it must keep the expressions it traces, not the plan, whose
+    scans hold the host tables of in-memory DataFrames."""
+    import gc
+    import weakref
+    from spark_rapids_tpu.plan import physical
+    sess = shuffle_only
+    orders, items = _tables(rng)
+    held = [weakref.ref(orders), weakref.ref(items)]
+    df = (sess.create_dataframe(orders)
+          .join(sess.create_dataframe(items),
+                [("o_orderkey", "l_orderkey")], "inner")
+          .group_by("o_custkey").agg(F.sum(F.col("l_price")).alias("rev")))
+    del orders, items
+    sess.conf.set("spark.rapids.tpu.shuffle.mode", "ICI")
+    try:
+        assert df.collect()
+    finally:
+        sess.conf.set("spark.rapids.tpu.shuffle.mode", "CACHE_ONLY")
+    with physical._STAGE_CACHE_LOCK:
+        steps = [k for k in physical._STAGE_CACHE if k.startswith("ici-step|")]
+    assert steps
+    # what else may hold the query: the session's last trace and context
+    df = None
+    sess.create_dataframe(pa.table({"x": [1]})).collect()
+    gc.collect()
+    assert [r() for r in held] == [None, None]
+
+
+@pytest.mark.parametrize("n_keys,nulls", [(1, False), (3, False), (2, True)])
+def test_group_sort_puts_equal_keys_in_one_stable_run(rng, n_keys, nulls):
+    """The group sort is four stable passes of a two-operand sort over
+    the digits of a 128-bit key hash (cheap to compile for the TPU):
+    every distinct key, null or not, is one run of adjacent rows in
+    their first order, and the inactive rows come last."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu.ops.groupby import group_sort_indices
+    n = 5000
+    keys, cols = [], []
+    for k in range(n_keys):
+        data = rng.integers(-40, 40, n) * (k + 1)
+        valid = rng.random(n) > 0.1 if nulls else np.ones(n, dtype=bool)
+        keys.append((jnp.asarray(data), jnp.asarray(valid) if nulls else None))
+        cols.append([(int(d), bool(v)) if v else None
+                     for d, v in zip(data, valid)])
+    active = rng.random(n) > 0.2
+    perm = np.asarray(group_sort_indices(keys, jnp.asarray(active)))
+    assert sorted(perm.tolist()) == list(range(n))
+    n_active = int(active.sum())
+    assert active[perm[:n_active]].all() and not active[perm[n_active:]].any()
+    runs = {}
+    rows = [tuple(c[i] for c in cols) for i in perm[:n_active]]
+    for pos, (row, src) in enumerate(zip(rows, perm[:n_active])):
+        first, last_pos, last_src = runs.get(row, (pos, pos - 1, -1))
+        assert pos == last_pos + 1 and src > last_src, row
+        runs[row] = (first, pos, src)
+    assert len(runs) > 1
