@@ -304,6 +304,24 @@ COMPACT_FORMS = ("batch_compact", "batch_compact_scatter")
 _SEARCH_STEP_NS = 7.8
 _SEARCH_STEP_NS_PAST_2_23 = 19.0
 _SCATTER_ROW_NS = 5.2
+# The same grid's other prices, for scatter_rung: a scatter of a 64-bit
+# array (a pair of 32-bit arrays on this chip) by source row, a gather
+# by element (bool 8.2, int32 7.4; 64-bit 16.0), a cumulative sum by row.
+_SCATTER64_ROW_NS = 70.3
+_GATHER_NS = 8.2
+_GATHER64_NS = 16.0
+_CUMSUM_ROW_NS = 0.32
+# below this a whole scatter is not worth a branch: a couple of ms
+_RUNG_FLOOR_NS = 2e6
+# the rung is cap >> this: Q3's 10,000 live of 2,097,152 rows and the
+# stars' few hundred of 262,144 fit it (PERF.md section 6, PR 30)
+_RUNG_SHIFT = 6
+
+
+def _search_ns(cap: int, new_cap: int) -> float:
+    step_ns = _SEARCH_STEP_NS if cap <= 1 << 23 \
+        else _SEARCH_STEP_NS_PAST_2_23
+    return new_cap * cap.bit_length() * step_ns
 
 
 def _compact_form(cap: int, new_cap: int) -> str:
@@ -312,19 +330,58 @@ def _compact_form(cap: int, new_cap: int) -> str:
     groups out of a 16.7M-slot table), one scatter where most rows stay
     (a filter, a join's output).  A pure function of what is static at
     trace time; the arrays do not enter, both forms gather each alike."""
-    step_ns = _SEARCH_STEP_NS if cap <= 1 << 23 \
-        else _SEARCH_STEP_NS_PAST_2_23
-    search_ns = new_cap * cap.bit_length() * step_ns
-    return COMPACT_FORMS[search_ns >= cap * _SCATTER_ROW_NS]
+    return COMPACT_FORMS[_search_ns(cap, new_cap) >= cap * _SCATTER_ROW_NS]
+
+
+def scatter_rung(cap: int, n64: int, n32: int) -> Optional[int]:
+    """The capacity below ``cap`` at which a program that scatters
+    ``cap`` rows ``n64`` times at 64 bits and ``n32`` times at 32 or 8
+    may run instead, when its live rows fit: it then pays a cumulative
+    sum over ``cap`` rows, the search (or scatter) for the rung's source
+    rows, a gather of the slot and of each scattered array, and the
+    scatters over the rung alone.  A power of two, about ``cap / 64``;
+    ``None`` where the whole scatter is cheap or the rung would not pay.
+    A pure function of what is static at trace time, from the prices
+    above, as :func:`_compact_form` is."""
+    row_ns = n64 * _SCATTER64_ROW_NS + n32 * _SCATTER_ROW_NS
+    if cap * row_ns < _RUNG_FLOOR_NS:
+        return None
+    r = 1 << max((cap >> _RUNG_SHIFT).bit_length() - 1, 0)
+    find_ns = min(_search_ns(cap, r), cap * _SCATTER_ROW_NS)
+    gather_ns = (n64 + 1) * _GATHER64_NS + n32 * _GATHER_NS
+    pays = cap * _CUMSUM_ROW_NS + find_ns + r * (gather_ns + row_ns) \
+        < cap * row_ns
+    return r if pays else None
+
+
+def live_sources(form: str, active: jax.Array, new_cap: int,
+                 csum: Optional[jax.Array] = None) -> jax.Array:
+    """``src[j]`` = the position of the (j+1)-th live row of ``active``,
+    for ``new_cap`` slots; ``cap`` for the slots past the last.  Stable:
+    the live rows keep their order.  ``form`` is one of
+    :data:`COMPACT_FORMS` (:func:`_compact_form` names the cheaper);
+    ``csum`` is ``cumsum(active)`` as int32 where the caller has it."""
+    cap = active.shape[0]
+    if csum is None:
+        csum = jnp.cumsum(active.astype(jnp.int32))
+    if form == "batch_compact":
+        # log2(cap) steps of a new_cap-long gather
+        return jnp.searchsorted(
+            csum, jnp.arange(1, new_cap + 1, dtype=jnp.int32),
+            side="left")
+    # one cap-long scatter for the whole batch
+    return jnp.full((new_cap,), cap, dtype=jnp.int32).at[
+        jnp.where(active, csum - 1, new_cap)].set(
+            jnp.arange(cap, dtype=jnp.int32), mode="drop")
 
 
 @functools.lru_cache(maxsize=512)
 def _compact_program(form: str, cap: int, new_cap: int, spec: tuple,
                      has_sel: bool):
     """One jitted program compacting EVERY device column of a batch:
-    the source row of each output slot is found ONCE (``src[j]`` = position of the (j+1)-th live row, ``cap`` for the
-    slots past the last), then every array is gathered by it; the slots
-    past the live rows read zeros and ``False``."""
+    the source row of each output slot is found ONCE
+    (:func:`live_sources`), then every array is gathered by it; the
+    slots past the live rows read zeros and ``False``."""
 
     from ..plan.physical import program
 
@@ -333,17 +390,7 @@ def _compact_program(form: str, cap: int, new_cap: int, spec: tuple,
         active = jnp.arange(cap, dtype=jnp.int32) < num_rows
         if sel is not None:
             active = active & sel
-        csum = jnp.cumsum(active.astype(jnp.int32))
-        if form == "batch_compact":
-            # log2(cap) steps of a new_cap-long gather
-            src = jnp.searchsorted(
-                csum, jnp.arange(1, new_cap + 1, dtype=jnp.int32),
-                side="left")
-        else:
-            # one cap-long scatter for the whole batch
-            src = jnp.full((new_cap,), cap, dtype=jnp.int32).at[
-                jnp.where(active, csum - 1, new_cap)].set(
-                    jnp.arange(cap, dtype=jnp.int32), mode="drop")
+        src = live_sources(form, active, new_cap)
         outs = []
         for (kind, _dt, _hv, _extra), dv in zip(spec, cols):
             if kind == "h":

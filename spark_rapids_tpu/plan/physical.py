@@ -28,10 +28,10 @@ from ..batch import ColumnBatch, DeviceColumn, Field, HostStringColumn, Schema
 from ..config import TpuConf
 from ..exprs import (AggregateExpression, Alias, BoundReference, EvalContext,
                      Expression)
-from ..ops import batch_utils, groupby
+from ..ops import batch_utils, dense_agg, groupby
 from ..utils import tracing
-from ..utils.metrics import MetricSet, fetch, fetch_scalars, prestage, \
-    region_fetch, region_scalars, upload
+from ..utils.metrics import MetricSet, QueryStats, fetch, fetch_scalars, \
+    prestage, region_fetch, region_scalars, upload
 
 __all__ = ["ExecContext", "TpuExec", "ScanExec", "StageExec", "AggregateExec",
            "CollectExec"]
@@ -680,7 +680,6 @@ class StageExec(TpuExec):
                 b.donatable = False
                 use_fn = fn_donate
                 donated = True
-                from ..utils.metrics import QueryStats
                 QueryStats.get().donated_batches += 1
 
             from ..runtime import warmstore
@@ -1121,12 +1120,29 @@ class AggregateExec(TpuExec):
     #
     # The group-by sibling of the dense join kernel: a single int/date
     # group key with a bounded domain aggregates by SCATTER into
-    # domain-sized accumulators (acc.at[key - kmin].add/min/max) — no
-    # sort at all, and scatters run at gather speed on this chip while a
-    # 6M-row hash-sort pass costs ~0.3-0.5 s.  TPC-H q10/q17/q18/q21's
-    # high-cardinality aggregations are the measured victims.
-    # Out-of-domain and NULL-key rows divert to the generic sort path
-    # and merge at the end (usually empty).
+    # domain-sized accumulators (acc.at[key - kmin].add/min/max), with no
+    # sort at all.  TPC-H q10/q17/q18/q21's high-cardinality
+    # aggregations are the measured victims of the sort path.  What the
+    # scatter costs on one v5e (PERF.md section 6, PR 27's grid): 70.3 ns
+    # a SOURCE row for int64/float64 and 5.2-6.4 for 32-bit and 8-bit
+    # arrays, live or not, against 16.0 / 8.2 ns an element for a gather
+    # and 0.32 ns a row for a cumulative sum.  So the update programs
+    # count their in-domain rows and, where few of a batch's padded rows
+    # are bound for the tables (q3: 10,000 of 2,097,152 behind its
+    # joins), compact them on the device and scatter those alone
+    # (ops/dense_agg.update_tables, by the rule
+    # batch_utils.scatter_rung).  Out-of-domain and NULL-key rows divert
+    # to the generic sort path and merge at the end (usually empty).
+
+    @staticmethod
+    def _count_dense_batches(m, batches: int, compacted: int) -> None:
+        """How many batches the dense update programs took, and how many
+        of them scattered a compacted rung: read in the tail fetch."""
+        m.add("aggDenseBatches", batches)
+        m.add("aggDenseCompactedBatches", compacted)
+        stats = QueryStats.get()
+        stats.agg_dense_batches += batches
+        stats.agg_dense_compacted_batches += compacted
 
     def _dense_agg_static_ok(self, ops, conf) -> bool:
         if self.mode != "complete" or len(self.group_exprs) != 1:
@@ -1197,26 +1213,14 @@ class AggregateExec(TpuExec):
         D = bucket_capacity(domain)
         n_bufs = len(ops)
 
-        from ..ops.groupby import _SENTINELS
-
-        def _sent_kind(np_dt):
-            return ("f" if np_dt.kind == "f"
-                    else "b" if np_dt == np.bool_ else "i")
-
         def _init_acc():
-            accs = []
-            for f, op in zip(buffer_schema.fields[1:], ops):
-                np_dt = np.dtype(f.dtype.numpy_dtype)
-                if op == "sum":
-                    accs.append(jnp.zeros((D,), dtype=np_dt))
-                else:
-                    sent = _SENTINELS[op][_sent_kind(np_dt)](np_dt)
-                    accs.append(jnp.full((D,), sent, dtype=np_dt))
-            return accs
+            return [dense_agg.empty_table(op, D, f.dtype.numpy_dtype)
+                    for f, op in zip(buffer_schema.fields[1:], ops)]
 
         def build_update():
             @program("agg_dense_update")
-            def f(arrays, sel, num_rows, accs, present, kmin_s):
+            def f(arrays, sel, num_rows, accs, present, kmin_s,
+                  n_compacted):
                 cap = next(a[0].shape[0] for a in arrays
                            if a is not None)
                 active = jnp.arange(cap, dtype=jnp.int32) < num_rows
@@ -1228,32 +1232,13 @@ class AggregateExec(TpuExec):
                 idx = kd.astype(jnp.int64) - kmin_s
                 in_dom = ok & (idx >= 0) & (idx < D)
                 sidx = jnp.where(in_dom, idx, jnp.int64(D))
-                contribs = update(ectx)
-                new_accs = []
-                for (cd, cv), acc, op in zip(contribs, accs, ops):
-                    mask = in_dom if cv is None else (in_dom & cv)
-                    if op == "sum":
-                        z = jnp.zeros((), dtype=acc.dtype)
-                        new_accs.append(acc.at[sidx].add(
-                            jnp.where(mask, cd.astype(acc.dtype), z),
-                            mode="drop"))
-                    else:
-                        np_dt = np.dtype(acc.dtype)
-                        sent = acc.dtype.type(
-                            _SENTINELS[op][_sent_kind(np_dt)](np_dt))
-                        scatter = (acc.at[sidx].min if op == "min"
-                                   else acc.at[sidx].max)
-                        new_accs.append(scatter(
-                            jnp.where(mask, cd.astype(acc.dtype), sent),
-                            mode="drop"))
-                present = present.at[sidx].max(
-                    jnp.where(in_dom, jnp.int8(1), jnp.int8(0)),
-                    mode="drop")
+                new_accs, _, present, took = dense_agg.update_tables(
+                    sidx, in_dom, update(ectx), (), accs, ops, (),
+                    present)
                 # rows the dense table cannot hold (null key / outside
                 # the first batch's domain) divert to the generic path
                 leftover = active & ~in_dom
-                any_left = jnp.any(leftover)
-                return tuple(new_accs), present, leftover, any_left
+                return new_accs, present, leftover, n_compacted + took
             return f
 
         ufn = _cached_program(fp + f"|update|{D}", build_update)
@@ -1290,15 +1275,20 @@ class AggregateExec(TpuExec):
                 leftovers.clear()
 
             first_batch = True
+            n_batches = 0
+            # batches that scattered a compacted rung: counted on the
+            # device, read in the tail fetch
+            n_compacted = np.int32(0)
             for batch in itertools.chain([first], rest):
                 if batch.num_rows == 0:
                     continue
                 with m.time("opTime"):
-                    accs_t, present, leftover, _ = ufn(
+                    accs_t, present, leftover, n_compacted = ufn(
                         arrays_of(batch), batch.sel,
                         np.int32(batch.num_rows), tuple(accs), present,
-                        kmin_s)
+                        kmin_s, n_compacted)
                     accs = list(accs_t)
+                n_batches += 1
                 if not (first_batch and key_nonnull):
                     leftovers.append((
                         ColumnBatch(batch.schema, batch.columns,
@@ -1314,13 +1304,14 @@ class AggregateExec(TpuExec):
             pending = self._to_buffer_batch(
                 buffer_schema, [(key_col, None)],
                 [(a, None) for a in accs], present > 0)
-            # one tail fetch: leftover counts + group count together —
-            # n_groups then sizes a sync-free output compaction, so a
-            # sparse domain (D >> groups) doesn't inflate every
-            # downstream operator to D capacity
+            # one tail fetch: leftover counts + group count + compacted
+            # batches together — n_groups then sizes a sync-free output
+            # compaction, so a sparse domain (D >> groups) doesn't
+            # inflate every downstream operator to D capacity
             n_groups_dev = jnp.sum((present > 0).astype(jnp.int64))
-            left_counts, n_groups = fetch(  # fusion-ok (end-of-stream tail: one batched fetch by construction)
-                ([c for _, c in leftovers], n_groups_dev))
+            left_counts, n_groups, n_compacted = fetch(  # fusion-ok (end-of-stream tail: one batched fetch by construction)
+                ([c for _, c in leftovers], n_groups_dev, n_compacted))
+            self._count_dense_batches(m, n_batches, int(n_compacted))
             for (b, _), cnt in zip(leftovers, left_counts):
                 if int(cnt):
                     left_parts.append(sort_part_fn(
@@ -1498,43 +1489,30 @@ class AggregateExec(TpuExec):
         if est > ctx.conf["spark.rapids.tpu.sql.agg.dense.maxAccumBytes"]:
             return None
 
-        from ..ops.groupby import _SENTINELS
-
-        def _sent_kind(np_dt):
-            return ("f" if np_dt.kind == "f"
-                    else "b" if np_dt == np.bool_ else "i")
-
         def _res_np_dtype(k):
             if k.dtype.is_string:
                 return np.dtype(np.int32)  # dictionary codes
             return np.dtype(k.dtype.numpy_dtype)
 
         def _init_acc():
-            accs = []
-            for f, op in zip(buffer_schema.fields[n_keys:], ops):
-                np_dt = np.dtype(f.dtype.numpy_dtype)
-                if op == "sum":
-                    accs.append(jnp.zeros((D,), dtype=np_dt))
-                else:
-                    sent = _SENTINELS[op][_sent_kind(np_dt)](np_dt)
-                    accs.append(jnp.full((D,), sent, dtype=np_dt))
-            return accs
+            return [dense_agg.empty_table(op, D, f.dtype.numpy_dtype)
+                    for f, op in zip(buffer_schema.fields[n_keys:], ops)]
 
         def _init_res():
             res = []
             for i in residual_idx:
                 np_dt = _res_np_dtype(keys[i])
-                lo = _SENTINELS["min"][_sent_kind(np_dt)](np_dt)
-                hi = _SENTINELS["max"][_sent_kind(np_dt)](np_dt)
-                res.append((jnp.full((D,), lo, dtype=np_dt),   # vmin
-                            jnp.full((D,), hi, dtype=np_dt),   # vmax
-                            jnp.ones((D,), dtype=jnp.int8),    # validmin
-                            jnp.zeros((D,), dtype=jnp.int8)))  # validmax
+                res.append((
+                    dense_agg.empty_table("min", D, np_dt),  # vmin
+                    dense_agg.empty_table("max", D, np_dt),  # vmax
+                    jnp.ones((D,), dtype=jnp.int8),          # validmin
+                    jnp.zeros((D,), dtype=jnp.int8)))        # validmax
             return res
 
         def build_update():
             @program("agg_mdense_update")
-            def f(arrays, sel, num_rows, accs, res, present, kmin_s):
+            def f(arrays, sel, num_rows, accs, res, present, kmin_s,
+                  n_compacted):
                 cap = next(a[0].shape[0] for a in arrays
                            if a is not None)
                 active = jnp.arange(cap, dtype=jnp.int32) < num_rows
@@ -1546,50 +1524,13 @@ class AggregateExec(TpuExec):
                 idx = kd.astype(jnp.int64) - kmin_s
                 in_dom = ok & (idx >= 0) & (idx < D)
                 sidx = jnp.where(in_dom, idx, jnp.int64(D))
-                contribs = update(ectx)
-                new_accs = []
-                for (cd, cv), acc, op in zip(contribs, accs, ops):
-                    mask = in_dom if cv is None else (in_dom & cv)
-                    if op == "sum":
-                        z = jnp.zeros((), dtype=acc.dtype)
-                        new_accs.append(acc.at[sidx].add(
-                            jnp.where(mask, cd.astype(acc.dtype), z),
-                            mode="drop"))
-                    else:
-                        np_dt = np.dtype(acc.dtype)
-                        sent = acc.dtype.type(
-                            _SENTINELS[op][_sent_kind(np_dt)](np_dt))
-                        scatter = (acc.at[sidx].min if op == "min"
-                                   else acc.at[sidx].max)
-                        new_accs.append(scatter(
-                            jnp.where(mask, cd.astype(acc.dtype), sent),
-                            mode="drop"))
-                new_res = []
-                for (vmin, vmax, dmn, dmx), ri in zip(res, residual_idx):
-                    rd, rv = keys[ri].eval(ectx)
-                    rd = rd.astype(vmin.dtype)
-                    r_ok = in_dom if rv is None else (in_dom & rv)
-                    np_dt = np.dtype(vmin.dtype)
-                    lo = vmin.dtype.type(
-                        _SENTINELS["min"][_sent_kind(np_dt)](np_dt))
-                    hi = vmin.dtype.type(
-                        _SENTINELS["max"][_sent_kind(np_dt)](np_dt))
-                    nvmin = vmin.at[sidx].min(
-                        jnp.where(r_ok, rd, lo), mode="drop")
-                    nvmax = vmax.at[sidx].max(
-                        jnp.where(r_ok, rd, hi), mode="drop")
-                    v01 = jnp.where(r_ok, jnp.int8(1), jnp.int8(0))
-                    # validmin over in-domain rows (1 outside so it
-                    # never spuriously reports a null)
-                    ndmn = dmn.at[sidx].min(
-                        jnp.where(in_dom, v01, jnp.int8(1)), mode="drop")
-                    ndmx = dmx.at[sidx].max(v01, mode="drop")
-                    new_res.append((nvmin, nvmax, ndmn, ndmx))
-                present = present.at[sidx].max(
-                    jnp.where(in_dom, jnp.int8(1), jnp.int8(0)),
-                    mode="drop")
+                new_accs, new_res, present, took = dense_agg.update_tables(
+                    sidx, in_dom, update(ectx),
+                    [keys[ri].eval(ectx) for ri in residual_idx],
+                    accs, ops, res, present)
                 leftover = active & ~in_dom
-                return tuple(new_accs), tuple(new_res), present, leftover
+                return (new_accs, new_res, present, leftover,
+                        n_compacted + took)
             return f
 
         ufn = _cached_program(fp + f"|update|{pidx}|{D}", build_update)
@@ -1633,6 +1574,10 @@ class AggregateExec(TpuExec):
             # in HBM next to the D-sized accumulators
             buffered = []
             first_batch = True
+            n_batches = 0
+            # batches that scattered a compacted rung: counted on the
+            # device, read in the tail fetch
+            n_compacted = np.int32(0)
 
             def flush_leftovers():
                 if not leftovers:
@@ -1661,12 +1606,13 @@ class AggregateExec(TpuExec):
                     return
                 buffered.append(catalog.register(batch, priority=1))
                 with m.time("opTime"):
-                    accs_t, res_t, present, leftover = ufn(
+                    accs_t, res_t, present, leftover, n_compacted = ufn(
                         arrays_of(batch), batch.sel,
                         np.int32(batch.num_rows), tuple(accs),
-                        tuple(res), present, kmin_s)
+                        tuple(res), present, kmin_s, n_compacted)
                     accs = list(accs_t)
                     res = list(res_t)
+                n_batches += 1
                 if not (first_batch and key_nonnull):
                     # count prestaged: its D2H copy overlaps the next
                     # batch's dispatch instead of stalling the tail fetch
@@ -1678,11 +1624,13 @@ class AggregateExec(TpuExec):
                 if len(leftovers) >= 8:
                     flush_leftovers()
             # ONE end-of-stream fetch: violation flag + per-batch
-            # leftover counts + group count together
+            # leftover counts + group count + compacted batches together
             n_groups_dev = jnp.sum((present > 0).astype(jnp.int64))
             tail = fetch((vfn(tuple(res), present),  # fusion-ok (end-of-stream tail: one batched fetch by construction)
-                          [c for _, c in leftovers], n_groups_dev))
-            violated, left_counts, n_groups = tail
+                          [c for _, c in leftovers], n_groups_dev,
+                          n_compacted))
+            violated, left_counts, n_groups, n_compacted = tail
+            self._count_dense_batches(m, n_batches, int(n_compacted))
             if bool(violated):
                 m.add("aggDenseResidualFallback", 1)
                 try:

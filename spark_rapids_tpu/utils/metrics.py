@@ -101,6 +101,12 @@ class QueryStats:
         self.ici_feed_bytes = 0
         self.ici_exchange_bytes = 0
         self.ici_overflow_retries = 0
+        # dense aggregation (plan/physical.py, ops/dense_agg.py): batches
+        # its update programs took, and those of them whose in-domain
+        # rows fit a rung and were compacted on the device before the
+        # scatters (decided in the program, read in the tail fetch)
+        self.agg_dense_batches = 0
+        self.agg_dense_compacted_batches = 0
         # the query's host-time account (utils/tracing.account): nine
         # disjoint terms of the DRIVING thread's time, by span self
         # time, that sum to ``query_wall_s``.  Unlike fetch_wait_s /

@@ -196,6 +196,12 @@ METRICS = (
     ("query_ici_overflow_retries_total", "counter", "",
      "Fragments re-run at 4x capacities after a bucket or join "
      "expansion overflowed."),
+    ("query_agg_dense_batches_total", "counter", "",
+     "Batches the dense aggregation's update programs took."),
+    ("query_agg_dense_compacted_batches_total", "counter", "",
+     "Dense-aggregation batches whose in-domain rows were compacted on "
+     "the device before the scatters (chosen in the program from the "
+     "row count)."),
     # the host-time account (utils/tracing.account): disjoint shares of
     # the driving thread's time; the nine terms sum to the wall
     ("query_wall_seconds_total", "counter", "",
@@ -379,6 +385,9 @@ _QS_FOLD = (
     ("ici_feed_bytes", "query_ici_feed_bytes_total"),
     ("ici_exchange_bytes", "query_ici_exchange_bytes_total"),
     ("ici_overflow_retries", "query_ici_overflow_retries_total"),
+    ("agg_dense_batches", "query_agg_dense_batches_total"),
+    ("agg_dense_compacted_batches",
+     "query_agg_dense_compacted_batches_total"),
     ("query_wall_s", "query_wall_seconds_total"),
     ("acct_plan_s", "query_acct_plan_seconds_total"),
     ("acct_admit_s", "query_acct_admit_seconds_total"),

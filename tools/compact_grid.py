@@ -13,6 +13,22 @@ form): milliseconds a call (host clock around ``block_until_ready``, best
 of the repeats) and whether the output equals the per-array scatter's.
 ``new_cap`` is ``cap * 2**ratio``, at least 1024.  1 array is one int32
 column; 8 are int32, int64, float64, float64, each with validity.
+
+    python3 tools/compact_grid.py --grid dense
+        [--out chiprun_out/dense_grid.jsonl] [--caps 18,21]
+        [--shares 0.0001,0.005,0.1,0.75] [--shifts 6]
+
+The dense aggregation's update itself (``ops/dense_agg.update_tables``:
+one float64 sum and one int64 residual key, so three 64-bit scatters and
+three 8-bit ones into 4,194,304-slot tables), over batches of which a
+given share is bound for the tables: the bare scatter (``full``: the
+rule pinned to no rung), the program as it ships (``rule``: the rung of
+``batch_utils.scatter_rung`` behind its ``lax.cond``) and, to price a
+rung the rule does not offer, ``cap >> shift`` pinned for each of
+``--shifts``: run it again before trusting the rule's prices on another
+chip.  One JSON line per (cap, share, path): milliseconds a call, the
+rung, the branch the program takes at that share, and whether the
+tables equal the bare scatter's bit for bit.
 """
 import argparse
 import json
@@ -20,6 +36,7 @@ import os
 import sys
 import time
 from concurrent.futures import ThreadPoolExecutor
+from unittest import mock
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -28,7 +45,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-from spark_rapids_tpu.ops import batch_utils  # noqa: E402
+from spark_rapids_tpu.ops import batch_utils, dense_agg  # noqa: E402
 
 SCATTER_EACH = "scatter_each"
 
@@ -70,15 +87,91 @@ def timed(fn, args, min_s, max_reps=20):
     return out, best, reps
 
 
+DENSE_SLOTS = 1 << 22
+
+
+def dense_grid(a, fh):
+    """``--grid dense``: the dense update, full path against each rung."""
+    d = DENSE_SLOTS
+    rng = np.random.default_rng(30)
+
+    def tables():
+        return ((jnp.zeros((d,), jnp.float64),),
+                ((dense_agg.empty_table("min", d, np.int64),
+                  dense_agg.empty_table("max", d, np.int64),
+                  jnp.ones((d,), jnp.int8), jnp.zeros((d,), jnp.int8)),),
+                jnp.zeros((d,), jnp.int8))
+
+    def build():
+        # a function of its own each time: jit keeps one trace a function
+        def f(sidx, in_dom, cd, rd, accs, res, present):
+            return dense_agg.update_tables(
+                sidx, in_dom, [(cd, None)], [(rd, None)], accs, ("sum",),
+                res, present)
+        return jax.jit(f)
+
+    for cap in (1 << int(c) for c in a.caps.split(",")):
+        rule = batch_utils.scatter_rung(cap, 3, 3)
+        paths = [("full", None), ("rule", rule)] + [
+            (f"rung:{cap >> k}", cap >> k)
+            for k in map(int, a.shifts.split(",")) if cap >> k != rule]
+        slot = rng.integers(0, d, cap).astype(np.int64)
+        cd = jnp.asarray(rng.random(cap))
+        rd = jnp.asarray(slot * 3)
+        # one program a path: the shares of a capacity share its compile
+        fns = {}
+        for path, rung in paths:
+            with mock.patch.object(batch_utils, "scatter_rung",
+                                   lambda *_, rung=rung: rung):
+                fns[path] = build().lower(
+                    jnp.asarray(slot), jnp.zeros((cap,), bool), cd, rd,
+                    *tables()).compile()
+        for share in map(float, a.shares.split(",")):
+            n_live = int(cap * share)
+            in_dom = np.zeros(cap, bool)
+            in_dom[rng.choice(cap, n_live, replace=False)] = True
+            args = (jnp.asarray(np.where(in_dom, slot, d)),
+                    jnp.asarray(in_dom), cd, rd) + tables()
+            ref = None
+            for path, rung in paths:
+                out, best, reps = timed(fns[path], args, a.min_s)
+                flat = jax.tree_util.tree_leaves(out[:3])
+                ref = ref or flat
+                rec = {"grid": "dense", "cap": cap, "slots": d,
+                       "share": share, "n_live": n_live, "path": path,
+                       "rung": rung,
+                       "takes": "rung" if int(out[3]) else "full",
+                       "ms": round(best * 1e3, 4), "reps": reps,
+                       "equal": all(bool(jnp.array_equal(x, y))
+                                    for x, y in zip(ref, flat)),
+                       "device": jax.devices()[0].device_kind}
+                print(json.dumps(rec), flush=True)
+                fh.write(json.dumps(rec) + "\n")
+                if not rec["equal"]:
+                    sys.exit(f"{path} differs from the full path")
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
-    ap.add_argument("--out", default="chiprun_out/compact_grid.jsonl")
-    ap.add_argument("--caps", default="17,20,22,24")
+    ap.add_argument("--grid", choices=("compact", "dense"),
+                    default="compact")
+    ap.add_argument("--out")
+    ap.add_argument("--shares", default="0.0001,0.005,0.1,0.75")
+    ap.add_argument("--caps", help="log2 of the capacities; default "
+                    "17,20,22,24 (compact), 18,21 (dense)")
+    ap.add_argument("--shifts", default="6", help="dense: rungs to pin, "
+                    "as cap >> shift, besides the rule's own")
     ap.add_argument("--ratios", default="-14,-10,-6,-3,-1,0")
     ap.add_argument("--arrays", default="1,8")
     ap.add_argument("--min-s", type=float, default=0.2)
     a = ap.parse_args()
+    a.out = a.out or f"chiprun_out/{a.grid}_grid.jsonl"
+    a.caps = a.caps or {"compact": "17,20,22,24", "dense": "18,21"}[a.grid]
     os.makedirs(os.path.dirname(a.out) or ".", exist_ok=True)
+    if a.grid == "dense":
+        with open(a.out, "w") as fh:
+            dense_grid(a, fh)
+        return
     forms = (SCATTER_EACH,) + batch_utils.COMPACT_FORMS
     rng = np.random.default_rng(27)
     with open(a.out, "w") as fh:
