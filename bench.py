@@ -319,7 +319,6 @@ def _run_one(name: str, sf: float, iters: int) -> dict:
         "overlap_s": round(max(0.0, warm_stats["pipeline_stage_s"]
                                - warm_stats["h2d_wait_s"]), 4),
         "fetch_wait_s": warm_stats["fetch_wait_s"],
-        "donated_warm": warm_stats["donated_batches"],
         # cross-query cache profile: hits per warm iteration and the MB
         # served from HBM instead of decode+upload (0s when
         # SRT_BENCH_CACHE=0 — the printed A/B evidence)
@@ -354,11 +353,9 @@ def _run_concurrent(sf: float, conc: int, which) -> None:
     from spark_rapids_tpu.utils.metrics import QueryStats
 
     settings = {
-        # host decoded-file cache stays on in BOTH A/B passes; the
-        # legacy per-scan device tier is off in both so the A/B
-        # isolates the cross-query cache (its successor subsystem)
+        # host decoded-file cache stays on in BOTH A/B passes, so the
+        # A/B isolates the cross-query cache
         "spark.rapids.tpu.sql.fileCache.enabled": True,
-        "spark.rapids.tpu.sql.fileCache.deviceTier": False,
         "spark.rapids.tpu.sql.scheduler.maxConcurrent": conc,
         "spark.rapids.tpu.sql.concurrentTpuTasks": conc,
     }

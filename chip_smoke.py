@@ -56,8 +56,8 @@ _CACHE_EVENTS = {
     "/jax/compilation_cache/cache_misses": "writes",
 }
 _STAT_KEYS = ["blocking_fetches", "async_fetches", "fetch_wait_s",
-              "h2d_wait_s", "compiles", "compile_s", "donated_batches",
-              "degraded_batches", "transient_retries", "fused_regions"]
+              "h2d_wait_s", "compiles", "compile_s", "degraded_batches",
+              "transient_retries", "fused_regions"]
 # operators the planner places on the CPU today, by query: accounted for
 # here by name and reason instead of dropping the placement check
 CPU_PLACED = {

@@ -276,16 +276,9 @@ class ColumnBatch:
     ``bound`` (optional) is a STATIC upper limit on live rows, set by
     bounded producers (dense-grid aggregation): it lets downstream
     compaction stay sync-free (ops/batch_utils.compact_packed).
-
-    ``donatable`` marks a batch whose device buffers have exactly ONE
-    consumer: a fused stage program may donate them to XLA (HBM reuse).
-    Producers of fresh single-consumer uploads set it True; anything
-    that creates a second reference (spill registration, the device-tier
-    file cache) clears it — see SpillableBatch.__init__ and ScanExec.
     """
 
     bound = None
-    donatable = False
 
     def __init__(self, schema: Schema, columns: Sequence[Column], num_rows: int,
                  sel: Optional[jax.Array] = None):

@@ -23,9 +23,8 @@ work's RESULTS resident across queries:
     but never one a query currently holds (refcounts).
 
 Entries are held through :class:`..memory.spill.SpillableBatch` handles,
-which pin ``ColumnBatch.donatable=False`` (a fused stage must never
-donate a cached buffer to XLA) and re-materialize transparently after a
-spill demotion.  All lookups/insertions key through
+which re-materialize transparently after a spill demotion.  All
+lookups/insertions key through
 :mod:`.keys` (the srtlint ``cache-keys`` pass enforces it).
 """
 
@@ -326,7 +325,7 @@ class QueryCache:
                 else:
                     cols = b.columns
                 # fresh wrapper: consumers can't perturb cached row
-                # accounting, and donatable stays False (shared arrays)
+                # accounting
                 out.append(ColumnBatch(schema, cols, b.num_rows, b.sel))
                 served += batch_bytes(out[-1])
         except integrity.IntegrityFault:

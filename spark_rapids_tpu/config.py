@@ -357,16 +357,6 @@ PIPELINE_DEPTH = register(
     "always wins.",
     check=lambda v: None if v >= 0 else "must be >= 0")
 
-PIPELINE_DONATION = register(
-    "spark.rapids.tpu.sql.pipeline.donation", True,
-    "Donate the input device buffers of fused stage programs to XLA "
-    "(jax.jit donate_argnums) so the output reuses the input's HBM — "
-    "steady-state churn drops and the spill budget sees real headroom. "
-    "Only single-consumer batches are donated (never cached or "
-    "spill-registered ones), and a donated batch cannot be replayed by "
-    "the OOM retry path: disable this when debugging OOM-heavy "
-    "workloads. No-op on the CPU backend (XLA ignores donation there).")
-
 HBM_POOL_FRACTION = register(
     "spark.rapids.tpu.memory.tpu.poolFraction", 0.9,
     "Fraction of free TPU HBM the arena manages for batch storage; "
@@ -586,24 +576,14 @@ FILE_CACHE_ENABLED = register(
     "spark.rapids.tpu.sql.fileCache.enabled", False,
     "Cache decoded Arrow tables of scanned files in host memory (keyed by "
     "path+mtime+columns+row-groups) so repeated scans skip the parquet "
-    "decode. Analog of the reference's FileCache (filecache.md).")
+    "decode. Analog of the reference's FileCache (filecache.md). Host "
+    "tier only: uploaded batches stay on the device under "
+    "sql.cache.enabled + sql.cache.scan.enabled.")
 
 FILE_CACHE_MAX_BYTES = register(
     "spark.rapids.tpu.sql.fileCache.maxBytes", 4 << 30,
     "Byte budget for the decoded-file cache; least-recently-used files are "
     "evicted beyond it.")
-
-FILE_CACHE_DEVICE_TIER = register(
-    "spark.rapids.tpu.sql.fileCache.deviceTier", True,
-    "When the file cache is enabled, additionally keep the *uploaded* device "
-    "batches of repeated identical scans resident in HBM (LRU under "
-    "fileCache.device.maxBytes), so steady-state queries skip the host→HBM "
-    "upload entirely. The ShuffleBufferCatalog keep-it-on-device idea "
-    "(RapidsShuffleInternalManagerBase.scala:897) applied to scans.")
-
-FILE_CACHE_DEVICE_MAX_BYTES = register(
-    "spark.rapids.tpu.sql.fileCache.device.maxBytes", 2 << 30,
-    "HBM byte budget for the device tier of the file cache.")
 
 CACHE_ENABLED = register(
     "spark.rapids.tpu.sql.cache.enabled", False,

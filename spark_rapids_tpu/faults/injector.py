@@ -193,9 +193,7 @@ class FaultInjector:
 
     # -- state --------------------------------------------------------------------
     def armed(self) -> bool:
-        """True while any injection (schedule or rate) can fire — buffer
-        donation must not engage (a donated batch cannot be replayed by
-        the retry/degradation paths)."""
+        """True while any injection (schedule or rate) can fire."""
         with self._lock:
             return bool(self._sched) or self._rate > 0.0
 

@@ -8,10 +8,9 @@ tables keyed by (path, mtime, size, columns, row-groups) with LRU eviction
 under a byte budget, so repeated scans skip decode and go straight to the
 host→HBM upload.
 
-:class:`DeviceBatchCache` is the second tier: uploaded device batches of
-repeated identical scans stay HBM-resident.  Because those bytes are
-invisible to the spill catalog, the OOM path (memory/retry.py device_op)
-clears this tier before retrying.
+The device tier above it (uploaded batches kept in HBM across queries) is
+the cross-query cache in ``spark_rapids_tpu/cache/``, which the spill
+catalog sees.
 """
 
 from __future__ import annotations
@@ -21,8 +20,7 @@ import threading
 from collections import OrderedDict
 from typing import List, Optional, Tuple
 
-__all__ = ["FileCache", "DeviceBatchCache", "get_file_cache",
-           "get_device_cache", "clear_file_cache", "clear_device_cache"]
+__all__ = ["FileCache", "get_file_cache", "clear_file_cache"]
 
 
 class FileCache:
@@ -90,33 +88,7 @@ class FileCache:
             self._bytes = 0
 
 
-class DeviceBatchCache(FileCache):
-    """LRU cache of *uploaded* scan output (device-resident ColumnBatch lists).
-
-    Second tier above :class:`FileCache`: where FileCache skips the parquet
-    decode, this skips the host→HBM upload as well, keyed by the scan's full
-    identity (source token embeds files, projection, and pushed predicates).
-    Entries are immutable by convention — every operator in this engine
-    builds new batches rather than mutating inputs — and ScanExec re-wraps
-    them on both populate and hit so callers can't perturb cached row
-    accounting.
-    """
-
-    @staticmethod
-    def _batch_bytes(b) -> int:
-        total = b.device_size_bytes()
-        for c in b.columns:
-            arr = getattr(c, "array", None)  # HostStringColumn
-            if arr is not None:
-                total += arr.nbytes
-        return total
-
-    def _entry_bytes(self, values: list) -> int:
-        return sum(self._batch_bytes(b) for b in values)
-
-
 _cache: Optional[FileCache] = None
-_device_cache: Optional[DeviceBatchCache] = None
 _cache_lock = threading.Lock()
 
 
@@ -130,31 +102,10 @@ def get_file_cache(max_bytes: int) -> FileCache:
         return _cache
 
 
-def get_device_cache(max_bytes: int) -> DeviceBatchCache:
-    global _device_cache
-    with _cache_lock:
-        if _device_cache is None:
-            _device_cache = DeviceBatchCache(max_bytes)
-        elif _device_cache.max_bytes != max_bytes:
-            _device_cache.set_max_bytes(max_bytes)
-        return _device_cache
-
-
-def clear_device_cache() -> None:
-    """Drop all HBM-resident cached scan batches (called by the OOM-retry
-    path: these bytes are not in the spill catalog, so spilling alone cannot
-    free them)."""
-    with _cache_lock:
-        if _device_cache is not None:
-            _device_cache.clear()
-
-
 def clear_file_cache() -> None:
     with _cache_lock:
         if _cache is not None:
             _cache.clear()
-        if _device_cache is not None:
-            _device_cache.clear()
     # the cross-query cache (spark_rapids_tpu/cache/) composes ABOVE this
     # host tier — "drop every cached scan" should mean both layers
     from ..cache import clear_query_cache

@@ -50,12 +50,6 @@ class OOMInjector:
             self.remaining = n_retry
             self.split_remaining = n_split
 
-    def armed(self) -> bool:
-        """True while injected OOMs are pending: buffer donation must not
-        engage (a donated batch cannot be replayed by the retry loop)."""
-        with self._lock:
-            return self.remaining > 0 or self.split_remaining > 0
-
     def maybe_raise(self) -> None:
         with self._lock:
             if self.remaining > 0:
@@ -97,10 +91,6 @@ def device_op(ctx, fn: Callable, *args):
         if _is_xla_oom(ex):
             catalog = get_catalog(ctx.conf if ctx is not None else None)
             catalog.spill_all_device()
-            # cached scan batches live outside the catalog: drop them too or
-            # the retry re-OOMs against memory spilling cannot reach
-            from ..io.filecache import clear_device_cache
-            clear_device_cache()
             # the cross-query cache IS catalog-registered (its device
             # bytes just spilled to host above); dropping unpinned
             # entries additionally frees the host copies before retry

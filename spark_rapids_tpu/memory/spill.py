@@ -50,9 +50,6 @@ class SpillableBatch:
     def __init__(self, batch: ColumnBatch, catalog: "SpillCatalog",
                  priority: int = 0):
         self._batch: Optional[ColumnBatch] = batch
-        # the handle is a second reference to these device buffers: a
-        # fused stage program must never donate them out from under it
-        batch.donatable = False
         self._host: Optional[dict] = None
         self._disk_path: Optional[str] = None
         self._catalog = catalog

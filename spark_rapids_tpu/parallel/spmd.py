@@ -338,9 +338,8 @@ class _Join:
                 f"{self.right.fingerprint()}>")
 
     def emit(self, env):
-        import jax.numpy as jnp
         from ..exprs import EvalContext, promote_physical
-        from ..ops.groupby import _segment_starts, group_sort_indices
+        from ..ops.join import match_ranges, rows_ok
 
         how = self.how
         l_arrays, l_active = self.left.emit(env)
@@ -366,36 +365,9 @@ class _Join:
                else (promote_physical(d, e.dtype, ct), v)
                for (d, v), e, ct in zip(bkv, bk, common)]
 
-        def _ok(kvs, act):
-            ok = act
-            for _d, v in kvs:
-                if v is not None:
-                    ok = ok & v
-            return ok
-
-        p_ok = _ok(pkv, probe_active)
-        b_ok = _ok(bkv, build_active)
-        BIG = jnp.int32(2**31 - 1)
-        keys = [(jnp.concatenate([pd, bd]), None)
-                for (pd, _), (bd, _) in zip(pkv, bkv)]
-        union_ok = jnp.concatenate([p_ok, b_ok])
-        perm = group_sort_indices(keys, union_ok)
-        s_keys = [(d[perm], None) for d, _ in keys]
-        s_ok = union_ok[perm]
-        starts = _segment_starts(s_keys, s_ok)
-        gid_sorted = jnp.cumsum(starts.astype(jnp.int32)) - 1
-        gid = jnp.zeros((p_cap + b_cap,), dtype=jnp.int32)
-        gid = gid.at[perm].set(jnp.where(s_ok, gid_sorted, BIG))
-        p_gid = jnp.where(p_ok, gid[:p_cap], -1)
-        b_gid = jnp.where(b_ok, gid[p_cap:], BIG)
-        # build rows of one key in any order: every match is emitted
-        b_perm = jnp.argsort(b_gid, stable=False)
-        b_gid_sorted = b_gid[b_perm]
-        lo = jnp.searchsorted(b_gid_sorted, p_gid, side="left").astype(
-            jnp.int32)
-        hi = jnp.searchsorted(b_gid_sorted, p_gid, side="right").astype(
-            jnp.int32)
-        matches = jnp.where(p_ok, hi - lo, 0)
+        lo, matches, b_perm = match_ranges(
+            pkv, bkv, rows_ok(pkv, probe_active),
+            rows_ok(bkv, build_active))
 
         if how in ("semi", "anti"):
             sel = (matches > 0) if how == "semi" else (matches == 0)
@@ -404,7 +376,7 @@ class _Join:
         else:
             out, active = self._expand(
                 env, how, probe_arrays, probe_active, build_arrays,
-                build_active, lo, matches, b_perm, p_cap, b_cap)
+                build_active, lo, matches, b_perm)
 
         if self.condition is not None:
             cctx = EvalContext(list(out), active.shape[0], active=active)
@@ -414,36 +386,26 @@ class _Join:
         return out, active
 
     def _expand(self, env, how, probe_arrays, probe_active, build_arrays,
-                build_active, lo, matches, b_perm, p_cap, b_cap):
+                build_active, lo, matches, b_perm):
         import jax.numpy as jnp
+        from ..ops.join import expand_pairs, unmatched_build
         out_cap = self.cap
+        b_cap = build_active.shape[0]
         outer = how in ("left", "right", "full")
         counts = jnp.maximum(matches, 1) if outer else matches
         counts = jnp.where(probe_active, counts, 0)
         offsets = jnp.cumsum(counts)
         total = offsets[-1]
-        j = jnp.arange(out_cap, dtype=jnp.int32)
-        pi = jnp.searchsorted(offsets, j, side="right").astype(jnp.int32)
-        pi_c = jnp.clip(pi, 0, p_cap - 1)
-        start = jnp.where(pi_c > 0, offsets[jnp.clip(pi_c - 1, 0, p_cap - 1)],
-                          0)
-        k = j - start
-        in_range = j < total
-        matched = in_range & (k < matches[pi_c])
-        bi = b_perm[jnp.clip(lo[pi_c] + k, 0, b_cap - 1)]
-        bi = jnp.where(matched, bi, -1)
-        p_idx = jnp.where(in_range, pi_c, -1)
+        pi, bi, _matched = expand_pairs(offsets, counts, lo, matches,
+                                        b_perm, out_cap)
+        # the capacity is static here: slots past the rows emitted are
+        # padding, and rows past the capacity are reported, not dropped
+        in_range = jnp.arange(out_cap, dtype=jnp.int32) < total
+        p_idx = jnp.where(in_range, pi, -1)
         grand_total = total
         if how == "full":
             # build rows matched by no probe row emit null-probe output rows
-            inc = jnp.zeros((b_cap + 1,), dtype=jnp.int32)
-            inc = inc.at[jnp.clip(lo, 0, b_cap)].add(
-                jnp.where(matches > 0, 1, 0))
-            ends = jnp.clip(lo + matches, 0, b_cap)
-            inc = inc.at[ends].add(jnp.where(matches > 0, -1, 0))
-            hit_sorted = jnp.cumsum(inc[:-1]) > 0
-            hit = jnp.zeros((b_cap,), dtype=bool).at[b_perm].set(hit_sorted)
-            b_un = build_active & ~hit
+            b_un = unmatched_build(lo, matches, b_perm, build_active)
             extra = jnp.sum(b_un.astype(jnp.int32))
             dest = total + jnp.cumsum(b_un.astype(jnp.int32)) - 1
             dest = jnp.where(b_un, dest, out_cap)  # drop non-unmatched

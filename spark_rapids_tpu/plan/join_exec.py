@@ -6,16 +6,16 @@ hash tables are a poor fit for XLA (SURVEY §7.3 prescribes sort-based joins
 on TPU), so the algorithm here is:
 
   1. evaluate join keys on both sides, promoted to a common type;
-  2. **union group-id encoding**: concatenate both sides' keys, sort once,
-     mark segment starts, and give every row a dense group id — equal keys on
-     either side share an id (nulls never match, as in SQL equi-join);
-  3. sort the build side by group id; for every probe row a pair of
-     ``searchsorted`` calls yields its match range [lo, hi);
-  4. semi/anti joins finish here as a selection mask (no data movement);
+  2. union group ids and every probe row's match range [lo, lo + matches)
+     over the build side sorted by id (``ops/join.match_ranges``; nulls
+     never match, as in SQL equi-join);
+  3. semi/anti joins finish here as a selection mask (no data movement);
      inner/outer joins compute per-row output counts, sync ONCE to learn the
-     total, and run a static-shape **expansion gather**: output slot j maps
-     to probe row ``searchsorted(cumsum(counts), j)`` and build row
-     ``perm[lo + (j - start)]``, with unmatched outer rows emitting nulls.
+     total, and run a static-shape **expansion gather** at that capacity
+     (``ops/join.expand_pairs``), with unmatched outer rows emitting nulls.
+
+The kernels of steps 2 and 3 live in ``ops/join.py``, which the mesh's
+shuffled join (``parallel/spmd.py``) traces too.
 
 Every compiled program is cached by structural fingerprint + shape bucket, so
 repeated joins of the same shape reuse executables (SURVEY §7.2).
@@ -34,14 +34,13 @@ from ..batch import (ColumnBatch, DeviceColumn, DictStringColumn, Field,
                      HostStringColumn, Schema, bucket_capacity)
 from ..exprs import EvalContext, Expression, promote_physical
 from ..ops import batch_utils
-from ..ops.groupby import group_sort_indices, _segment_starts
+from ..ops.join import (expand_pairs, match_ranges, rows_ok,
+                        unmatched_build)
 from ..utils.metrics import current_region, fetch, region_scalars, \
     stage_scalars
 from .physical import ExecContext, TpuExec, _cached_program, program
 
 __all__ = ["SortMergeJoinExec"]
-
-_BIG = np.int32(2**31 - 1)
 
 
 def bound_join_keys(plan, lsch: Schema, rsch: Schema):
@@ -471,16 +470,8 @@ class SortMergeJoinExec(TpuExec):
         def build_fn():
             @program("join_cond_expand")
             def f(offsets, counts, lo, matches, b_perm, out_cap_arr):
-                out_cap_ = out_cap_arr.shape[0]
-                pi_c = _expand_rows(offsets, counts, out_cap_)
-                start = jnp.where(pi_c > 0,
-                                  offsets[jnp.clip(pi_c - 1, 0, None)], 0)
-                j = jnp.arange(out_cap_, dtype=jnp.int32)
-                k = j - start
-                in_range = k < matches[pi_c]
-                bi = b_perm[jnp.clip(lo[pi_c] + k, 0,
-                                     b_perm.shape[0] - 1)]
-                return pi_c, jnp.where(in_range, bi, -1), in_range
+                return expand_pairs(offsets, counts, lo, matches, b_perm,
+                                    out_cap_arr.shape[0])
             return f
 
         fn = _cached_program("join-condexpand|" + fp, build_fn)
@@ -649,34 +640,8 @@ class SortMergeJoinExec(TpuExec):
                 bkv = [(d, v) if ct.is_string
                        else (promote_physical(d, e.dtype, ct), v)
                        for (d, v), e, ct in zip(bkv, bk, common)]
-                # null keys never match
-                def _ok(kvs, active):
-                    ok = active
-                    for d, v in kvs:
-                        if v is not None:
-                            ok = ok & v
-                    return ok
-                p_ok = _ok(pkv, p_active)
-                b_ok = _ok(bkv, b_active)
-                keys = [(jnp.concatenate([pd, bd]), None)
-                        for (pd, _), (bd, _) in zip(pkv, bkv)]
-                union_ok = jnp.concatenate([p_ok, b_ok])
-                perm = group_sort_indices(keys, union_ok)
-                s_keys = [(d[perm], None) for d, _ in keys]
-                s_ok = union_ok[perm]
-                starts = _segment_starts(s_keys, s_ok)
-                gid_sorted = jnp.cumsum(starts.astype(jnp.int32)) - 1
-                gid = jnp.zeros((p_cap + b_cap,), dtype=jnp.int32)
-                gid = gid.at[perm].set(jnp.where(s_ok, gid_sorted, _BIG))
-                p_gid = jnp.where(p_ok, gid[:p_cap], -1)
-                b_gid = jnp.where(b_ok, gid[p_cap:], _BIG)
-                # sort build rows by gid (non-matching rows park at the end)
-                b_perm = jnp.argsort(b_gid)
-                b_gid_sorted = b_gid[b_perm]
-                lo = jnp.searchsorted(b_gid_sorted, p_gid, side="left")
-                hi = jnp.searchsorted(b_gid_sorted, p_gid, side="right")
-                matches = jnp.where(p_ok, (hi - lo).astype(jnp.int32), 0)
-                return lo.astype(jnp.int32), matches, b_perm.astype(jnp.int32)
+                return match_ranges(pkv, bkv, rows_ok(pkv, p_active),
+                                    rows_ok(bkv, b_active))
             return f
 
         fn = _cached_program("join-match|" + fp, build_fn)
@@ -723,14 +688,10 @@ class SortMergeJoinExec(TpuExec):
         def build_fn():
             @program("join_expand")
             def f(offsets, counts, lo, matches, b_perm, out_cap_arr):
-                out_cap_ = out_cap_arr.shape[0]
-                pi_c = _expand_rows(offsets, counts, out_cap_)
-                start = jnp.where(pi_c > 0, offsets[pi_c - 1], 0)
-                j = jnp.arange(out_cap_, dtype=jnp.int32)
-                k = j - start
-                matched = k < matches[pi_c]
-                bi = b_perm[jnp.clip(lo[pi_c] + k, 0, b_perm.shape[0] - 1)]
-                return pi_c, jnp.where(matched, bi, -1)
+                pi, bi, _matched = expand_pairs(
+                    offsets, counts, lo, matches, b_perm,
+                    out_cap_arr.shape[0])
+                return pi, bi
             return f
 
         fn = _cached_program("join-expand|" + fp, build_fn)
@@ -754,17 +715,9 @@ class SortMergeJoinExec(TpuExec):
         def build_fn():
             @program("join_unmatched")
             def f(lo, matches, b_perm, n_build):
-                b_cap = b_perm.shape[0]
-                hit_sorted = jnp.zeros((b_cap,), dtype=jnp.int32)
-                # scatter-add match ranges: mark [lo, lo+matches) as hit
-                inc = jnp.zeros((b_cap + 1,), dtype=jnp.int32)
-                inc = inc.at[lo].add(jnp.where(matches > 0, 1, 0))
-                ends = jnp.clip(lo + matches, 0, b_cap)
-                inc = inc.at[ends].add(jnp.where(matches > 0, -1, 0))
-                hit_sorted = jnp.cumsum(inc[:-1]) > 0
-                hit = jnp.zeros((b_cap,), dtype=bool).at[b_perm].set(hit_sorted)
-                b_active = jnp.arange(b_cap, dtype=jnp.int32) < n_build
-                return b_active & ~hit
+                b_active = (jnp.arange(b_perm.shape[0], dtype=jnp.int32)
+                            < n_build)
+                return unmatched_build(lo, matches, b_perm, b_active)
             return f
 
         fn = _cached_program("join-unmatched|" + fp, build_fn)
@@ -1644,32 +1597,6 @@ class BroadcastJoinExec(SortMergeJoinExec):
             self._dense_stats_host = None
             self._cache_entry = None
             self._exec_ctx = None
-
-
-@jax.named_scope("join_expand_rows")
-def _expand_rows(offsets, counts, out_cap: int):
-    """Output-slot -> probe-row map for count expansion, WITHOUT the
-    searchsorted-over-output pass (measured ~35x slower than a gather on
-    this chip: a 4M searchsorted costs ~700 ms, scatter+scan ~20 ms).
-
-    Each probe row with counts[i] > 0 owns the contiguous output range
-    [offsets[i]-counts[i], offsets[i]).  Scatter (i+1) at each range
-    start, then a running max assigns every slot its owning row.
-    Padding slots (>= total) inherit the last row; callers mask them via
-    the k < matches check exactly as with searchsorted."""
-    starts = (offsets - counts).astype(jnp.int32)
-    n = offsets.shape[0]
-    i1 = jnp.arange(1, n + 1, dtype=jnp.int32)
-    seg = jnp.zeros((out_cap,), dtype=jnp.int32).at[
-        jnp.where(counts > 0, starts, out_cap)].max(
-        i1, mode="drop")
-    # lax.cummax, NOT associative_scan(maximum): the generic scan's
-    # unrolled slice tree hangs the TPU compiler beyond ~2M elements,
-    # while the cumulative-op primitive compiles in seconds and runs
-    # 5.7x faster than the searchsorted it replaces (measured 135 ms
-    # vs 774 ms at 4M output rows)
-    pi = jax.lax.cummax(seg) - 1
-    return jnp.clip(pi, 0, n - 1)
 
 
 def _float_orderable(d, ik):

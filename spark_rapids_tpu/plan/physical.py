@@ -205,9 +205,8 @@ class ScanExec(TpuExec):
 
         # cross-query device cache (spark_rapids_tpu/cache/): a hit skips
         # decode AND upload across QUERIES, not just reruns of this plan;
-        # a cached superset projection serves narrower scans by slicing.
-        # When engaged it supersedes the per-scan fileCache device tier
-        # below (the host decoded-file cache still composes on misses).
+        # a cached superset projection serves narrower scans by slicing
+        # (the host decoded-file cache still composes on misses).
         from ..cache import cache_enabled
         qcache = None
         qkey = None
@@ -236,40 +235,11 @@ class ScanExec(TpuExec):
                         qcache.release(entry)
                     return
 
-        # device-tier file cache: repeated identical scans skip decode AND
-        # upload (fileCache.deviceTier; keep-batches-resident idea from
-        # RapidsShuffleInternalManagerBase.scala:897 applied to scans)
-        dcache = None
-        dkey = None
-        if (qcache is None
-                and ctx.conf["spark.rapids.tpu.sql.fileCache.enabled"]
-                and ctx.conf["spark.rapids.tpu.sql.fileCache.deviceTier"]):
-            token_fn = getattr(source, "cache_token", None)
-            token = token_fn() if token_fn is not None else None
-            if token is not None:
-                from ..io.filecache import get_device_cache
-                dcache = get_device_cache(
-                    ctx.conf["spark.rapids.tpu.sql.fileCache.device.maxBytes"])
-                dkey = (token, min_cap, str(ctx.device))
-                hit = dcache.get(dkey)
-                if hit is not None:
-                    origin = str(getattr(source, "path", "") or "")
-                    for b in hit:
-                        m.add("numOutputRows", b.num_rows)
-                        m.add("numOutputBatches", 1)
-                        # fresh wrapper: callers can't perturb cached state
-                        out = _CB(b.schema, b.columns, b.num_rows, b.sel)
-                        out.origin_file = origin
-                        yield out
-                    return
-
         # the accumulator pins batches in HBM until the scan completes, so
         # abandon it the moment the running size exceeds the cache budget —
         # an over-budget scan must keep streaming/spilling, not OOM
         from ..cache import batch_bytes as _cb_bytes
-        acc = [] if (dcache is not None or qcache is not None) else None
-        acc_cap = qcache.max_bytes if qcache is not None else \
-            (dcache.max_bytes if dcache is not None else 0)
+        acc = [] if qcache is not None else None
         acc_bytes = 0
         origin = str(getattr(source, "path", "") or "")
 
@@ -297,26 +267,16 @@ class ScanExec(TpuExec):
             m.add("numOutputBatches", 1)
             if acc is not None:
                 acc_bytes += _cb_bytes(b)
-                if acc_bytes > acc_cap:
+                if acc_bytes > qcache.max_bytes:
                     acc = None
-                    b.donatable = True  # won't be cached after all
                 else:
                     acc.append(b)
                     # re-wrap on the populate path too: consumers must never
-                    # hold the object that sits in the cache (the wrapper
-                    # also stays non-donatable: its arrays ARE the cache's)
+                    # hold the object that sits in the cache
                     b = _CB(b.schema, b.columns, b.num_rows, b.sel)
-            else:
-                # fresh upload with exactly one consumer: fused stages may
-                # donate these buffers back to XLA
-                b.donatable = True
             yield b
         if acc is not None:
-            if qcache is not None:
-                qcache.insert_scan(qkey, acc, op_id=self.op_id,
-                                   conf=ctx.conf)
-            else:
-                dcache.put(dkey, acc)
+            qcache.insert_scan(qkey, acc, op_id=self.op_id, conf=ctx.conf)
 
 
 # ---------------------------------------------------------------------------------
@@ -365,7 +325,7 @@ def _cached_program(fp: str, build: Callable[[], Callable]) -> Callable:
 # ---------------------------------------------------------------------------------
 
 PROGRAM_NAMES = frozenset((
-    "stage", "stage_donate", "window",
+    "stage", "window",
     "agg_ungrouped", "agg_ungrouped_fused", "agg_ungrouped_merge",
     "agg_ungrouped_finalize", "agg_dense_stats", "agg_dense_update",
     "agg_mdense_stats", "agg_mdense_update", "agg_mdense_violation",
@@ -551,21 +511,7 @@ class StageExec(TpuExec):
         fn = _cached_program(
             "stage|" + fp,
             lambda: program("stage", self._build_fn(in_schema, ansi=ansi)))
-        # donation variant: single-consumer input batches hand their HBM
-        # to XLA (output reuses input buffers → steady-state churn drops).
-        # A separate cached executable — the donating and non-donating
-        # programs coexist because cached/spilled batches must never
-        # donate (see ColumnBatch.donatable).
-        from ..runtime.pipeline import (donation_supported, effective_depth,
-                                        pipeline_batches)
-        fn_donate = None
-        if ctx.conf["spark.rapids.tpu.sql.pipeline.donation"] \
-                and donation_supported():
-            fn_donate = _cached_program(
-                "stage-donate|" + fp,
-                lambda: program("stage_donate",
-                                self._build_fn(in_schema, ansi=ansi),
-                                donate_argnums=(0, 1, 2)))
+        from ..runtime.pipeline import effective_depth, pipeline_batches
 
         # figure out host pass-through columns for the final projection
         final_proj = None
@@ -575,9 +521,8 @@ class StageExec(TpuExec):
                 break
 
         from ..cpu.eval import set_ansi
-        from ..faults.injector import INJECTOR as FAULT_INJECTOR
         from ..faults.recovery import device_guard
-        from ..memory.retry import INJECTOR, with_retry
+        from ..memory.retry import with_retry
 
         # batch-context state for mid()/spark_partition_id()/
         # input_file_name() (miscfns.py): per-partition row offsets when
@@ -638,7 +583,7 @@ class StageExec(TpuExec):
                     # the thread-local must never leak past this batch —
                     # ANSI errors raise out of evaluate_host_expr
                     set_ansi(False)
-            def _assemble(out_arrays, new_sel, fresh_output):
+            def _assemble(out_arrays, new_sel):
                 cols: List = []
                 for oi, f_ in enumerate(self._schema):
                     val = out_arrays[oi] if oi < len(out_arrays) else None
@@ -653,46 +598,23 @@ class StageExec(TpuExec):
                     else:
                         data, valid = val
                         cols.append(DeviceColumn(f_.dtype, data, valid))
-                out = ColumnBatch(self._schema, cols, b.num_rows, new_sel)
-                # device outputs are fresh program results (single
-                # consumer); the pure-host path shares the input's sel,
-                # so it inherits
-                out.donatable = fresh_output \
-                    or getattr(b, "donatable", False)
-                return out
+                return ColumnBatch(self._schema, cols, b.num_rows, new_sel)
 
             if all(a is None for a in arrays) and \
                     all(e is None for e in extras):
                 # pure host-column stage (string-only projection): no XLA
                 # program to run
-                return _assemble((None,) * len(self._schema), b.sel,
-                                 fresh_output=False)
-            use_fn = fn
-            donated = False
-            if fn_donate is not None and b.donatable \
-                    and not INJECTOR.armed() \
-                    and not FAULT_INJECTOR.armed():
-                # this program consumes the input buffers; the batch
-                # is dead to every later reference (incl. an OOM
-                # replay or a transient re-dispatch — donation is gated
-                # off while either injector is armed, and the conf
-                # documents the real-OOM caveat)
-                b.donatable = False
-                use_fn = fn_donate
-                donated = True
-                QueryStats.get().donated_batches += 1
-
+                return _assemble((None,) * len(self._schema), b.sel)
             from ..runtime import warmstore
             if warmstore.is_active():
                 # record this program call's pytree signature under the
                 # statement's warm-start entry (deduped after batch 1)
-                warmstore.note_program(
-                    ("stage-donate|" if donated else "stage|") + fp,
-                    arrays, extras, b.sel, ansi, donated)
+                warmstore.note_program("stage|" + fp, arrays, extras,
+                                       b.sel, ansi)
 
             def _device_result():
-                outs = use_fn(tuple(arrays), tuple(extras),
-                              b.sel, np.int32(b.num_rows))
+                outs = fn(tuple(arrays), tuple(extras),
+                          b.sel, np.int32(b.num_rows))
                 if ansi:
                     out_arrays, new_sel, err = outs
                     if bool(err):
@@ -703,14 +625,8 @@ class StageExec(TpuExec):
                             "nulling/wrapping)")
                 else:
                     out_arrays, new_sel = outs
-                return _assemble(out_arrays, new_sel, fresh_output=True)
+                return _assemble(out_arrays, new_sel)
 
-            if donated:
-                # donated inputs are consumed by the program: they can
-                # be neither replayed by a transient re-dispatch nor
-                # handed to the CPU fallback — run unguarded (donation
-                # never engages while an injector is armed)
-                return _device_result()
             # device.op guard: transient (non-OOM) runtime failures
             # re-dispatch with backoff, then this batch degrades to the
             # host expression evaluator (cpu/eval) when the stage has no

@@ -29,8 +29,7 @@ import queue
 import threading
 from typing import Callable, Iterable, Iterator, TypeVar
 
-__all__ = ["pipeline_map", "pipeline_batches", "effective_depth",
-           "donation_supported"]
+__all__ = ["pipeline_map", "pipeline_batches", "effective_depth"]
 
 T = TypeVar("T")
 U = TypeVar("U")
@@ -124,13 +123,6 @@ def effective_depth(ctx) -> int:
         if jax.default_backend() == "cpu":
             return 0
     return conf[_DEPTH_KEY]
-
-
-def donation_supported() -> bool:
-    """XLA buffer donation is a no-op (with a warning) on the CPU backend;
-    only engage it where the runtime actually reuses the HBM."""
-    import jax
-    return jax.default_backend() in ("tpu", "gpu")
 
 
 def pipeline_map(src: Iterable[T], fn: Callable[[T], U],

@@ -555,8 +555,8 @@ def note_statement(fp: Optional[str], spec: Optional[dict] = None) -> None:
         st.note_statement(fp, spec)
 
 
-def note_program(program_key: str, arrays, extras, sel, ansi: bool,
-                 donated: bool) -> None:
+def note_program(program_key: str, arrays, extras, sel,
+                 ansi: bool) -> None:
     """Hot-path hook (plan/physical.StageExec.run_one): record the
     pytree signature of one stage program call under the current
     statement.  One set lookup per batch after the first."""
@@ -587,7 +587,7 @@ def note_program(program_key: str, arrays, extras, sel, ansi: bool,
     sig = {"arrays": [pair(a) for a in arrays],
            "extras": [pair(e) for e in extras],
            "sel": aval(sel) if sel is not None else None,
-           "ansi": bool(ansi), "donate": bool(donated)}
+           "ansi": bool(ansi)}
     st.note_program(program_key, fp, sig, capacity)
 
 
@@ -673,10 +673,7 @@ def _aot_compile(stage, in_schema, sig: dict):
     nr = jax.ShapeDtypeStruct((), np.dtype("int32"))
     build = stage._build_fn(in_schema, ansi=bool(sig.get("ansi")))
     from ..plan.physical import program
-    if sig.get("donate"):
-        jitted = program("stage_donate", build, donate_argnums=(0, 1, 2))
-    else:
-        jitted = program("stage", build)
+    jitted = program("stage", build)
     compiled = jitted.lower(arrays, extras, sel, nr).compile()
     # install_program wraps the pair in the program's span: the
     # fallback is the bare jit, not to span it twice
@@ -701,16 +698,18 @@ def _prewarm_entry(session, prepared, tables, conf, ent: dict) -> int:
     compiled = 0
     for stage in _walk_stages(stmt.phys):
         fp = stage.fingerprint() + ("|ansi" if ansi else "")
-        for prefix, name in (("stage|", "stage"),
-                             ("stage-donate|", "stage_donate")):
-            key = prefix + fp
-            rec = programs.get(key)
-            if rec is None or physical.has_program(key):
-                continue
-            fn = _aot_compile(stage, stage.children[0].output_schema,
-                              rec["sig"])
-            physical.install_program(key, name, fn)
-            compiled += 1
+        key = "stage|" + fp
+        rec = programs.get(key)
+        # an older manifest may hold the signature of a stage program
+        # that donated its inputs: no such program exists, the entry is
+        # skipped
+        if rec is None or rec["sig"].get("donate") \
+                or physical.has_program(key):
+            continue
+        fn = _aot_compile(stage, stage.children[0].output_schema,
+                          rec["sig"])
+        physical.install_program(key, "stage", fn)
+        compiled += 1
     return compiled
 
 

@@ -130,9 +130,6 @@ class QueryStats:
         # spent staging — bench derives overlap_s = stage - wait
         self.h2d_wait_s = 0.0
         self.pipeline_stage_s = 0.0
-        # input batches whose device buffers were donated to a fused
-        # stage program (HBM reuse; plan/physical.StageExec)
-        self.donated_batches = 0
         # wall-clock this query waited in the service admission queue
         # before starting (service/scheduler.py writes it; 0 for
         # synchronous queries) — the bench concurrency mode derives

@@ -233,9 +233,6 @@ METRICS = (
     ("query_h2d_wait_seconds_total", "counter", "",
      "Consumer wall seconds blocked waiting on pipeline-staged "
      "batches."),
-    ("query_donated_batches_total", "counter", "",
-     "Input batches whose device buffers were donated to fused stage "
-     "programs."),
     ("query_fused_regions_total", "counter", "",
      "Fused plan regions executed (plan/fusion.py region planner)."),
     ("query_region_fetches_total", "counter", "",
@@ -400,7 +397,6 @@ _QS_FOLD = (
     ("acct_unattributed_s", "query_acct_unattributed_seconds_total"),
     ("shuffle_bytes", "query_shuffle_bytes_total"),
     ("h2d_wait_s", "query_h2d_wait_seconds_total"),
-    ("donated_batches", "query_donated_batches_total"),
     ("fused_regions", "query_fused_regions_total"),
     ("region_fetches", "query_region_fetches_total"),
     ("spill_events", "query_spill_events_total"),

@@ -788,6 +788,17 @@ def _quarantine(c, fp=None, attempts=12):
     raise AssertionError("poison was never quarantined")
 
 
+def _healthy_rows(s, c):
+    """The healthy statement, served beside whatever the poison left
+    behind.  The fixture's 400 ms stall window is there to trip the
+    poison fast; a healthy query's first compile can pass it on a loaded
+    machine and would come back FAULTED by the watchdog, which this is no
+    test of.  The watchdog reads the conf every cycle, so the window is
+    back at its default before the query is sent."""
+    s.conf.unset("spark.rapids.tpu.faults.watchdog.stallMs")
+    return c.query(HEALTHY_SPEC).rows()
+
+
 class TestQuarantineWire:
     def test_faulted_payload_carries_why(self, poison_wire):
         s, door, fp = poison_wire
@@ -814,9 +825,10 @@ class TestQuarantineWire:
             assert e.code == "QUARANTINED"
             assert e.reason == "quarantined"
             assert e.retry_after_ms > 0
-            # the shed names the postmortem (races with the bundle
-            # write resolve within a retry or two)
-            deadline = time.monotonic() + 5
+            # the shed names the postmortem: the breaker opens under
+            # its lock and writes the bundle after it, so a shed in
+            # between carries no id yet; wait for the write itself
+            deadline = time.monotonic() + 60
             bid = e.info.get("bundle_id")
             while not bid and time.monotonic() < deadline:
                 try:
@@ -826,7 +838,7 @@ class TestQuarantineWire:
                 time.sleep(0.05)
             assert bid
             # healthy statements keep serving beside the quarantine
-            assert c.query(HEALTHY_SPEC).rows()
+            assert _healthy_rows(s, c)
         finally:
             c.close()
         assert _await_clean(s, door)
@@ -900,7 +912,7 @@ class TestQuarantineCleanup:
             assert _await_clean(s, door)
             assert door.quotas.inflight() == 0
             get_catalog().assert_no_leaks()
-            assert c.query(HEALTHY_SPEC).rows()
+            assert _healthy_rows(s, c)
         finally:
             from spark_rapids_tpu.cache import device_cache as dc
             dc.set_serve_only(False)

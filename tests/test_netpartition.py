@@ -425,7 +425,12 @@ class TestQuorumFencedFailover:
             # HEAL: rank 0 probes, finds gen 2, abdicates its stale
             # coordinator, re-registers (fresh incarnation)
             FABRIC.heal()
-            _wait(lambda: not pgs[0].quorum_lost, timeout=60,
+            # the rejoin clears quorum_lost first, then absorbs the new
+            # membership (generation, epoch), then counts itself: wait
+            # for the last of these, not the first
+            _wait(lambda: not pgs[0].quorum_lost
+                  and QueryStats.delta_since(s0)["rank_rejoins"] >= 1,
+                  timeout=60,
                   what=lambda: (
                       f"rank 0 heal + rejoin (pg0: ql="
                       f"{pgs[0].quorum_lost} coord_rank="

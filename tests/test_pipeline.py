@@ -1,13 +1,10 @@
 """Pipelined async executor (runtime/pipeline.py) regression tests.
 
-Three contracts the pipeline must never break:
+Two contracts the pipeline must never break:
   (a) pipelined (depth>0) and serial (depth=0) execution produce
       identical results — the pipeline reorders WHEN work happens,
       never WHAT is computed;
-  (b) depth is a hard bound on staged batches (HBM stays bounded);
-  (c) buffer donation only ever sees single-consumer batches — a batch
-      referenced by a SpillableBatch handle or the device-tier file
-      cache is never donatable.
+  (b) depth is a hard bound on staged batches (HBM stays bounded).
 """
 
 import threading
@@ -18,8 +15,7 @@ import pyarrow as pa
 import pytest
 
 import spark_rapids_tpu as srt
-from spark_rapids_tpu.runtime.pipeline import (donation_supported,
-                                               effective_depth,
+from spark_rapids_tpu.runtime.pipeline import (effective_depth,
                                                pipeline_batches,
                                                pipeline_map)
 
@@ -177,79 +173,12 @@ def test_effective_depth_resolution(session):
         ExecContext(ctx.conf)
 
 
-# ---------------------------------------------------------------------------
-# (c) donation eligibility
-# ---------------------------------------------------------------------------
-
-def _scan_exec(table, **conf):
-    from spark_rapids_tpu.config import TpuConf
-    from spark_rapids_tpu.plan.physical import ExecContext, ScanExec
-    from spark_rapids_tpu.batch import Schema, Field, _arrow_to_logical
-    schema = Schema([Field(n, _arrow_to_logical(t), True)
-                     for n, t in zip(table.column_names,
-                                     table.schema.types)])
-    scan = ScanExec(schema, lambda: iter([table]), desc="mem")
-    return scan, ExecContext(TpuConf(conf))
-
-
-def _table(n=4096):
-    rng = np.random.default_rng(5)
-    return pa.table({"a": rng.integers(0, 100, n),
-                     "b": rng.random(n)})
-
-
-def test_fresh_scan_batches_are_donatable(session):
-    scan, ctx = _scan_exec(_table())
-    batches = list(scan.execute(ctx))
-    assert batches and all(b.donatable for b in batches)
-
-
-def test_spill_registration_clears_donatable(session):
-    from spark_rapids_tpu.memory.spill import SpillCatalog
-    scan, ctx = _scan_exec(_table())
-    b = next(scan.execute(ctx))
-    assert b.donatable
-    cat = SpillCatalog(1 << 30, 1 << 30)
-    h = cat.register(b)
-    try:
-        # the handle is a second reference: donating b's buffers to a
-        # stage program would corrupt what h.get() re-materializes
-        assert not b.donatable
-    finally:
-        h.close()
-
-
-def test_device_cached_scan_batches_not_donatable(session, tmp_path):
-    """Both the populate-path re-wraps and later cache hits share the
-    cached arrays — neither may ever be donated."""
-    import pyarrow.parquet as pq
-    from spark_rapids_tpu.io.filecache import clear_device_cache
-    from spark_rapids_tpu.io.parquet import ParquetSource
-    path = str(tmp_path / "t.parquet")
-    pq.write_table(_table(), path)
-    from spark_rapids_tpu.config import TpuConf
-    from spark_rapids_tpu.plan.physical import ExecContext, ScanExec
-    clear_device_cache()
-    src = ParquetSource(path)
-    scan = ScanExec(src.schema(), src, desc="pq")
-    conf = {"spark.rapids.tpu.sql.fileCache.enabled": True,
-            "spark.rapids.tpu.sql.fileCache.deviceTier": True}
-    first = list(scan.execute(ExecContext(TpuConf(conf))))
-    hits = list(scan.execute(ExecContext(TpuConf(conf))))
-    clear_device_cache()
-    assert first and hits
-    assert all(not b.donatable for b in first)
-    assert all(not b.donatable for b in hits)
-
-
-def test_stage_output_donatable_and_correct(session):
-    """Stage outputs are fresh program results (donatable downstream);
-    donation itself only engages off-CPU, so on the test backend the
-    non-donating program must produce the same rows."""
+def test_stage_output_correct(session):
+    """A fused filter + project stage over a fresh scan gives the rows
+    the expressions define."""
     f = srt.functions
     df = session.create_dataframe(
         {"x": np.arange(100, dtype=np.int64)})
     rows = (df.filter(f.col("x") % 2 == 0)
               .select((f.col("x") * 10).alias("y")).collect())
     assert sorted(r[0] for r in rows) == [x * 10 for x in range(0, 100, 2)]
-    assert not donation_supported()  # CPU test backend: donation is a no-op
