@@ -30,18 +30,33 @@ def hash_ids(keys: Sequence[Value], n_parts: int) -> jax.Array:
 
 
 def bucketize(pids: jax.Array, active: jax.Array, n_parts: int,
-              bucket_cap: int, arrays: Sequence[jax.Array]):
+              bucket_cap: int, arrays: Sequence[jax.Array],
+              live_cap: Optional[int] = None):
     """Scatter rows into [n_parts, bucket_cap] send buckets.
 
     Returns (bucketed arrays, per-bucket counts, overflow scalar).  Rows
     beyond a bucket's capacity are dropped and counted in ``overflow`` —
     callers must check it is zero (and retry with larger buckets otherwise).
+
+    A scatter is paid by SOURCE row, live or not, and a 64-bit one dearly
+    (``ops/batch_utils._SCATTER64_ROW_NS`` against ``_SCATTER_ROW_NS``; the
+    gathers ``_GATHER64_NS`` / ``_GATHER_NS`` an element): a few thousand
+    live rows of a 2M-slot capacity cost what 2M rows cost.  A caller
+    that knows a static bound on its live rows (the mesh exchange does: the
+    host read the count a step earlier) passes it as ``live_cap``: the one
+    sort over every row puts the inactive ones last, and only the first
+    ``live_cap`` sorted rows are gathered, placed and scattered.  At most
+    ``live_cap`` rows may be active; without it every row is walked.
     """
     capacity = pids.shape[0]
     pid_sortable = jnp.where(active, pids, n_parts)  # inactive rows last
     # a bucket's rows in any order (an unstable sort compiles in 15 s for
     # the TPU, a stable one in 39 s at 1M rows)
     perm = jnp.argsort(pid_sortable, stable=False)
+    if live_cap is not None and live_cap < capacity:
+        # every live row sorted before the cut: what is past it is padding
+        perm = perm[:live_cap]
+        capacity = live_cap
     s_pid = pid_sortable[perm]
     s_active = s_pid < n_parts
     # position of each (sorted) row within its partition

@@ -26,10 +26,12 @@ Dataflow per query:
      input rows on the mesh with their destinations and returns the rows
      counted per destination; the host reads the counts (one small
      blocking fetch a step), sizes the send bucket to the capacity-ladder
-     rung over the fullest one, and the next step starts with the
-     all_to_all.  So a bucket holds what is sent, not the sender's whole
-     capacity, and capacities shrink with the rows instead of growing
-     ``n_devices`` times per exchange.  Every step is one program
+     rung over the fullest one and the rows to bucket to the rung over
+     the fullest sender's, and the next step starts with the all_to_all.
+     So a bucket holds what is sent, not the sender's whole capacity, its
+     scatters walk the live rows and not the padding, and capacities
+     shrink with the rows instead of growing ``n_devices`` times per
+     exchange.  Every step is one program
      (``ici_fragment_step``) kept in the process-wide program cache under
      what it is traced from: the plan fingerprints of the operators it
      runs, every static capacity, and the mesh.  The second run of a query
@@ -160,8 +162,18 @@ class _Exchange:
 
     It runs in two halves, a step apart.  ``emit_send`` ends the step
     below: the child's rows stay on the mesh with their destinations and
-    the rows per destination go to the host, which sizes the send bucket
-    (``size``).  ``emit`` opens the step above with the all_to_all."""
+    the rows per destination, per sender, go to the host.  From that one
+    count ``size`` takes three static numbers: ``bucket_cap``, the ladder
+    rung over the most rows any sender has for one destination (what an
+    all_to_all bucket holds); ``live_cap``, the rung over the most rows
+    any sender has at all, never more than ``child.cap`` (what ``emit``
+    buckets); and ``cap``, ``n_dev x bucket_cap``, the rows a device
+    receives.  ``emit`` opens the step above: it buckets ``live_cap`` of
+    the ``child.cap`` staged rows and runs the all_to_all.  The staged
+    rows are often a padded aggregate's or join's output, a few thousand
+    live of 2M slots, and a scatter is paid by source row
+    (``exchange.bucketize``); where most rows are live ``live_cap`` is
+    ``child.cap`` and nothing is cut."""
 
     OVERFLOW = "exchange bucket (spark.rapids.tpu.shuffle.ici.bucketRows)"
 
@@ -177,6 +189,7 @@ class _Exchange:
         self._fixed_rows = bucket_rows * cap_scale
         self.index = None      # among the fragment's exchanges, emit order
         self.bucket_cap = None  # set by size(), once the send half ran
+        self.live_cap = None
         self.cap = None
 
     @property
@@ -184,8 +197,9 @@ class _Exchange:
         """The send half ran: rows and destinations are on the mesh."""
         return self.bucket_cap is not None
 
-    def size(self, need: int) -> None:
-        """``need``: the most rows any device sends to one destination."""
+    def size(self, need: int, live: int) -> None:
+        """``need``: the most rows any device sends to one destination;
+        ``live``: the most rows any device sends in all."""
         from ..batch import bucket_capacity
         if self._fixed_rows > 0:
             if need > self._fixed_rows:
@@ -194,7 +208,14 @@ class _Exchange:
             self.bucket_cap = self._fixed_rows
         else:
             self.bucket_cap = bucket_capacity(max(1, need), min_capacity=8)
+        self.live_cap = min(self.child.cap, bucket_capacity(
+            max(1, live), min_capacity=8))
         self.cap = self.n_dev * self.bucket_cap
+
+    @property
+    def compacted(self) -> bool:
+        """``emit`` buckets fewer rows than the step below staged."""
+        return self.live_cap < self.child.cap
 
     def resolve(self):
         # reached from the step above only: the child resolved in its own
@@ -205,8 +226,8 @@ class _Exchange:
         return f"send{self.n_dev}({keys})<{self.child.fingerprint()}>"
 
     def fingerprint(self) -> str:
-        return (f"recv{self.index}[{self.child.cap}->{self.n_dev}x"
-                f"{self.bucket_cap}]({_schema_sig(self.schema)})")
+        return (f"recv{self.index}[{self.child.cap}->{self.live_cap}->"
+                f"{self.n_dev}x{self.bucket_cap}]({_schema_sig(self.schema)})")
 
     def emit_send(self, env):
         """((flat data/validity arrays, active, pids), rows per
@@ -234,9 +255,11 @@ class _Exchange:
         from .exchange import bucketize, exchange
         flat, active, pids = env["staged"][self.index]
         # the bucket was sized from the count (or checked against it), so
-        # bucketize's own overflow count is zero
+        # bucketize's own overflow count is zero; so was live_cap, so the
+        # rows past it once sorted are padding
         bucketed, sent, _ = bucketize(
-            pids, active, self.n_dev, self.bucket_cap, flat)
+            pids, active, self.n_dev, self.bucket_cap, flat,
+            live_cap=self.live_cap)
         recv, recv_counts = exchange(self.axis, bucketed, sent)
         total = self.n_dev * self.bucket_cap
         lane = jnp.arange(self.bucket_cap, dtype=jnp.int32)
@@ -805,10 +828,14 @@ def _run_steps(lowered, feeds: Dict, mesh, axis: str, n_dev: int, stats):
             raise ICICapacityOverflow(detail)
         if final:
             return result, int(sized.max())
-        # (n_dev senders, exchanges of the step, n_dev destinations)
-        need = sized.reshape(n_dev, len(roots), n_dev).max(axis=(0, 2))
-        for e, send, rows in zip(roots, result, need):
-            e.size(int(rows))
+        # (n_dev senders, exchanges of the step, n_dev destinations): the
+        # fullest bucket of each exchange, and its fullest sender's rows
+        sized = sized.reshape(n_dev, len(roots), n_dev)
+        need = sized.max(axis=(0, 2))
+        live = sized.sum(axis=2).max(axis=0)
+        for e, send, rows, sender_rows in zip(roots, result, need, live):
+            e.size(int(rows), int(sender_rows))
+            stats.ici_compacted_exchanges += int(e.compacted)
             staged[e.index] = send
             stats.ici_exchange_bytes += (
                 n_dev * n_dev * e.bucket_cap

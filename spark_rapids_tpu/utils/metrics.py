@@ -91,8 +91,10 @@ class QueryStats:
         # account's terms and add to nothing), the bytes placed on the
         # mesh as padded (every device's copy of a replicated leaf), the
         # bytes the all_to_alls move (from the static bucket shapes:
-        # n_dev * n_dev * bucket rows * row width an exchange), and the
-        # re-runs at 4x capacities after an overflow
+        # n_dev * n_dev * bucket rows * row width an exchange), the
+        # re-runs at 4x capacities after an overflow, and the exchanges
+        # that bucketed fewer rows than their input's capacity (the
+        # ladder rung over the fullest sender's counted rows was below it)
         self.ici_fragments = 0
         self.ici_materialize_s = 0.0
         self.ici_feed_s = 0.0
@@ -101,6 +103,7 @@ class QueryStats:
         self.ici_feed_bytes = 0
         self.ici_exchange_bytes = 0
         self.ici_overflow_retries = 0
+        self.ici_compacted_exchanges = 0
         # dense aggregation (plan/physical.py, ops/dense_agg.py): batches
         # its update programs took, and those of them whose in-domain
         # rows fit a rung and were compacted on the device before the

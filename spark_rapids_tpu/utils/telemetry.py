@@ -196,6 +196,9 @@ METRICS = (
     ("query_ici_overflow_retries_total", "counter", "",
      "Fragments re-run at 4x capacities after a bucket or join "
      "expansion overflowed."),
+    ("query_ici_compacted_exchanges_total", "counter", "",
+     "ICI exchanges that bucketed fewer rows than their input's capacity "
+     "(cut to the ladder rung over the fullest sender's counted rows)."),
     ("query_agg_dense_batches_total", "counter", "",
      "Batches the dense aggregation's update programs took."),
     ("query_agg_dense_compacted_batches_total", "counter", "",
@@ -382,6 +385,7 @@ _QS_FOLD = (
     ("ici_feed_bytes", "query_ici_feed_bytes_total"),
     ("ici_exchange_bytes", "query_ici_exchange_bytes_total"),
     ("ici_overflow_retries", "query_ici_overflow_retries_total"),
+    ("ici_compacted_exchanges", "query_ici_compacted_exchanges_total"),
     ("agg_dense_batches", "query_agg_dense_batches_total"),
     ("agg_dense_compacted_batches",
      "query_agg_dense_compacted_batches_total"),

@@ -88,6 +88,34 @@ def test_bucketize_overflow_detected_not_corrupting():
     assert p1 == [4, 5]
 
 
+@pytest.mark.parametrize("live,live_cap", [
+    (0, 8), (5, 8), (8, 8), (37, 64), (200, 256), (256, 256), (200, None)])
+def test_bucketize_live_cap_keeps_every_live_row(live, live_cap):
+    """A caller that knows a bound on its live rows passes it: the rows
+    past it once sorted are padding, and each bucket holds the rows it
+    held, in whatever order."""
+    rng = np.random.default_rng(live)
+    cap, n_parts, bucket_cap = 256, 4, 256
+    keys = jnp.asarray(rng.integers(0, 1 << 40, cap), dtype=jnp.int64)
+    vals = jnp.asarray(rng.uniform(0, 10, cap))
+    mask = np.zeros(cap, dtype=bool)
+    mask[rng.choice(cap, live, replace=False)] = True
+    pids = hash_ids([(keys, None)], n_parts)
+    want = bucketize(pids, jnp.asarray(mask), n_parts, bucket_cap,
+                     [keys, vals])
+    got = bucketize(pids, jnp.asarray(mask), n_parts, bucket_cap,
+                    [keys, vals], live_cap=live_cap)
+    assert int(got[2]) == int(want[2]) == 0
+    counts = np.asarray(got[1])
+    assert counts.tolist() == np.asarray(want[1]).tolist()
+    assert counts.sum() == live
+    for p in range(n_parts):
+        for g, w in zip(got[0], want[0]):
+            assert g.shape == w.shape == (n_parts, bucket_cap)
+            assert sorted(np.asarray(g)[p, :counts[p]].tolist()) \
+                == sorted(np.asarray(w)[p, :counts[p]].tolist())
+
+
 def test_bucketize_multiple_arrays_consistent():
     rng = np.random.default_rng(3)
     cap = 64

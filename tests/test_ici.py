@@ -505,6 +505,146 @@ def test_ici_cached_step_programs_hold_no_plan(shuffle_only, rng):
     assert [r() for r in held] == [None, None]
 
 
+# ---------------------------------------------------------------------------------
+# the exchange buckets its live rows only (live_cap, from the counts the
+# host read at the end of the step below)
+# ---------------------------------------------------------------------------------
+
+def _grouped(sess, table):
+    return (sess.create_dataframe(pa.table(table)).group_by("k")
+            .agg(F.sum(F.col("v")).alias("s"), F.count(F.col("v")).alias("c")))
+
+
+def _live_all(sess, rng, n):
+    # every row its own group: the partial aggregate's output is full
+    return _grouped(sess, {"k": pa.array(rng.permutation(n)),
+                           "v": pa.array(rng.uniform(0, 10, n))})
+
+
+def _live_few(sess, rng, n):
+    return _grouped(sess, {"k": pa.array(rng.integers(0, 5, n)),
+                           "v": pa.array(rng.uniform(0, 10, n))})
+
+
+def _live_none_on_one_device(sess, rng, n):
+    # device 0 holds the first n/8 rows: the filter leaves it none
+    df = sess.create_dataframe(pa.table({
+        "pos": pa.array(np.arange(n)), "k": pa.array(rng.integers(0, 5, n)),
+        "v": pa.array(rng.uniform(0, 10, n))}))
+    return (df.filter(F.col("pos") >= n // 8).group_by("k")
+            .agg(F.sum(F.col("v")).alias("s")))
+
+
+def _live_exactly_a_rung(sess, rng, n):
+    # all 64 keys in every device's share: 64 groups a sender, a whole rung
+    return _grouped(sess, {"k": pa.array(np.arange(n) % 64),
+                           "v": pa.array(rng.uniform(0, 10, n))})
+
+
+def _live_nullable_and_strings(sess, rng, n):
+    cats = ["alpha", "beta", "gamma", None]
+    return _grouped(sess, {
+        "k": pa.array([cats[i % 4] for i in range(n)]),
+        "v": pa.array([None if i % 7 == 0 else float(i % 13)
+                       for i in range(n)], type=pa.float64())})
+
+
+LIVE_CASES = {
+    # name: (query, rows, the exchange's (child.cap, live_cap), counter)
+    "all_live": (_live_all, 8 * 512, (512, 512), 0),
+    "few_live": (_live_few, 8 * 2048, (2048, 8), 1),
+    "none_on_one_device": (_live_none_on_one_device, 8 * 2048, (2048, 8), 1),
+    "exactly_a_rung": (_live_exactly_a_rung, 8 * 2048, (2048, 64), 1),
+    "nullable_and_strings": (_live_nullable_and_strings, 8 * 1024,
+                             (1024, 8), 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LIVE_CASES))
+def test_ici_exchange_buckets_its_live_rows(sess, rng, monkeypatch, case):
+    """The receive half buckets ``live_cap`` rows, the ladder rung over
+    the fullest sender's counted rows, never more than the step below
+    staged; the answers are the single-process plan's either way and
+    ``ici_compacted_exchanges`` counts the exchanges that were cut."""
+    make, n, (child_cap, live_cap), compacted = LIVE_CASES[case]
+    df = make(sess, rng, n)
+    want = df.collect()
+    sess.conf.set("spark.rapids.tpu.shuffle.mode", "ICI")
+    try:
+        got, st, ran = _run_recording_fragments(df, monkeypatch)
+    finally:
+        sess.conf.set("spark.rapids.tpu.shuffle.mode", "CACHE_ONLY")
+    _assert_rows_equal(got, want)
+    (exch,) = _exchanges(ran[0][0])
+    assert (exch.child.cap, exch.live_cap) == (child_cap, live_cap)
+    assert exch.compacted == (live_cap < child_cap)
+    assert st.ici_compacted_exchanges == compacted
+    assert st.ici_overflow_retries == 0
+    # the step that opens with the exchange is cached under all three
+    # numbers: the rows staged, the rows bucketed, the bucket
+    assert exch.fingerprint().startswith(
+        f"recv0[{child_cap}->{live_cap}->8x{exch.bucket_cap}](")
+
+
+def _scatter_gather_rows(jaxpr, out):
+    """(primitive, source rows) of every scatter and gather in ``jaxpr``
+    and under it: a scatter is paid by update row, a gather by element
+    fetched; plus ("sort", rows) and ("cumsum", rows)."""
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name.startswith("scatter"):
+            out.append((name, eqn.invars[2].aval.shape[:1]))
+        elif name == "gather":
+            out.append((name, eqn.outvars[0].aval.shape[:1]))
+        elif name in ("sort", "cumsum"):
+            out.append((name, eqn.invars[0].aval.shape[:1]))
+        for sub in eqn.params.values():
+            for j in sub if isinstance(sub, (list, tuple)) else (sub,):
+                inner = getattr(j, "jaxpr", j)
+                if hasattr(inner, "eqns"):
+                    _scatter_gather_rows(inner, out)
+    return out
+
+
+@pytest.mark.parametrize("case", ["few_live", "all_live"])
+def test_ici_compacted_step_walks_the_staged_rows_once(sess, rng,
+                                                       monkeypatch, case):
+    """In the step above a compacted exchange nothing but the one sort
+    is as long as the rows staged: no column is gathered or scattered
+    at ``child.cap`` rows.  The all-live step is the control: the same
+    walk finds its ``child.cap``-long gathers and scatters."""
+    import jax
+    from spark_rapids_tpu.parallel import spmd
+    make, n, (child_cap, live_cap), _ = LIVE_CASES[case]
+    traced = []
+    inner = spmd._step_program
+
+    def recording(roots, final, mesh, axis):
+        fn, *rest = inner(roots, final, mesh, axis)
+
+        def call(*args):
+            traced.append((final, jax.make_jaxpr(fn.call)(*args)))
+            return fn(*args)
+        return (call, *rest)
+    monkeypatch.setattr(spmd, "_step_program", recording)
+    sess.conf.set("spark.rapids.tpu.shuffle.mode", "ICI")
+    try:
+        assert make(sess, rng, n).collect()
+    finally:
+        sess.conf.set("spark.rapids.tpu.shuffle.mode", "CACHE_ONLY")
+    (send, _), (final, above) = traced
+    assert not send and final
+    ops = _scatter_gather_rows(above.jaxpr, [])
+    long_ops = [(name, rows) for name, rows in ops if rows == (child_cap,)]
+    assert any(name == "gather" for name, _ in ops)
+    assert any(name.startswith("scatter") for name, _ in ops)
+    if live_cap < child_cap:
+        assert long_ops == [("sort", (child_cap,))]
+    else:
+        assert {name for name, _ in long_ops} >= {"sort", "gather",
+                                                  "scatter"}
+
+
 @pytest.mark.parametrize("n_keys,nulls", [(1, False), (3, False), (2, True)])
 def test_group_sort_puts_equal_keys_in_one_stable_run(rng, n_keys, nulls):
     """The group sort is four stable passes of a two-operand sort over
