@@ -98,6 +98,23 @@ def values_to_arrow(schema: Schema, values, n: int):
     return pa.table(dict(zip(schema.names(), arrays)))
 
 
+def sort_table(table, schema: Schema, orders):
+    """``table`` ordered by ``orders`` ((bound expression, ascending, nulls
+    first) triples) on the host: the CPU Sort, and the device sort's way
+    with a few rows under a string key (plan/exec_nodes.SortExec)."""
+    import pyarrow as pa
+    vals = arrow_to_values(table, schema)
+    n = table.num_rows
+    # lexicographic: apply np.argsort stably from minor to major key
+    perm = np.arange(n)
+    for expr, ascending, nulls_first in reversed(orders):
+        d, v = eval_cpu(expr, vals, n)
+        d2, v2 = d[perm], (v[perm] if v is not None else None)
+        keys = CpuOpExec._sort_key(d2, v2, ascending, nulls_first)
+        perm = perm[np.argsort(keys, kind="stable")]
+    return table.take(pa.array(perm))
+
+
 class CpuOpExec(TpuExec):
     """Executes one logical operator on host over its children's output."""
 
@@ -408,19 +425,11 @@ class CpuOpExec(TpuExec):
         return out, None if ok else np.array([False])
 
     def _run_sort(self, ctx, p: L.Sort):
-        import pyarrow as pa
         in_schema = self.children[0].output_schema
-        table = self._child_table(ctx)
-        vals = arrow_to_values(table, in_schema)
-        n = table.num_rows
-        # lexicographic: apply np.argsort stably from minor to major key
-        perm = np.arange(n)
-        for o in reversed(p.orders):
-            d, v = eval_cpu(bind(o.expr, in_schema), vals, n)
-            d2, v2 = d[perm], (v[perm] if v is not None else None)
-            keys = self._sort_key(d2, v2, o.ascending, o.nulls_first)
-            perm = perm[np.argsort(keys, kind="stable")]
-        return table.take(pa.array(perm))
+        return sort_table(
+            self._child_table(ctx), in_schema,
+            [(bind(o.expr, in_schema), o.ascending, o.nulls_first)
+             for o in p.orders])
 
     @staticmethod
     def _sort_key(d, v, ascending, nulls_first):

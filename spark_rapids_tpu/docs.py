@@ -39,18 +39,25 @@ _EXEC_ROWS: List[Tuple[str, List[str], str, str]] = [
      "TPU", "build side materialized once (hint or "
      "autoBroadcastJoinThreshold); probe side streamed, never shuffled"),
     ("Sort (in-core + out-of-core)", ["plan.exec_nodes.SortExec"],
-     "TPU", "range-partitioned merge of spillable runs"),
+     "TPU", "range-partitioned merge of spillable runs; string keys as "
+     "order-preserving dictionary codes (in-core)"),
     ("Window", ["plan.window_exec.WindowExec"],
      "TPU", "sorted segmented scans; rank/row_number/lead/lag/"
-     "running + unbounded aggs"),
+     "running + unbounded aggs; string partition and order keys as "
+     "dictionary codes (a bare column, or a CASE between one column and "
+     "NULL); other computed string keys fall back"),
     ("TakeOrderedAndProject (TopK)", ["plan.exec_nodes.TopKExec"],
-     "TPU", "running device top-k"),
+     "TPU", "running device top-k; string keys as for Sort"),
     ("Limit / Offset", ["plan.exec_nodes.LimitExec"], "TPU", ""),
     ("Sample", ["plan.exec_nodes.SampleExec"],
      "TPU", "per-row uniform folded into the selection mask"),
-    ("Union / Distinct / Range / Expand",
-     ["plan.exec_nodes.UnionExec", "plan.exec_nodes.RangeExec",
-      "plan.exec_nodes.ExpandExec"], "TPU", ""),
+    ("Union / Distinct / Range",
+     ["plan.exec_nodes.UnionExec", "plan.exec_nodes.RangeExec"], "TPU", ""),
+    ("Expand (rollup / cube, grouping, grouping_id)",
+     ["plan.exec_nodes.ExpandExec"],
+     "TPU", "one projection a grouping set under the ordinary aggregate; "
+     "string keys pass through or are NULL-ed over their dictionary; "
+     "computed string keys fall back"),
     ("Exchange (hash/single/broadcast)",
      ["plan.exchange_exec.ShuffleExchangeExec",
       "plan.join_exec.BroadcastExchangeExec"],

@@ -31,6 +31,14 @@ _Q12_CATS = ["Sports", "Books", "Home"]
 
 
 def _revratio_runner(dfs, fact, item_col, price_col, date_lo, date_hi):
+    # Q12 / Q20 / Q98 are written AROUND their window: the specification's
+    # ``sum(sum(price)) over (partition by i_class)`` is the join with a
+    # second aggregate below, from when a window over a string partition
+    # fell back to the CPU.  It no longer does (plan/window_exec.py: string
+    # keys as dictionary codes), but these stay as they are: the
+    # differential tests pin this plan shape (tests/test_tpcds.py and the
+    # fusion / sync-budget counts over it).  The window form of the same
+    # idea is benchmark/queries/tpcds/q89.py (``avg(sum(..)) over``).
     pre = {"web_sales": "ws", "catalog_sales": "cs",
            "store_sales": "ss"}[fact]
     f = _F()

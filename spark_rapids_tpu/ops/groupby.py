@@ -79,13 +79,23 @@ def _null_order_key(valid: Optional[jax.Array], capacity: int) -> jax.Array:
     return valid.astype(jnp.int32)
 
 
+# one lexsort holds at most this many operands: XLA's compile time for a
+# TPU sort doubles per operand (group_sort_indices below has the seconds),
+# so an ordering with more keys than any accepted cell sorts by today
+# (TPC-DS Q52's top-k: three keys, five operands) runs as stable passes
+_LEXSORT_MAX_OPERANDS = 5
+
+
 def sort_indices_for_keys(keys: Sequence[Value], active: jax.Array,
                           descending: Optional[Sequence[bool]] = None,
-                          nulls_first: Optional[Sequence[bool]] = None) -> jax.Array:
+                          nulls_first: Optional[Sequence[bool]] = None,
+                          passes: bool = False) -> jax.Array:
     """Stable sort permutation: active rows first, ordered by keys.
 
     ``keys`` are (data, valid) pairs; inactive (filtered/padding) rows sort to
-    the end regardless of key value.
+    the end regardless of key value.  ``passes`` asks for the form that
+    compiles fast whatever the number of keys (:func:`_sort_passes`); past
+    ``_LEXSORT_MAX_OPERANDS`` operands it is taken anyway.
     """
     capacity = active.shape[0]
     arrays = []
@@ -131,7 +141,35 @@ def sort_indices_for_keys(keys: Sequence[Value], active: jax.Array,
             arrays.append(view)
             arrays.append(vkey)
     arrays.append(~active)  # most significant: active rows (False) first
+    if passes or len(arrays) > _LEXSORT_MAX_OPERANDS:
+        return _sort_passes(arrays)
     return jnp.lexsort(tuple(arrays))
+
+
+@jax.named_scope("sort_passes")
+def _sort_passes(arrays: Sequence[jax.Array]) -> jax.Array:
+    """The permutation of ``jnp.lexsort(arrays)`` (minor key first) as one
+    stable two-operand sort per 32-bit digit, least significant first: a
+    gather a pass, and a compile time that grows by the pass, not by the
+    power (a ten-key ORDER BY is a 20-operand lexsort otherwise)."""
+    capacity = arrays[0].shape[0]
+    perm = jnp.arange(capacity, dtype=jnp.int32)
+    for a in arrays:
+        if a.dtype == jnp.bool_:
+            digits = [a.astype(jnp.uint32)]
+        elif a.dtype.itemsize <= 4:
+            # signed order as unsigned: flip the sign bit
+            digits = [a.astype(jnp.int32).astype(jnp.uint32)
+                      ^ jnp.uint32(1 << 31)]
+        else:
+            u = a.astype(jnp.int64).astype(jnp.uint64) \
+                ^ jnp.uint64(1 << 63)
+            digits = [(u & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32),
+                      (u >> jnp.uint64(32)).astype(jnp.uint32)]
+        for digit in digits:
+            perm = jax.lax.sort((digit[perm], perm), num_keys=1,
+                                is_stable=True)[1]
+    return perm
 
 
 @jax.named_scope("groupby_sort")

@@ -22,7 +22,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-__all__ = ["StringDictionary"]
+__all__ = ["StringDictionary", "key_codes", "key_view"]
 
 
 class StringDictionary:
@@ -71,7 +71,7 @@ class StringDictionary:
         denc = arr.dictionary_encode()
         local_vals = denc.dictionary.to_pylist()
         with self._lock:
-            remap = np.empty(max(len(local_vals), 1), dtype=np.int32)
+            remap = np.zeros(max(len(local_vals), 1), dtype=np.int32)
             for i, v in enumerate(local_vals):
                 code = self._code_of.get(v)
                 if code is None:
@@ -118,3 +118,92 @@ class StringDictionary:
                else None
                for i in range(len(codes))]
         return pa.array(out, type=pa.string())
+
+
+# ---------------------------------------------------------------------------------
+# String KEY columns of a sort or a window, as int32 codes on the device
+# ---------------------------------------------------------------------------------
+
+def _dictionary_ranks(dictionary) -> np.ndarray:
+    """rank[i] = position of ``dictionary[i]`` among the dictionary's
+    values in binary (UTF-8 byte) order, Spark's string order.  Equal
+    values share the smaller position, so a dictionary that repeats a
+    value still gives equal strings equal codes."""
+    import pyarrow.compute as pc
+    order = pc.sort_indices(dictionary).to_numpy(zero_copy_only=False)
+    svals = dictionary.take(order)
+    n = len(order)
+    new = np.ones(n, dtype=bool)
+    if n > 1:
+        new[1:] = pc.not_equal(svals.slice(1), svals.slice(0, n - 1)) \
+            .fill_null(True).to_numpy(zero_copy_only=False)
+    first = np.maximum.accumulate(np.where(new, np.arange(n), 0))
+    ranks = np.zeros(max(n, 1), dtype=np.int32)
+    ranks[order] = first.astype(np.int32)
+    return ranks
+
+
+def key_codes(col, ordered: bool, device=None):
+    """A string column as device ``(int32 codes, validity or None)``: equal
+    strings get equal codes, and with ``ordered`` a smaller string a
+    smaller code (its rank among the column's distinct values).  The codes
+    of two columns compare only where both carry one dictionary object, so
+    a consumer takes them from ONE batch at a time.
+
+    A ``DictStringColumn`` stays on the device: its codes as they are, or
+    a gather through the rank table of its dictionary (made on the host
+    and uploaded).  A host column is encoded on the host and uploaded.
+    Either way the codes are remembered on the column object.
+    """
+    import jax.numpy as jnp
+
+    from ..batch import DictStringColumn
+    from ..utils.metrics import upload
+    cache = col.__dict__.setdefault("_key_codes", {})
+    hit = cache.get(ordered)
+    if hit is not None:
+        return hit
+    if isinstance(col, DictStringColumn):
+        codes = col.codes
+        if ordered:
+            table = upload(_dictionary_ranks(col.dictionary), device)
+            codes = jnp.take(table, jnp.clip(codes, 0, table.shape[0] - 1))
+        hit = _nulls_to_zero(codes, col.valid), col.valid
+    else:
+        arr = col.array
+        denc = arr.dictionary_encode()
+        local = denc.indices.fill_null(0).to_numpy(
+            zero_copy_only=False).astype(np.int32)
+        if ordered:
+            local = _dictionary_ranks(denc.dictionary)[local]
+        valid = np.asarray(arr.is_valid()) if arr.null_count else None
+        hit = upload((_nulls_to_zero(local, valid), valid), device)
+    cache[ordered] = hit
+    return hit
+
+
+def _nulls_to_zero(codes, valid):
+    """One code for every NULL: the ordering sort compares what lies under
+    a NULL too, so NULLs that a CASE's branches made of different rows
+    would not tie, and the keys after them would not order them."""
+    if valid is None:
+        return codes
+    import jax.numpy as jnp
+    xp = np if isinstance(codes, np.ndarray) else jnp
+    return xp.where(valid, codes, xp.zeros_like(codes))
+
+
+def key_view(batch, ordinals, ordered: bool, device=None):
+    """``batch`` with the string columns at ``ordinals`` replaced by device
+    columns of :func:`key_codes` (logical STRING, physical int32): what a
+    sort's or a window's key expressions evaluate over.  The caller
+    gathers the batch it came from, so the strings themselves never move.
+    """
+    from .. import types as T
+    from ..batch import ColumnBatch, DeviceColumn, HostStringColumn
+    cols = list(batch.columns)
+    for o in ordinals:
+        if isinstance(cols[o], HostStringColumn):
+            data, valid = key_codes(cols[o], ordered, device)
+            cols[o] = DeviceColumn(T.STRING, data, valid)
+    return ColumnBatch(batch.schema, cols, batch.num_rows, batch.sel)

@@ -48,7 +48,17 @@ class SortedWindowContext:
         keys = part_keys + order_keys
         desc = [False] * len(part_keys) + list(order_desc)
         nf = [True] * len(part_keys) + list(order_nulls_first)
-        self.perm = groupby.sort_indices_for_keys(keys, active, desc, nf)
+        # stable passes, not one lexsort: a window's sort holds a key a
+        # partition column more, and its compile time must not double
+        # with each (ops/groupby._sort_passes)
+        with jax.named_scope("window_sort"):
+            self.perm = groupby.sort_indices_for_keys(keys, active, desc,
+                                                      nf, passes=True)
+        self._segments(part_keys, order_keys, active)
+
+    @jax.named_scope("window_segments")
+    def _segments(self, part_keys, order_keys, active):
+        cap = self.capacity
         self.active = active[self.perm]
         s_part = [(d[self.perm], None if v is None else v[self.perm])
                   for d, v in part_keys]

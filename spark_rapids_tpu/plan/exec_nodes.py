@@ -7,7 +7,6 @@ basicPhysicalOperators.scala:1096 (GpuRangeExec), GpuExpandExec.scala.
 
 from __future__ import annotations
 
-import functools
 from typing import Iterator, List, Optional, Tuple
 
 import jax
@@ -18,11 +17,17 @@ from .. import types as T
 from ..batch import ColumnBatch, DeviceColumn, Field, HostStringColumn, Schema
 from ..exprs import EvalContext, Expression
 from ..ops import batch_utils, groupby
+from ..ops.strings import key_view
 from ..utils.metrics import upload
-from .physical import ExecContext, TpuExec, program
+from .physical import ExecContext, TpuExec, _cached_program, program
 
 __all__ = ["SortExec", "LimitExec", "UnionExec", "RangeExec", "ExpandExec",
            "plan_join"]
+
+
+# a string-keyed sort of at most this many rows runs on the host
+# (SortExec._sort_on_host has the two prices)
+_HOST_SORT_ROWS = 4096
 
 
 class SortExec(TpuExec):
@@ -65,12 +70,50 @@ class SortExec(TpuExec):
         nf = tuple(n for _, _, n in self.orders)
         return key_exprs, desc, nf
 
-    def _sort_batch(self, whole: ColumnBatch) -> ColumnBatch:
+    def _string_key_ordinals(self) -> List[int]:
+        from .planner import string_key_ordinals
+        return string_key_ordinals([e for e, _, _ in self.orders])
+
+    def _sort_on_host(self, batch: ColumnBatch, device) -> ColumnBatch:
+        """A few rows under a string key, ordered on the host, where the
+        strings are and the answer is going.  It is what such a sort cost
+        while string keys fell back to the CPU (the top 100 of TPC-DS Q42's
+        nine groups by i_category: 0.128 s a query that way, 0.156 through
+        the device's passes, gathers and their dispatches; my chip runs, PR
+        33); past ``_HOST_SORT_ROWS`` the rows stay on the device (Q89's
+        50,000)."""
+        from ..batch import from_arrow, to_arrow
+        from ..cpu.exec import sort_table
+        return from_arrow(sort_table(to_arrow(batch), batch.schema,
+                                     self.orders), device=device)
+
+    def _sort_input(self, b: ColumnBatch, device) -> ColumnBatch:
+        """One input batch in order.  A string-keyed sort takes a batch of
+        small CAPACITY to the host as it stands: ``to_arrow`` reads its
+        selection in the fetch it makes anyway, where compacting first
+        costs a fetch of its own for the live count."""
+        if b.capacity <= _HOST_SORT_ROWS and self._string_key_ordinals():
+            return self._sort_on_host(b, device)
+        return self._sort_batch(batch_utils.compact(b), device)
+
+    def _sort_batch(self, whole: ColumnBatch, device=None) -> ColumnBatch:
+        """``whole`` (compact: ``num_rows`` live rows in front) in order."""
         key_exprs, desc, nf = self._order_tuples()
+        strings = self._string_key_ordinals()
+        if strings and whole.num_rows <= _HOST_SORT_ROWS:
+            return self._sort_on_host(whole, device)
+        # string keys sort as the ranks of their strings among the batch's
+        # distinct values (ops/strings.key_view): taken from THIS batch, so
+        # every call orders the rows it is given, whatever dictionary they
+        # came with
+        keyed = key_view(whole, strings, True, device)
         arrays = tuple(
             (c.data, c.valid) if isinstance(c, DeviceColumn) else None
-            for c in whole.columns)
-        perm = _sort_perm(key_exprs, desc, nf)(
+            for c in keyed.columns)
+        # a string key is new to the device: its sort takes the form that
+        # compiles by the pass (TPC-DS Q89's top-k, one float and one
+        # string key, compiled for 136 s as one lexsort at 65,536 rows)
+        perm = _sort_perm(key_exprs, desc, nf, bool(strings))(
             arrays, jnp.int32(whole.num_rows))
         return batch_utils.gather(whole, perm, whole.num_rows)
 
@@ -104,21 +147,24 @@ class SortExec(TpuExec):
                 with m.time("opTime"):
                     for srt_b in with_retry(
                             ctx, batch,
-                            lambda b: self._sort_batch(
-                                batch_utils.compact(b))):
+                            lambda b: self._sort_input(b, ctx.device)):
                         if srt_b.num_rows == 0:
                             continue
                         total += srt_b.num_rows
                         runs.append(catalog.register(srt_b, priority=2))
             if not runs:
                 return
-            if len(runs) == 1 or total <= batch_rows:
-                # in-core: one more sort over the concatenation
+            if len(runs) == 1 or total <= batch_rows \
+                    or self._string_key_ordinals():
+                # in-core: one more sort over the concatenation (always,
+                # under a string key: its ranks hold within one batch, so
+                # runs cannot be cut at a common boundary)
                 with m.time("opTime"):
                     whole = batch_utils.compact(batch_utils.concat_batches(
                         [h.get() for h in runs])) \
                         if len(runs) > 1 else runs[0].get()
-                    out = self._sort_batch(whole) if len(runs) > 1 else whole
+                    out = self._sort_batch(whole, ctx.device) \
+                        if len(runs) > 1 else whole
                 m.add("numOutputRows", out.num_rows)
                 yield out
                 return
@@ -149,8 +195,10 @@ class SortExec(TpuExec):
                     del slices
                     # plain retry only: splitting a range would interleave
                     # the globally-ordered output
-                    outs = list(with_retry(ctx, part, self._sort_batch,
-                                           split=None))
+                    outs = list(with_retry(
+                        ctx, part,
+                        lambda b: self._sort_batch(b, ctx.device),
+                        split=None))
                 for out in outs:
                     m.add("numOutputRows", out.num_rows)
                     yield out
@@ -217,9 +265,10 @@ def _sample_bounds(keys: List[np.ndarray], n_ranges: int):
     return bounds
 
 
-def _sort_perm(key_exprs, desc, nf):
+def _sort_perm(key_exprs, desc, nf, passes: bool = False):
     from .physical import _cached_program
-    fp = "|".join(e.fingerprint() for e in key_exprs) + str(desc) + str(nf)
+    fp = "|".join(e.fingerprint() for e in key_exprs) + str(desc) + str(nf) \
+        + ("|passes" if passes else "")
 
     def build():
         @program("sort")
@@ -228,7 +277,8 @@ def _sort_perm(key_exprs, desc, nf):
             active = jnp.arange(cap, dtype=jnp.int32) < num_rows
             ectx = EvalContext(list(arrays), cap, active=active)
             keys = [e.eval(ectx) for e in key_exprs]
-            return groupby.sort_indices_for_keys(keys, active, desc, nf)
+            return groupby.sort_indices_for_keys(keys, active, desc, nf,
+                                                 passes=passes)
         return f
 
     return _cached_program("sort|" + fp, build)
@@ -270,8 +320,7 @@ class TopKExec(SortExec):
             with m.time("opTime"):
                 for srt in with_retry(
                         ctx, batch,
-                        lambda b: _clip(self._sort_batch(
-                            batch_utils.compact(b)))):
+                        lambda b: _clip(self._sort_input(b, ctx.device))):
                     if srt.num_rows == 0:
                         continue
                     if top is None:
@@ -279,7 +328,7 @@ class TopKExec(SortExec):
                     else:
                         merged = batch_utils.compact(
                             batch_utils.concat_batches([top, srt]))
-                        top = _clip(self._sort_batch(merged))
+                        top = _clip(self._sort_batch(merged, ctx.device))
         if top is None:
             return
         take = top.num_rows - self.offset
@@ -586,7 +635,14 @@ class GenerateExec(TpuExec):
 
 class ExpandExec(TpuExec):
     """Emit one projected batch per projection per input batch
-    (grouping sets — GpuExpandExec.scala)."""
+    (grouping sets — GpuExpandExec.scala).
+
+    A string column is host-carried or dictionary-coded, so a projection
+    cannot compute it: it either passes the column through or, for a key
+    outside the grouping set, puts NULLs in its place: the same dictionary
+    with no valid row where the column is a ``DictStringColumn`` (the
+    aggregate above then reads one dictionary in every batch), a host
+    column of nulls otherwise."""
 
     # pure-device batch-in/batches-out streaming: region-safe
     region_fusible = True
@@ -595,47 +651,110 @@ class ExpandExec(TpuExec):
         super().__init__([child])
         self.projections = projections
         self._schema = out_schema
+        # output position -> the child's column a NULL string stands in for
+        self._string_src = {}
+        for triples in projections:
+            for j, (_name, _e, host_src) in enumerate(triples):
+                if host_src is not None:
+                    self._string_src.setdefault(j, host_src)
 
     @property
     def output_schema(self) -> Schema:
         return self._schema
 
+    def node_desc(self):
+        return f"TpuExpand [{len(self.projections)} projections]"
+
+    def _strings(self, batch: ColumnBatch, j: int, host_src, memo: dict):
+        """Output column ``j`` of one projection: the child's string
+        column ``host_src`` passed through, or (None) NULLs in its place."""
+        from ..batch import DictStringColumn
+        passed = host_src is not None
+        src = host_src if passed else self._string_src.get(j)
+        like = batch.columns[src] if src is not None else None
+        cap = batch.capacity
+        if isinstance(like, DictStringColumn):
+            if passed and like.valid is not None:
+                return like
+            # made FROM the column's codes, so that they sit where it sits:
+            # an array committed to the device and one that is not are
+            # different arguments to jit, and a different program each
+            if passed:  # a validity mask all the same: see _with_validity
+                if "ones" not in memo:
+                    memo["ones"] = jnp.ones_like(like.codes, dtype=bool)
+                return DictStringColumn(like.codes, memo["ones"],
+                                        like.dictionary)
+            if "dev" not in memo:
+                memo["dev"] = (jnp.zeros_like(like.codes, dtype=jnp.int32),
+                               jnp.zeros_like(like.codes, dtype=bool))
+            return DictStringColumn(*memo["dev"], like.dictionary)
+        if passed:
+            return like
+        if "host" not in memo:
+            import pyarrow as pa
+            memo["host"] = HostStringColumn(pa.nulls(cap, type=pa.string()))
+        return memo["host"]
+
     def execute(self, ctx: ExecContext) -> Iterator[ColumnBatch]:
+        from ..utils.metrics import counted_span
         m = ctx.metric_set(self.op_id)
 
-        @functools.lru_cache(maxsize=None)
         def proj_fn(pi: int):
-            triples = self.projections[pi]
+            # what the device computes: not the strings (class docstring)
+            exprs = tuple(
+                None if e is None or f_.dtype.is_string else e
+                for (_n, e, _h), f_ in zip(self.projections[pi],
+                                           self._schema))
 
-            @program("expand_project")
-            def f(arrays, sel, num_rows):
-                cap = next(a[0].shape[0] for a in arrays if a is not None)
-                active = jnp.arange(cap, dtype=jnp.int32) < num_rows
-                if sel is not None:
-                    active = active & sel
-                ectx = EvalContext(list(arrays), cap, active=active)
-                outs = []
-                for name, e, host_src in triples:
-                    outs.append(None if e is None else e.eval(ectx))
-                return tuple(outs), active
-            return f
+            def build():
+                @program("expand_project")
+                def f(arrays, sel, num_rows):
+                    cap = next(a[0].shape[0] for a in arrays
+                               if a is not None)
+                    active = jnp.arange(cap, dtype=jnp.int32) < num_rows
+                    if sel is not None:
+                        active = active & sel
+                    ectx = EvalContext(list(arrays), cap, active=active)
+                    return tuple(None if e is None else _with_validity(
+                        e.eval(ectx), cap) for e in exprs), active
+                return f
 
+            return _cached_program(
+                "expand|" + "|".join("-" if e is None else e.fingerprint()
+                                     for e in exprs), build)
+
+        fns = [proj_fn(pi) for pi in range(len(self.projections))]
         for batch in self.children[0].execute(ctx):
             arrays = tuple(
                 (c.data, c.valid) if isinstance(c, DeviceColumn) else None
                 for c in batch.columns)
+            nulls: dict = {}
             for pi in range(len(self.projections)):
-                with m.time("opTime"):
-                    outs, active = proj_fn(pi)(arrays, batch.sel,
-                                               jnp.int32(batch.num_rows))
+                with counted_span("expand_exec_s", self.op_id,
+                                  "expand:project", "expand") as stats, \
+                        m.time("opTime"):
+                    outs, active = fns[pi](arrays, batch.sel,
+                                           jnp.int32(batch.num_rows))
                     cols = []
-                    for (f_, val, (name, e, host_src)) in zip(
-                            self._schema, outs, self.projections[pi]):
+                    for j, (f_, val, (name, e, host_src)) in enumerate(
+                            zip(self._schema, outs, self.projections[pi])):
                         if val is None:
-                            cols.append(batch.columns[host_src])
+                            cols.append(self._strings(batch, j, host_src,
+                                                      nulls))
                         else:
-                            cols.append(DeviceColumn(f_.dtype, val[0], val[1]))
+                            cols.append(
+                                DeviceColumn(f_.dtype, val[0], val[1]))
+                stats.expand_slot_rows += batch.capacity
                 yield ColumnBatch(self._schema, cols, batch.num_rows, active)
+
+
+def _with_validity(value, cap: int):
+    """Every projection of an Expand hands on the same tree of arrays,
+    validity included, whether its grouping set keeps a key or NULLs it:
+    the aggregate above then compiles one program for all of them, not
+    one a grouping set (nine for TPC-DS Q67)."""
+    data, valid = value
+    return data, (jnp.ones((cap,), dtype=bool) if valid is None else valid)
 
 
 def plan_join(plan, left: TpuExec, right: TpuExec, conf):

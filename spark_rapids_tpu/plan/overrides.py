@@ -24,7 +24,8 @@ from ..batch import Schema
 from ..exprs import BoundReference, Expression, bind
 from . import logical as L
 from .physical import AggregateExec, ScanExec, StageExec, TpuExec
-from .planner import _bind_project, strip_alias
+from .planner import (_bind_project, string_code_predicates,
+                      string_code_source, strip_alias)
 
 __all__ = ["apply_overrides", "explain_plan", "NodeMeta"]
 
@@ -102,6 +103,25 @@ def expr_reasons(e: Expression, allow_string_passthrough: bool = True,
 
     walk(core)
     return reasons
+
+
+def key_reasons(e: Expression) -> List[str]:
+    """Reasons a sort key or a window's partition / order key cannot run
+    on the device.  A string key rides as int32 dictionary codes
+    (ops/strings.key_view: equal strings equal codes, and for an ORDER BY
+    the rank of the string among the column's distinct values), so a bare
+    string column is fine, and so is a CASE that picks between one string
+    column and NULL; any other string expression still needs device
+    string kernels."""
+    core = strip_alias(e)
+    if core.dtype is not None and core.dtype.is_string:
+        src = string_code_source(core)
+        if src is None or src < 0:
+            return ["computed string expression (device string kernels "
+                    "pending)"]
+        return [r for p in string_code_predicates(core)
+                for r in expr_reasons(p, allow_string_passthrough=False)]
+    return expr_reasons(e, allow_string_passthrough=False)
 
 
 # ---------------------------------------------------------------------------------
@@ -196,8 +216,7 @@ class NodeMeta:
         if isinstance(p, L.Sort):
             schema = p.children[0].schema()
             for o in p.orders:
-                b = bind(o.expr, schema)
-                for r in expr_reasons(b, allow_string_passthrough=False):
+                for r in key_reasons(bind(o.expr, schema)):
                     self.will_not_work(f"sort key: {r}")
             return
         if isinstance(p, L.Generate):
@@ -273,7 +292,10 @@ class NodeMeta:
             schema = p.children[0].schema()
             for proj in p.projections:
                 for name, e in proj:
-                    for r in expr_reasons(bind(e, schema)):
+                    b = strip_alias(bind(e, schema))
+                    if isinstance(b, E.Literal) and b.value is None:
+                        continue  # a key outside the grouping set
+                    for r in expr_reasons(b):
                         self.will_not_work(f"{name}: {r}")
             return
         if isinstance(p, L.Window):
@@ -288,11 +310,10 @@ class NodeMeta:
                 if r:
                     self.will_not_work(f"{name}: {r}")
                 for pe in b.spec.partition_by:
-                    for rr in expr_reasons(pe, allow_string_passthrough=False):
+                    for rr in key_reasons(pe):
                         self.will_not_work(f"{name} partition key: {rr}")
                 for o in b.spec.order_by:
-                    for rr in expr_reasons(o.expr,
-                                           allow_string_passthrough=False):
+                    for rr in key_reasons(o.expr):
                         self.will_not_work(f"{name} order key: {rr}")
                 for c in b.func.children:
                     for rr in expr_reasons(c, allow_string_passthrough=False):
@@ -358,8 +379,16 @@ def _plan_aggregate(child_phys: TpuExec, group_bound, agg_bound,
                          string_dicts=shared_dicts)
 
 
-def _convert(meta: NodeMeta, conf: TpuConf) -> TpuExec:
+def _cpu_node(plan: L.LogicalPlan, children: List[TpuExec]) -> TpuExec:
+    """A node placed on the CPU, counted in the query that planned it
+    (``QueryStats.cpu_fallback_nodes``)."""
     from ..cpu.exec import CpuOpExec
+    from ..utils.metrics import QueryStats
+    QueryStats.get().cpu_fallback_nodes += 1
+    return CpuOpExec(plan, children)
+
+
+def _convert(meta: NodeMeta, conf: TpuConf) -> TpuExec:
     p = meta.plan
 
     if not meta.on_tpu:
@@ -371,7 +400,7 @@ def _convert(meta: NodeMeta, conf: TpuConf) -> TpuExec:
             raise AssertionError(
                 f"validateExecsOnTpu: {type(p).__name__} fell back to CPU: "
                 f"{'; '.join(meta.reasons)}")
-        return CpuOpExec(p, [_convert(c, conf) for c in meta.children])
+        return _cpu_node(p, [_convert(c, conf) for c in meta.children])
 
     # fuse supported project/filter chains into one StageExec
     if isinstance(p, (L.Project, L.Filter)):
@@ -517,7 +546,6 @@ def _place(plan: L.LogicalPlan, conf: TpuConf):
             logging.getLogger("spark_rapids_tpu.overrides").info(
                 "plan placement:\n%s", "\n".join(lines))
     if mode == "explainonly" or not conf["spark.rapids.tpu.sql.enabled"]:
-        from ..cpu.exec import CpuOpExec
         # force everything to CPU, preserving the tagging report
         def all_cpu(m: NodeMeta) -> TpuExec:
             p = m.plan
@@ -527,7 +555,7 @@ def _place(plan: L.LogicalPlan, conf: TpuConf):
                 from .exec_nodes import RangeExec
                 return RangeExec(p.start, p.end, p.step,
                                  conf["spark.rapids.tpu.sql.batchSizeRows"])
-            return CpuOpExec(p, [all_cpu(c) for c in m.children])
+            return _cpu_node(p, [all_cpu(c) for c in m.children])
         return all_cpu(meta), False
     return _convert(meta, conf), True
 

@@ -69,3 +69,68 @@ def _bind_project(exprs, schema: Schema):
             triples.append((name, b, None))
             fields.append(Field(name, b.dtype, b.nullable))
     return triples, Schema(fields)
+
+
+def _case_parts(e: Expression):
+    """(conditions, values) of an IF or a CASE, or None for anything else."""
+    from ..exprs import CaseWhen, If
+    if isinstance(e, If):
+        return [e.children[0]], list(e.children[1:])
+    if isinstance(e, CaseWhen):
+        values = [v for _, v in e.branches]
+        if e.otherwise is not None:
+            values.append(e.otherwise)
+        return [c for c, _ in e.branches], values
+    return None
+
+
+def string_code_source(e: Expression):
+    """Where a string-valued key expression takes its strings from.
+
+    A sort or a window runs string keys as int32 dictionary codes
+    (``ops/strings.key_view``).  That works for a bare string column and
+    for a CASE / IF that picks between ONE string column and NULL (TPC-DS
+    Q36's ``case when grouping(i_class) = 0 then i_category end``): the
+    branches select codes as they would select strings.  Returns the
+    column's ordinal, -1 where every branch is a NULL literal, or None for
+    any other string expression (device string kernels pending).
+    """
+    from ..exprs import Literal
+    e = strip_alias(e)
+    if isinstance(e, BoundReference):
+        return e.ordinal if e.dtype.is_string else None
+    if isinstance(e, Literal):
+        return -1 if e.value is None else None
+    parts = _case_parts(e)
+    if parts is None:
+        return None
+    src = -1
+    for v in parts[1]:
+        s = string_code_source(v)
+        if s is None or (s >= 0 and src >= 0 and s != src):
+            return None
+        src = max(src, s)
+    return src
+
+
+def string_code_predicates(e: Expression) -> List[Expression]:
+    """The conditions of a :func:`string_code_source` expression: they run
+    on the device as they stand, so the planner tags them like any other
+    computed expression."""
+    parts = _case_parts(strip_alias(e))
+    if parts is None:
+        return []
+    return parts[0] + [p for v in parts[1]
+                       for p in string_code_predicates(v)]
+
+
+def string_key_ordinals(exprs) -> List[int]:
+    """Ordinals of the string columns that ``exprs`` (bound key
+    expressions) read as dictionary codes."""
+    out = []
+    for e in exprs:
+        if e.dtype is not None and e.dtype.is_string:
+            s = string_code_source(e)
+            if s is not None and s >= 0 and s not in out:
+                out.append(s)
+    return out

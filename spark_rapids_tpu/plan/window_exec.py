@@ -9,6 +9,12 @@ then every function is a segmented scan/reduce over it (ops/window.py).
 Output rows are emitted in (partition, order) sorted order, which is the
 order Spark's WindowExec produces (it requires sorted input and preserves
 it).
+
+String partition and order keys enter the program as int32 dictionary
+codes (ops/strings.key_view): equal strings have equal codes, which is all
+a partition needs, and an ORDER BY key gets the rank of each string among
+the column's distinct values.  The strings themselves ride through the
+gather as the columns they were (a ``DictStringColumn`` stays on the device).
 """
 
 from __future__ import annotations
@@ -22,7 +28,9 @@ import numpy as np
 from ..batch import ColumnBatch, DeviceColumn, Field, HostStringColumn, Schema
 from ..exprs import EvalContext
 from ..ops import batch_utils
+from ..ops.strings import key_view
 from ..ops.window import SortedWindowContext
+from ..utils.metrics import counted_span
 from ..windowfns import WindowExpression
 from .physical import ExecContext, TpuExec, _cached_program, program
 
@@ -57,6 +65,17 @@ class WindowExec(TpuExec):
     def _fingerprint(self) -> str:
         return "|".join(e.fingerprint() for _, e in self.window_exprs)
 
+    def _string_key_ordinals(self):
+        """Ordinals of the string columns the spec's keys read: they enter
+        the program as int32 dictionary codes (ops/strings.key_view),
+        order-preserving ones where an ORDER BY key reads the column."""
+        from .planner import string_key_ordinals
+        spec = self.window_exprs[0][1].spec
+        ordered = string_key_ordinals([o.expr for o in spec.order_by])
+        equal = [o for o in string_key_ordinals(spec.partition_by)
+                 if o not in ordered]
+        return equal, ordered
+
     def _build_fn(self):
         wexprs = [e for _, e in self.window_exprs]
         spec = wexprs[0].spec
@@ -71,7 +90,8 @@ class WindowExec(TpuExec):
                 part_keys, order_keys,
                 [not o.ascending for o in spec.order_by],
                 [o.nulls_first for o in spec.order_by], active)
-            outs = tuple(we.window_eval(w, ectx) for we in wexprs)
+            with jax.named_scope("window_scans"):
+                outs = tuple(we.window_eval(w, ectx) for we in wexprs)
             return w.perm, outs
 
         return fn
@@ -81,16 +101,28 @@ class WindowExec(TpuExec):
         batches = list(self.children[0].execute(ctx))
         if not batches:
             return
+        with counted_span("window_exec_s", self.op_id, "window:exec",
+                          "window") as stats:
+            result = self._run(ctx, m, batches, stats)
+        m.add("numOutputRows", result.num_rows)
+        m.add("numOutputBatches", 1)
+        yield result
+
+    def _run(self, ctx, m, batches, stats) -> ColumnBatch:
         whole = batch_utils.compact(batch_utils.concat_batches(batches)) \
             if len(batches) > 1 else batch_utils.compact(batches[0])
+        stats.window_rows += whole.num_rows
+        equal, ordered = self._string_key_ordinals()
         with m.time("opTime"):
             fn = _cached_program("window|" + self._fingerprint(),
                                  lambda: program("window", self._build_fn()))
 
             def run(b: ColumnBatch):
+                keyed = key_view(key_view(b, equal, False, ctx.device),
+                                 ordered, True, ctx.device)
                 arrays = tuple(
                     (c.data, c.valid) if isinstance(c, DeviceColumn) else None
-                    for c in b.columns)
+                    for c in keyed.columns)
                 return b, fn(arrays, np.int32(b.num_rows))
 
             # retry protocol like sort/agg, but split=None: a window frame
@@ -105,7 +137,4 @@ class WindowExec(TpuExec):
             for (name, we), (d, v) in zip(self.window_exprs, outs):
                 cols.append(DeviceColumn(
                     we.dtype, d.astype(we.dtype.numpy_dtype), v))
-        result = ColumnBatch(self._schema, cols, whole.num_rows)
-        m.add("numOutputRows", result.num_rows)
-        m.add("numOutputBatches", 1)
-        yield result
+        return ColumnBatch(self._schema, cols, whole.num_rows)

@@ -192,6 +192,22 @@ class DataFrame:
 
     groupBy = group_by
 
+    def rollup(self, *cols: Union[str, Column]) -> "GroupedData":
+        """GROUP BY ROLLUP(cols): the n+1 grouping sets cols[:n], ...,
+        cols[:1], () in one pass.  ``agg`` lowers them to an Expand under
+        the ordinary aggregate; ``functions.grouping`` / ``grouping_id``
+        tell a NULL the set put there from one in the data."""
+        n = len(cols)
+        return GroupedData(self, [_named(c) for c in cols],
+                           [tuple(range(k)) for k in range(n, -1, -1)])
+
+    def cube(self, *cols: Union[str, Column]) -> "GroupedData":
+        """GROUP BY CUBE(cols): every subset of cols, the full set first."""
+        n = len(cols)
+        sets = [tuple(i for i in range(n) if not (m >> (n - 1 - i)) & 1)
+                for m in range(1 << n)]
+        return GroupedData(self, [_named(c) for c in cols], sets)
+
     def agg(self, *cols: Column) -> "DataFrame":
         return GroupedData(self, []).agg(*cols)
 
@@ -541,14 +557,86 @@ class PivotedData:
         return self.agg(F.first(F.col(name)))
 
 
+GROUPING_ID = "spark_grouping_id"
+
+
+def _plan_grouping_sets(child: L.LogicalPlan, group_exprs, sets, agg_exprs
+                        ) -> L.LogicalPlan:
+    """ROLLUP / CUBE as Spark plans them: an Expand with one projection a
+    grouping set (the columns the aggregates read as they are, a copy of
+    every grouping key with NULL where the key is outside the set, and the
+    set's number as ``spark_grouping_id``), the ordinary Aggregate over
+    keys + grouping id (so a NULL key in the data and a key the set
+    null-ed stay different groups), and a Project that drops the id.
+    ``grouping(col)`` / ``grouping_id()`` become bits of the id."""
+    from .. import bitwisefns as B
+    from .. import types as T
+    from ..exprs import bind
+    from .functions import _GroupingMarker
+
+    n = len(group_exprs)
+    schema = child.schema()
+    gid = E.UnresolvedColumn(GROUPING_ID)
+    key_fps = [e.fingerprint() for _, e in group_exprs]
+
+    def rewrite(e):
+        if isinstance(e, _GroupingMarker):
+            if not e.children:
+                return gid
+            fp = e.children[0].fingerprint()
+            if fp not in key_fps:
+                raise ValueError(
+                    f"grouping() of {e.children[0]!r}: not one of the "
+                    f"grouping columns {[k for k, _ in group_exprs]}")
+            shift = n - 1 - key_fps.index(fp)
+            return E.Cast(B.BitwiseAnd(
+                B.ShiftRight(gid, E.Literal(shift, T.INT32)),
+                E.Literal(1, T.INT64)), T.INT8)
+        if not e.children:
+            return e
+        import copy
+        node = copy.copy(e)
+        node.children = tuple(rewrite(c) for c in e.children)
+        return node
+
+    agg_exprs = [(name, rewrite(e)) for name, e in agg_exprs]
+    reads = sorted(set().union(*[e.references() for _, e in agg_exprs])
+                   - {GROUPING_ID})
+    null_of = [E.Literal(None, bind(e, schema).dtype) for _, e in group_exprs]
+    projections = []
+    for members in sets:
+        number = sum(1 << (n - 1 - i) for i in range(n) if i not in members)
+        projections.append(
+            [(r, E.UnresolvedColumn(r)) for r in reads]
+            + [(f"__gk{i}", e if i in members else null_of[i])
+               for i, (_, e) in enumerate(group_exprs)]
+            + [(GROUPING_ID, E.Literal(number, T.INT64))])
+    keys = [(name, E.UnresolvedColumn(f"__gk{i}"))
+            for i, (name, _) in enumerate(group_exprs)] + [(GROUPING_ID, gid)]
+    node = _decompose_agg_exprs(L.Expand(child, projections), keys,
+                                agg_exprs)
+    return L.Project(node, [(name, E.UnresolvedColumn(name))
+                            for name, _ in group_exprs + agg_exprs])
+
+
 class GroupedData:
-    def __init__(self, df: DataFrame, group_exprs):
+    def __init__(self, df: DataFrame, group_exprs, grouping_sets=None):
         self._df = df
         self._group_exprs = group_exprs
+        # rollup / cube: tuples of indices into group_exprs, the full set
+        # first; None for a plain GROUP BY
+        self._grouping_sets = grouping_sets
 
     def agg(self, *cols: Column) -> DataFrame:
         agg_exprs = [_named(c) for c in cols]
         cd = _split_count_distinct(agg_exprs)
+        if self._grouping_sets is not None:
+            if cd is not None:
+                raise NotImplementedError(
+                    "count(DISTINCT) under rollup / cube")
+            return DataFrame(_plan_grouping_sets(
+                self._df._plan, self._group_exprs, self._grouping_sets,
+                agg_exprs), self._df.session)
         if cd is not None:
             return _plan_count_distinct(self._df, self._group_exprs,
                                         *cd,

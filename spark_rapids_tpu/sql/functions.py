@@ -18,6 +18,7 @@ __all__ = [
     "to_json",
     "col", "lit", "when", "coalesce", "isnull", "isnan", "expr_abs",
     "sum", "count", "count_star", "min", "max", "avg", "mean", "first", "last",
+    "grouping", "grouping_id",
     "row_number", "rank", "dense_rank", "percent_rank", "cume_dist", "ntile",
     "lag", "lead", "parse_type",
     # math
@@ -368,6 +369,37 @@ class _CountDistinctMarker(E.Expression):
 
     def _fp_extra(self):
         return "count_distinct"
+
+
+class _GroupingMarker(E.Expression):
+    """``grouping(col)`` (one child) or ``grouping_id()`` (none): consumed
+    by the ``agg`` of a ``rollup`` / ``cube``, which rewrites it to bits of
+    the ``spark_grouping_id`` column its Expand adds."""
+
+    nullable = False
+
+    def __init__(self, cols=()):
+        self.children = tuple(cols)
+        self.dtype = T.INT8 if self.children else T.INT64
+
+    def references(self) -> set:
+        return set()  # reads the grouping id, not the column it names
+
+    def _fp_extra(self):
+        return "grouping" if self.children else "grouping_id"
+
+
+def grouping(c) -> Column:
+    """1 where ``c`` is aggregated away in the row's grouping set (its NULL
+    comes from the set, not the data), else 0; under rollup / cube only."""
+    return Column(_GroupingMarker([to_expr(col(c) if isinstance(c, str)
+                                           else c)]))
+
+
+def grouping_id() -> Column:
+    """The row's grouping set as Spark numbers it: bit ``n-1-i`` is set
+    where the i-th of the n grouping columns is aggregated away."""
+    return Column(_GroupingMarker())
 
 
 def count_star() -> Column:

@@ -110,6 +110,21 @@ class QueryStats:
         # scatters (decided in the program, read in the tail fetch)
         self.agg_dense_batches = 0
         self.agg_dense_compacted_batches = 0
+        # the reporting operators (plan/window_exec.py, plan/exec_nodes.py
+        # ExpandExec): seconds inside ``window:exec`` spans (concat,
+        # compact, the program, the gather) and the live rows that entered
+        # the window (the host knows them after the compact); seconds
+        # inside ``expand:project`` spans and the slots the expansion
+        # handed on (capacity x projections x batches, live or not: what
+        # the aggregate above it pays for); plan nodes placed on the CPU
+        # (plan/overrides.py: ``CpuOpExec`` in a plan that was run).
+        # Whole-span seconds, like decode_s: they overlap the account's
+        # terms and add to nothing
+        self.window_exec_s = 0.0
+        self.window_rows = 0
+        self.expand_exec_s = 0.0
+        self.expand_slot_rows = 0
+        self.cpu_fallback_nodes = 0
         # the query's host-time account (utils/tracing.account): nine
         # disjoint terms of the DRIVING thread's time, by span self
         # time, that sum to ``query_wall_s``.  Unlike fetch_wait_s /
@@ -409,6 +424,20 @@ def upload(tree, device=None):
         leaf.nbytes for leaf in jax.tree_util.tree_leaves(out))
     s.upload_s += sp.dur
     return out
+
+
+@contextlib.contextmanager
+def counted_span(field: str, op_id, name: str, cat: str):
+    """A tracing span whose whole seconds also add to the running query's
+    ``QueryStats.<field>`` (``window_exec_s``, ``expand_exec_s``): like
+    ``decode_s`` they overlap the account's terms and add to nothing."""
+    stats = QueryStats.get()
+    sp = tracing.span(op_id, name, cat)
+    try:
+        with sp:
+            yield stats
+    finally:
+        setattr(stats, field, getattr(stats, field) + sp.dur)
 
 
 def _start_copies(tree) -> None:

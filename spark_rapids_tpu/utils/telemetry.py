@@ -205,6 +205,20 @@ METRICS = (
      "Dense-aggregation batches whose in-domain rows were compacted on "
      "the device before the scatters (chosen in the program from the "
      "row count)."),
+    # the reporting operators (window, expand) and CPU placement
+    ("query_window_exec_seconds_total", "counter", "",
+     "Seconds inside window:exec spans: a window's input concatenated "
+     "and compacted, its program, the gather into sorted order."),
+    ("query_window_rows_total", "counter", "",
+     "Live rows that entered device windows."),
+    ("query_expand_exec_seconds_total", "counter", "",
+     "Seconds inside expand:project spans: one grouping set's "
+     "projection of one batch."),
+    ("query_expand_slot_rows_total", "counter", "",
+     "Slots grouping-set expansions handed on (capacity x projections "
+     "x batches, live or not)."),
+    ("query_cpu_fallback_nodes_total", "counter", "",
+     "Plan nodes placed on the CPU (CpuOpExec) in plans that were run."),
     # the host-time account (utils/tracing.account): disjoint shares of
     # the driving thread's time; the nine terms sum to the wall
     ("query_wall_seconds_total", "counter", "",
@@ -386,6 +400,11 @@ _QS_FOLD = (
     ("ici_exchange_bytes", "query_ici_exchange_bytes_total"),
     ("ici_overflow_retries", "query_ici_overflow_retries_total"),
     ("ici_compacted_exchanges", "query_ici_compacted_exchanges_total"),
+    ("window_exec_s", "query_window_exec_seconds_total"),
+    ("window_rows", "query_window_rows_total"),
+    ("expand_exec_s", "query_expand_exec_seconds_total"),
+    ("expand_slot_rows", "query_expand_slot_rows_total"),
+    ("cpu_fallback_nodes", "query_cpu_fallback_nodes_total"),
     ("agg_dense_batches", "query_agg_dense_batches_total"),
     ("agg_dense_compacted_batches",
      "query_agg_dense_compacted_batches_total"),

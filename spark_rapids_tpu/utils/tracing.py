@@ -174,6 +174,16 @@ SPANS = (
      "on the mesh and brought to a host table (program:"
      "ici_fragment_gather, fetch:blocking and result:arrow nest inside; "
      "QueryStats.ici_gather_s)."),
+    ("window:exec", "host_exec",
+     "plan/window_exec.py WindowExec: its input concatenated and "
+     "compacted, string keys to dictionary codes, the window program, "
+     "the gather into sorted order (program:window, eager:gather and "
+     "the compact's fetch nest inside and keep their own terms; "
+     "QueryStats.window_exec_s, window_rows)."),
+    ("expand:project", "dispatch",
+     "plan/exec_nodes.py ExpandExec: one projection of one batch "
+     "(program:expand_project nests inside; QueryStats.expand_exec_s, "
+     "expand_slot_rows)."),
     ("op:", "host_exec",
      "instrument_batches (one pull through an exec node) and "
      "MetricSet.time (op:opTime, op:scanTime, op:buildTime): the "
