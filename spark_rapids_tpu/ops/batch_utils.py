@@ -24,7 +24,8 @@ from ..batch import (ColumnBatch, DeviceColumn, DictStringColumn,
                      HostStringColumn, Schema, bucket_capacity)
 from ..utils.metrics import fetch, fetch_scalars
 
-__all__ = ["concat_batches", "compact", "slice_batch", "gather"]
+__all__ = ["concat_batches", "concat_packed", "compact", "slice_batch",
+           "gather"]
 
 
 def _pad_dev(arr: jax.Array, cap: int):
@@ -124,6 +125,36 @@ def concat_batches(batches: Sequence[ColumnBatch],
     if all(x is not None for x in bounds):
         out.bound = sum(bounds)
     return out
+
+
+def concat_packed(batches: Sequence[ColumnBatch]) -> ColumnBatch:
+    """Concatenate COMPACT batches live rows to live rows.
+
+    A compact batch has no selection mask: its live rows are its first
+    ``num_rows``, a count the host holds.  So the result can take the
+    rung over the SUM OF THE LIVE ROWS, not over the sum of capacities
+    as :func:`concat_batches` must, and what runs over it (a group sort,
+    segment sums) pays for rows, not for each part's slack under its own
+    rung.  Nothing is fetched.  Where that rung is no smaller, or a batch
+    carries a mask or a column that is not a plain device one, this IS
+    ``concat_batches``."""
+    total = sum(b.num_rows for b in batches)
+    cap = bucket_capacity(total, 1024)    # concat_batches' floor
+    if len(batches) < 2 \
+            or cap >= bucket_capacity(sum(b.capacity for b in batches),
+                                      1024) \
+            or any(b.sel is not None or type(c) is not DeviceColumn
+                   for b in batches for c in b.columns):
+        return concat_batches(batches)
+    spec = tuple((c.data.dtype.name, tuple(c.data.shape[1:]),
+                  tuple(b.columns[ci].valid is not None for b in batches))
+                 for ci, c in enumerate(batches[0].columns))
+    outs = _concat_packed_fn(tuple(b.capacity for b in batches), cap, spec)(
+        tuple(tuple((c.data, c.valid) for c in b.columns) for b in batches),
+        tuple(np.int32(b.num_rows) for b in batches))
+    cols = [DeviceColumn(f.dtype, d, v)
+            for f, (d, v) in zip(batches[0].schema, outs)]
+    return ColumnBatch(batches[0].schema, cols, total)
 
 
 def gather(batch: ColumnBatch, indices: jax.Array, num_rows: int,
@@ -288,6 +319,42 @@ def _concat_fn(caps: tuple, out_cap: int, col_kind: tuple, spec: tuple,
             outs.append((data, valid))
         sel = _pad_dev(jnp.concatenate(actives), out_cap)
         return tuple(outs), sel
+
+    return f
+
+
+@functools.lru_cache(maxsize=512)
+def _concat_packed_fn(caps: tuple, out_cap: int, spec: tuple):
+    """One jitted program laying N compact batches end to end: batch i
+    is written whole at the sum of the live rows before it, in order, so
+    the slack past its live rows is overwritten by batch i+1.  The work
+    array is ``max(caps)`` longer than the result: an update that ran
+    past the end would be clamped back over live rows."""
+    from ..plan.physical import program
+    work = out_cap + max(caps)
+
+    @program("batch_concat_packed")
+    def f(entries, num_rows_tuple):
+        starts = [jnp.int32(0)]
+        for n in num_rows_tuple[:-1]:
+            starts.append(starts[-1] + n)
+        outs = []
+        for ci, (dt, extra, valids_present) in enumerate(spec):
+            data = jnp.zeros((work,) + extra, dtype=dt)
+            valid = jnp.zeros((work,), dtype=bool) \
+                if any(valids_present) else None
+            for bi, start in enumerate(starts):
+                d, v = entries[bi][ci]
+                data = jax.lax.dynamic_update_slice_in_dim(
+                    data, d, start, axis=0)
+                if valid is not None:
+                    valid = jax.lax.dynamic_update_slice_in_dim(
+                        valid, v if v is not None
+                        else jnp.ones((caps[bi],), dtype=bool), start,
+                        axis=0)
+            outs.append((data[:out_cap],
+                         None if valid is None else valid[:out_cap]))
+        return tuple(outs)
 
     return f
 

@@ -332,7 +332,7 @@ PROGRAM_NAMES = frozenset((
     "agg_grouped", "agg_grid", "agg_passthrough", "agg_sample",
     "agg_bucket_pid", "agg_finalize", "agg_merge_grouped",
     "sort", "sort_range_key", "generate_gather", "expand_project",
-    "exchange_pid", "batch_concat", "batch_compact",
+    "exchange_pid", "batch_concat", "batch_concat_packed", "batch_compact",
     "batch_compact_scatter", "batch_slice",
     "smj_filter_stats", "smj_filter_vals", "join_subpid",
     "join_cond_expand", "join_cond", "join_residual", "join_match",
@@ -1238,7 +1238,8 @@ class AggregateExec(TpuExec):
             if _bcap(max(n_groups, 1)) < D:
                 pending = batch_utils.compact(pending, n_live=n_groups)
             for part in left_parts:
-                pending = self._merge_partials(pending, part, ops, 1)
+                pending = self._merge_partials(
+                    [pending, batch_utils.compact_packed(part)], ops, 1)
             out = self._finalize_grouped(pending)
             if left_parts:
                 m.add("numOutputRows", out.row_count())
@@ -1591,7 +1592,9 @@ class AggregateExec(TpuExec):
                 # domain inflate downstream operators to D capacity
                 pending = batch_utils.compact(pending, n_live=n_groups)
             for part in left_parts:
-                pending = self._merge_partials(pending, part, ops, n_keys)
+                pending = self._merge_partials(
+                    [pending, batch_utils.compact_packed(part)], ops,
+                    n_keys)
             out = self._finalize_grouped(pending)
             if left_parts:
                 m.add("numOutputRows", out.row_count())
@@ -1605,8 +1608,7 @@ class AggregateExec(TpuExec):
         """Violation/ineligibility fallback: run the buffered (and any
         remaining) batches through the generic sort path."""
         import itertools
-        n_keys = len(self.group_exprs)
-        pending = None
+        held = _HeldPartials(self, ops, len(self.group_exprs))
         stream = buffered if rest is None else itertools.chain(
             buffered, rest)
         for batch in stream:
@@ -1614,12 +1616,9 @@ class AggregateExec(TpuExec):
                 continue
             batch = self._encode_string_keys(batch, ctx)
             with m.time("opTime"):
-                part = sort_part_fn(batch)
-                if pending is None:
-                    pending = batch_utils.compact_packed(part)
-                else:
-                    pending = self._merge_partials(pending, part, ops,
-                                                   n_keys)
+                held.add(sort_part_fn(batch))
+        with m.time("opTime"):
+            pending = held.take()
         if pending is None:
             yield ColumnBatch(self._schema, self._empty_cols(), 0)
             return
@@ -1853,7 +1852,7 @@ class AggregateExec(TpuExec):
                     // max(width, 1))
         buckets = None
         bucket_over = None  # single OR-accumulated device overflow flag
-        pending: Optional[ColumnBatch] = None
+        held = _HeldPartials(self, ops, n_keys, limit)
         for batch in child_batches:
             out_now: List[ColumnBatch] = []
             with m.time("opTime"):
@@ -1886,16 +1885,13 @@ class AggregateExec(TpuExec):
                                 bucket_over = flag if bucket_over is None \
                                     else (bucket_over | flag)
                             continue
-                        if pending is None:
-                            pending = batch_utils.compact_packed(part,
-                                                                 bound=gb)
-                        else:
-                            pending = self._merge_partials(
-                                pending, part, ops, n_keys, bound=gb)
-                        if gb is None and pending.num_rows > limit:
+                        held.add(part, bound=gb)
+                        if gb is None and held.rows > limit:
+                            # add() merged before its rows could pass
+                            # the limit: the count is the merged one
+                            pending = held.take()
                             if self.mode == "partial":
                                 out_now.append(pending)
-                                pending = None
                             else:
                                 nb = ctx.conf[
                                     "spark.rapids.tpu.sql.agg"
@@ -1903,7 +1899,6 @@ class AggregateExec(TpuExec):
                                 buckets = self._split_by_key_hash(
                                     pending, n_keys, nb)
                                 m.add("aggRepartitions", 1)
-                                pending = None
             for ob in out_now:
                 m.add("numOutputRows", ob.num_rows)
                 yield ob
@@ -1930,6 +1925,8 @@ class AggregateExec(TpuExec):
             if not any_rows:
                 yield ColumnBatch(self._schema, self._empty_cols(), 0)
             return
+        with m.time("opTime"):
+            pending = held.take()
         if pending is None:
             yield ColumnBatch(self._schema, self._empty_cols(), 0)
             return
@@ -2127,33 +2124,40 @@ class AggregateExec(TpuExec):
         """Merge one hash bucket's pending with a piece; stays bounded at
         ``limit`` live rows (sync-free slice) and returns a device
         overflow flag, all flags checked ONCE at stream end."""
-        both = batch_utils.concat_batches([a, piece])
-        arrays = tuple((c.data, c.valid) for c in both.columns)
-        merge = _merge_fn(tuple(ops), n_keys)
-        ok, ov, gmask = merge(arrays, both.sel, np.int32(both.num_rows))
-        merged = self._to_buffer_batch(both.schema, list(ok), list(ov),
-                                       gmask)
+        merged = self._concat_merge([a, piece], ops, n_keys)
+        gmask = merged.sel
         from ..batch import bucket_capacity
         cap = bucket_capacity(min(limit, merged.capacity))
         over = jnp.any(gmask[cap:]) if cap < merged.capacity \
             else jnp.zeros((), dtype=bool)
         return batch_utils.compact_packed(merged, bound=limit), over
 
-    def _merge_partials(self, a: ColumnBatch, b: ColumnBatch, ops, n_keys,
-                        bound=None):
-        """Concat partial results and re-reduce (concat-merge loop).
-
-        ``b`` arrives at the INPUT batch's full capacity with live groups
-        packed at the front (group_reduce contract) — compact it first or
-        the concat+re-reduce runs over millions of dead rows per merge
-        (measured: Q1 @ SF1 spent ~3s here)."""
-        b = batch_utils.compact_packed(b, bound=bound)
-        both = batch_utils.concat_batches([a, b])
+    def _concat_merge(self, parts: List[ColumnBatch], ops,
+                      n_keys) -> ColumnBatch:
+        """ONE run of ``agg_merge_grouped`` over partial results laid end
+        to end in the order given (the group sort is stable: first/last
+        see rows in that order).  The result is at the concatenation's
+        capacity, groups packed at the front under its mask."""
+        both = batch_utils.concat_packed(parts)
         arrays = tuple((c.data, c.valid) for c in both.columns)
         merge = _merge_fn(tuple(ops), n_keys)
         ok, ov, gmask = merge(arrays, both.sel, np.int32(both.num_rows))
-        merged = self._to_buffer_batch(both.schema, list(ok), list(ov), gmask)
-        return batch_utils.compact_packed(merged, bound=bound)
+        stats = QueryStats.get()
+        stats.agg_merges += 1
+        stats.agg_merge_parts += len(parts)
+        return self._to_buffer_batch(both.schema, list(ok), list(ov), gmask)
+
+    def _merge_partials(self, parts: List[ColumnBatch], ops, n_keys,
+                        bound=None) -> ColumnBatch:
+        """Concat partial results and re-reduce (concat-merge loop).
+
+        A part comes out of ``group_reduce`` at the INPUT batch's full
+        capacity with live groups packed at the front: the caller has
+        compacted it (``compact_packed``), or the concat+re-reduce runs
+        over millions of dead rows per merge (measured: Q1 @ SF1 spent
+        ~3s here)."""
+        return batch_utils.compact_packed(
+            self._concat_merge(parts, ops, n_keys), bound=bound)
 
     def _finalize_grouped(self, pending: ColumnBatch) -> ColumnBatch:
         n_keys = len(self.group_exprs)
@@ -2237,6 +2241,86 @@ def _merge_fn(ops: tuple, n_keys: int):
         return tuple(ok), tuple(ov), gmask
 
     return merge
+
+
+# A concat is one program per tuple of capacities: however long a stream
+# and however few its groups, no merge takes more partials than this.
+_MERGE_FAN_IN = 16
+
+
+class _HeldPartials:
+    """The partial results a sort-path aggregate holds between merges.
+
+    Every concat-merge loop of the sort path keeps its partials here,
+    each compacted to the rung over its own groups, and merges them all
+    in ONE ``agg_merge_grouped`` (the last merge's result first, then the
+    partials in arrival order) only when
+
+    * the stream has ended (:meth:`take`);
+    * the rows held are twice the largest piece held (the last merge's
+      result, or a partial), so a merge at most doubles work already
+      paid and the merges' total is linear in the rows that arrive (one
+      merge a part re-reduces the first part's rows once for every part
+      after it), AND the pieces fill an input batch's capacity in slots:
+      under that a merge costs a program and two blocking fetches to
+      free less memory than the batch in flight holds;
+    * the rows held could pass ``limit``: the caller consults the limit
+      on merged counts only, so the re-partition fallback and a partial
+      aggregate's early emit fire where one merge a part fires them;
+    * ``_MERGE_FAN_IN`` pieces are held.
+
+    The rule reads what the host has anyway: each piece's ``num_rows``
+    after its compact (under a grid ``bound`` the static slice's, capped
+    by the bound: the compacts stay sync-free), capacities, ``limit``."""
+
+    def __init__(self, agg: "AggregateExec", ops, n_keys: int,
+                 limit: Optional[int] = None):
+        self._agg = agg
+        self._ops = ops
+        self._n_keys = n_keys
+        self._limit = limit
+        self._bound = None
+        self._parts: List[ColumnBatch] = []
+
+    def _piece_rows(self) -> List[int]:
+        """The most rows each piece can hold (a grid bound only grows)."""
+        return [p.num_rows if self._bound is None
+                else min(p.num_rows, self._bound) for p in self._parts]
+
+    @property
+    def rows(self) -> int:
+        """Rows held: exact after a merge, else the most there can be."""
+        return sum(self._piece_rows())
+
+    def add(self, part: ColumnBatch, bound: Optional[int] = None) -> None:
+        """Hold one ``group_reduce`` output; merge if the rule says so."""
+        batch_slots = part.capacity
+        part = batch_utils.compact_packed(part, bound=bound)
+        if bound is None and part.num_rows == 0:
+            return      # no group: nothing to hold, nothing to merge
+        self._bound = bound
+        self._parts.append(part)
+        rows = self._piece_rows()
+        if (bound is None and self._limit is not None
+                and sum(rows) > self._limit) \
+                or len(self._parts) >= _MERGE_FAN_IN \
+                or (sum(rows) >= 2 * max(rows)
+                    and sum(p.capacity for p in self._parts)
+                    >= batch_slots):
+            self._merge()
+
+    def _merge(self) -> None:
+        if len(self._parts) > 1:
+            self._parts = [self._agg._merge_partials(
+                self._parts, self._ops, self._n_keys, bound=self._bound)]
+
+    def take(self) -> Optional[ColumnBatch]:
+        """Everything held, merged into one batch (None if nothing is
+        held); nothing is held afterwards."""
+        self._merge()
+        out = self._parts[0] if self._parts else None
+        self._parts = []
+        return out
 
 
 # ---------------------------------------------------------------------------------

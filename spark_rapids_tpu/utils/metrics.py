@@ -110,6 +110,13 @@ class QueryStats:
         # scatters (decided in the program, read in the tail fetch)
         self.agg_dense_batches = 0
         self.agg_dense_compacted_batches = 0
+        # the sort path's merges (plan/physical.py): runs of the
+        # ``agg_merge_grouped`` program, counted where it is called, and
+        # the partial results that entered them.  parts / merges is 2.0
+        # where every part is merged into the result as it arrives, and
+        # the parts a merge took where they were held (_HeldPartials)
+        self.agg_merges = 0
+        self.agg_merge_parts = 0
         # the reporting operators (plan/window_exec.py, plan/exec_nodes.py
         # ExpandExec): seconds inside ``window:exec`` spans (concat,
         # compact, the program, the gather) and the live rows that entered

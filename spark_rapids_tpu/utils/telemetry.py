@@ -205,6 +205,13 @@ METRICS = (
      "Dense-aggregation batches whose in-domain rows were compacted on "
      "the device before the scatters (chosen in the program from the "
      "row count)."),
+    ("query_agg_merges_total", "counter", "",
+     "Runs of the agg_merge_grouped program: sort-path partial results "
+     "concatenated and reduced again."),
+    ("query_agg_merge_parts_total", "counter", "",
+     "Partial results that entered those merges: over "
+     "query_agg_merges_total, 2.0 is one merge a part, 9.0 a nine-set "
+     "rollup merged once."),
     # the reporting operators (window, expand) and CPU placement
     ("query_window_exec_seconds_total", "counter", "",
      "Seconds inside window:exec spans: a window's input concatenated "
@@ -408,6 +415,8 @@ _QS_FOLD = (
     ("agg_dense_batches", "query_agg_dense_batches_total"),
     ("agg_dense_compacted_batches",
      "query_agg_dense_compacted_batches_total"),
+    ("agg_merges", "query_agg_merges_total"),
+    ("agg_merge_parts", "query_agg_merge_parts_total"),
     ("query_wall_s", "query_wall_seconds_total"),
     ("acct_plan_s", "query_acct_plan_seconds_total"),
     ("acct_admit_s", "query_acct_admit_seconds_total"),
