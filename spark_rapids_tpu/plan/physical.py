@@ -336,7 +336,8 @@ PROGRAM_NAMES = frozenset((
     "batch_compact_scatter", "batch_slice",
     "smj_filter_stats", "smj_filter_vals", "join_subpid",
     "join_cond_expand", "join_cond", "join_residual", "join_match",
-    "join_expand", "join_unmatched", "bjoin_sort", "bjoin_probe",
+    "join_expand", "join_unmatched", "join_take64", "bjoin_sort",
+    "bjoin_probe",
     "bjoin_csr", "bjoin_csr_probe", "bjoin_dense_stats",
     "bjoin_dense_table", "bjoin_dense_probe",
     "ici_fragment_step", "ici_fragment_gather", "ici_agg_step",

@@ -51,6 +51,10 @@ def _as_predicate(e: E.Expression):
             return (r.name, _FLIP[op], l.value)
         return None
     if isinstance(e, E.In) and isinstance(e.children[0], E.UnresolvedColumn):
+        # an IN over a subquery (plan/subquery.InSubqueryValues, in a
+        # plan explained before it is resolved) has no literal list
+        if not isinstance(e.values, (list, tuple)):
+            return None
         return (e.children[0].name, "in", list(e.values))
     if isinstance(e, E.IsNotNull) and isinstance(e.children[0],
                                                  E.UnresolvedColumn):

@@ -274,8 +274,16 @@ class DataFrame:
 
     def join(self, other: "DataFrame", on=None, how: str = "inner"
              ) -> "DataFrame":
+        """``on``: a column name or a list of them (USING), a list of
+        ``(left, right)`` name pairs, or a boolean ``Column`` over both
+        sides' columns as pyspark takes it: the conjuncts that equate one
+        column of each side become the join's keys, what is left its
+        condition, which takes part in MATCHING (an EXISTS with a
+        non-equi predicate is ``how="semi"`` with one)."""
         if on is None:
             raise NotImplementedError("cross join: use crossJoin")
+        if isinstance(on, Column):
+            return self._join_on_condition(other, on.expr, how)
         if isinstance(on, str):
             on = [on]
         if isinstance(on, (list, tuple)) and all(isinstance(x, str) for x in on):
@@ -292,7 +300,42 @@ class DataFrame:
             node = L.Join(self._plan, other._plan, lk, rk, how=how)
             return DataFrame(node, self.session)
         raise NotImplementedError(
-            "join on: column names or (left, right) name pairs")
+            "join on: column names, (left, right) name pairs or a Column")
+
+    def _join_on_condition(self, other: "DataFrame", on: E.Expression,
+                           how: str) -> "DataFrame":
+        lnames = set(self._plan.schema().names())
+        rnames = set(other._plan.schema().names())
+        # the condition binds over both sides' columns by name: a name
+        # both sides carry could mean either
+        for name in sorted(on.references()):
+            if name in lnames and name in rnames:
+                raise ValueError(
+                    f"join condition: column {name!r} is on both sides; "
+                    f"rename one side's (select(col.alias(...))) first")
+            if name not in lnames and name not in rnames:
+                raise ValueError(
+                    f"join condition: no column {name!r} on either side")
+        from ..plan.optimizer import _and_all, _conjuncts
+        lk, rk, rest = [], [], []
+        for c in _conjuncts(on):
+            a, b = c.children if isinstance(c, E.EqualTo) else (None, None)
+            if isinstance(a, E.UnresolvedColumn) \
+                    and isinstance(b, E.UnresolvedColumn) \
+                    and (a.name in lnames) != (b.name in lnames):
+                left_first = a.name in lnames
+                lk.append(a if left_first else b)
+                rk.append(b if left_first else a)
+            else:
+                rest.append(c)
+        if not lk:
+            if how not in ("inner", "cross"):
+                raise NotImplementedError(
+                    f"{how} join on a condition with no equality between "
+                    f"one column of each side")
+            return self.cross_join(other).filter(Column(on))
+        return DataFrame(L.Join(self._plan, other._plan, lk, rk, how=how,
+                                condition=_and_all(rest)), self.session)
 
     def hint(self, name: str, *args) -> "DataFrame":
         """Planner hint. Supported: "broadcast" — prefer broadcasting this
@@ -442,23 +485,22 @@ def _plan_count_distinct(df, group_exprs, cds, plain, order):
     """count(DISTINCT ...) lowering: one dedup aggregation + count per
     distinct set, joined back to the plain aggregates on the group keys
     (Spark's RewriteDistinctAggregates, single-join form).  Groupless
-    aggregates join via a constant key."""
+    aggregates are each ONE row whatever the input holds (a count over no
+    rows is 0, a sum NULL) and join via a constant key put on after they
+    are computed."""
     from . import functions as F
 
     sess = df.session
     keys = [n for n, _ in group_exprs]
     groupless = not keys
-    if groupless:
-        # constant grouping key, dropped at the end
-        df = df.with_column("__cd_k", F.lit(1))
-        group_exprs = group_exprs + [
-            ("__cd_k", E.UnresolvedColumn("__cd_k"))]
-        keys = ["__cd_k"]
+
+    def one_row(part):
+        return part.with_column("__cd_k", F.lit(1)) if groupless else part
 
     parts = []
     if plain:
         node = _decompose_agg_exprs(df._plan, group_exprs, plain)
-        parts.append(DataFrame(node, sess))
+        parts.append(one_row(DataFrame(node, sess)))
     for idx, (name, cols) in enumerate(cds):
         # marker children are already expressions
         dcols = [(f"__cd{idx}_{i}", c) for i, c in enumerate(cols)]
@@ -471,10 +513,12 @@ def _plan_count_distinct(df, group_exprs, cds, plain, order):
         for n_, _ in dcols:
             c_ = F.col(n_).is_not_null()
             cond = c_ if cond is None else (cond & c_)
-        cnt = (dedup.group_by(*keys)
-               .agg(F.sum(F.when(cond, F.lit(1)).otherwise(
-                   F.lit(0))).alias(name)))
-        parts.append(cnt)
+        cnt = F.sum(F.when(cond, F.lit(1)).otherwise(F.lit(0)))
+        if groupless:
+            cnt = F.coalesce(cnt, F.lit(0))
+        parts.append(one_row(dedup.group_by(*keys).agg(cnt.alias(name))))
+    if groupless:
+        keys = ["__cd_k"]
     out = parts[0]
     for p_ in parts[1:]:
         renamed = p_

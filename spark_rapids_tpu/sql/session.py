@@ -689,5 +689,21 @@ class Session:
                         yield t
 
     def _explain(self, plan: L.LogicalPlan) -> str:
+        """The plan as it would run.  ``IN (subquery)`` as a filter
+        conjunct is a rewrite to a semi join and runs nothing, so it is
+        shown rewritten; a scalar or NOT IN subquery would have to
+        execute: the plan is then explained as written."""
         from ..plan.overrides import explain_plan
+        from ..plan.subquery import resolve_subqueries
+
+        class NeedsARun(Exception):
+            pass
+
+        def refuse(_subplan):
+            raise NeedsARun
+
+        try:
+            plan = resolve_subqueries(plan, refuse)
+        except (NeedsARun, NotImplementedError):
+            pass
         return explain_plan(plan, self._tpu_conf())

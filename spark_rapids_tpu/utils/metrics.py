@@ -132,6 +132,19 @@ class QueryStats:
         self.expand_exec_s = 0.0
         self.expand_slot_rows = 0
         self.cpu_fallback_nodes = 0
+        # the joins (plan/join_exec.py): seconds inside ``join:pair``
+        # spans (one probe batch against its build side; whole-span
+        # seconds of every thread, like window_exec_s), the candidate
+        # pairs the expansions were sized for (the ``total`` the host
+        # reads before it picks a capacity: every key match, before a
+        # condition drops any), the output slots they ran at (the
+        # capacity rung over that total: what the gathers above pay
+        # for), and the semi, anti and existence joins run (a batch
+        # each), which expand only where a condition takes part
+        self.join_exec_s = 0.0
+        self.join_pairs = 0
+        self.join_out_slots = 0
+        self.join_semi_anti = 0
         # the query's host-time account (utils/tracing.account): nine
         # disjoint terms of the DRIVING thread's time, by span self
         # time, that sum to ``query_wall_s``.  Unlike fetch_wait_s /
@@ -436,7 +449,8 @@ def upload(tree, device=None):
 @contextlib.contextmanager
 def counted_span(field: str, op_id, name: str, cat: str):
     """A tracing span whose whole seconds also add to the running query's
-    ``QueryStats.<field>`` (``window_exec_s``, ``expand_exec_s``): like
+    ``QueryStats.<field>`` (``window_exec_s``, ``expand_exec_s``,
+    ``join_exec_s``): like
     ``decode_s`` they overlap the account's terms and add to nothing."""
     stats = QueryStats.get()
     sp = tracing.span(op_id, name, cat)

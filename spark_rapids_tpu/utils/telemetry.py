@@ -226,6 +226,18 @@ METRICS = (
      "x batches, live or not)."),
     ("query_cpu_fallback_nodes_total", "counter", "",
      "Plan nodes placed on the CPU (CpuOpExec) in plans that were run."),
+    # the joins (plan/join_exec.py)
+    ("query_join_exec_seconds_total", "counter", "",
+     "Seconds inside join:pair spans: one probe batch joined with its "
+     "build side (the children's work is outside it)."),
+    ("query_join_pairs_total", "counter", "",
+     "Candidate pairs join expansions were sized for (every key match, "
+     "before a condition drops any)."),
+    ("query_join_out_slots_total", "counter", "",
+     "Output slots join expansions ran at (the capacity rung over their "
+     "candidate pairs)."),
+    ("query_join_semi_anti_total", "counter", "",
+     "Semi, anti and existence joins run, a probe batch each."),
     # the host-time account (utils/tracing.account): disjoint shares of
     # the driving thread's time; the nine terms sum to the wall
     ("query_wall_seconds_total", "counter", "",
@@ -412,6 +424,10 @@ _QS_FOLD = (
     ("expand_exec_s", "query_expand_exec_seconds_total"),
     ("expand_slot_rows", "query_expand_slot_rows_total"),
     ("cpu_fallback_nodes", "query_cpu_fallback_nodes_total"),
+    ("join_exec_s", "query_join_exec_seconds_total"),
+    ("join_pairs", "query_join_pairs_total"),
+    ("join_out_slots", "query_join_out_slots_total"),
+    ("join_semi_anti", "query_join_semi_anti_total"),
     ("agg_dense_batches", "query_agg_dense_batches_total"),
     ("agg_dense_compacted_batches",
      "query_agg_dense_compacted_batches_total"),

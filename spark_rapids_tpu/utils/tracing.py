@@ -184,6 +184,14 @@ SPANS = (
      "plan/exec_nodes.py ExpandExec: one projection of one batch "
      "(program:expand_project nests inside; QueryStats.expand_exec_s, "
      "expand_slot_rows)."),
+    ("join:pair", "host_exec",
+     "plan/join_exec.py: one probe batch joined with its build side, "
+     "SortMergeJoinExec._join_pair and the broadcast join's probe of a "
+     "streamed batch, its compaction included (program:join_* / bjoin_*, "
+     "eager:gather and the fetch of the candidate-pair count nest "
+     "inside and keep their own terms; the children's work is outside "
+     "it; QueryStats.join_exec_s, join_pairs, join_out_slots, "
+     "join_semi_anti)."),
     ("op:", "host_exec",
      "instrument_batches (one pull through an exec node) and "
      "MetricSet.time (op:opTime, op:scanTime, op:buildTime): the "
