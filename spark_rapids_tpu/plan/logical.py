@@ -84,10 +84,15 @@ class Filter(LogicalPlan):
 class Aggregate(LogicalPlan):
     def __init__(self, child: LogicalPlan,
                  group_exprs: List[Tuple[str, Expression]],
-                 agg_exprs: List[Tuple[str, Expression]]):
+                 agg_exprs: List[Tuple[str, Expression]],
+                 distinct_one_pass: bool = False):
         self.children = (child,)
         self.group_exprs = group_exprs
         self.agg_exprs = agg_exprs  # each contains an AggregateExpression tree
+        # the level-2 aggregate of a count(DISTINCT) lowered to two stacked
+        # aggregates over one copy of the child (sql/dataframe.py
+        # _plan_distinct_one_pass); counted where the plan is converted
+        self.distinct_one_pass = distinct_one_pass
 
     def schema(self) -> Schema:
         in_schema = self.children[0].schema()
@@ -102,7 +107,8 @@ class Aggregate(LogicalPlan):
 
     def node_desc(self):
         return (f"Aggregate keys=[{', '.join(n for n, _ in self.group_exprs)}] "
-                f"aggs=[{', '.join(n for n, _ in self.agg_exprs)}]")
+                f"aggs=[{', '.join(n for n, _ in self.agg_exprs)}]"
+                + (" distinct_one_pass" if self.distinct_one_pass else ""))
 
 
 class SortOrder:

@@ -429,6 +429,9 @@ def _convert(meta: NodeMeta, conf: TpuConf) -> TpuExec:
         schema = child_phys.output_schema
         group_bound = [(n, bind(e, schema)) for n, e in p.group_exprs]
         agg_bound = [(n, strip_alias(bind(e, schema))) for n, e in p.agg_exprs]
+        if p.distinct_one_pass:
+            from ..utils.metrics import QueryStats
+            QueryStats.get().distinct_one_pass_aggs += 1
         return _plan_aggregate(child_phys, group_bound, agg_bound, conf)
 
     if isinstance(p, L.Distinct):

@@ -481,13 +481,142 @@ def _split_count_distinct(agg_exprs):
     return cds, plain
 
 
+def _distinct_columns(idx, cols):
+    """The distinct set's expressions under the names the dedup aggregate
+    groups them by (marker children are already expressions)."""
+    return [(f"__cd{idx}_{i}", c) for i, c in enumerate(cols)]
+
+
+def _count_of_deduped(dcols, groupless):
+    """The count over a dedup aggregate's groups: those whose EVERY
+    distinct column is non-null (Spark count(distinct) semantics; a NULL
+    is a group of its own down there).  A groupless count over no groups
+    is 0, not the NULL a sum over nothing is."""
+    from . import functions as F
+    cond = None
+    for n_, _ in dcols:
+        c_ = F.col(n_).is_not_null()
+        cond = c_ if cond is None else (cond & c_)
+    cnt = F.sum(F.when(cond, F.lit(1)).otherwise(F.lit(0)))
+    return F.coalesce(cnt, F.lit(0)) if groupless else cnt
+
+
 def _plan_count_distinct(df, group_exprs, cds, plain, order):
-    """count(DISTINCT ...) lowering: one dedup aggregation + count per
-    distinct set, joined back to the plain aggregates on the group keys
-    (Spark's RewriteDistinctAggregates, single-join form).  Groupless
-    aggregates are each ONE row whatever the input holds (a count over no
-    rows is 0, a sum NULL) and join via a constant key put on after they
-    are computed."""
+    """count(DISTINCT ...) lowering.  ONE distinct set beside plain
+    aggregates that re-aggregate exactly runs the child once
+    (:func:`_plan_distinct_one_pass`); anything else keeps the join form
+    (:func:`_plan_count_distinct_join`).  The choice reads the aggregate
+    list alone."""
+    if len(cds) == 1:
+        node = _plan_distinct_one_pass(df._plan, group_exprs, cds[0], plain,
+                                       order)
+        if node is not None:
+            return DataFrame(node, df.session)
+    return _plan_count_distinct_join(df, group_exprs, cds, plain, order)
+
+
+def _plan_distinct_one_pass(child, group_exprs, cd, plain, order):
+    """Two stacked aggregates over ONE copy of the child (Spark's
+    AggUtils.planAggregateWithOneDistinct): level 1 groups by the keys +
+    the distinct columns and carries a partial for every plain aggregate
+    leaf; level 2 groups by the keys, counts level 1's groups whose
+    distinct columns are all non-null and merges the partials (a sum of
+    sums, a sum of counts, a min of mins, a max of maxes, sum over count
+    for an average).  A row whose distinct column is NULL is a level-1
+    group of its own: not counted, its partials merged like any other.
+    Float sums become sums of per-group sums, so their additions come in
+    another order than the join form's.
+
+    Returns None where a plain leaf does not re-aggregate by expression
+    (first / last, the central moments, covariance, percentiles,
+    collect_*, and the sum of a decimal, whose merge the device cannot
+    keep in level 1's type): the caller falls back to the join form."""
+    import copy
+
+    from .. import aggfns as A
+    from .. import types as T
+    from ..exprs import AggregateExpression, bind
+    from ..plan.planner import strip_alias
+
+    schema = child.schema()
+    name, cols = cd
+    keys = [n for n, _ in group_exprs]
+    partials: List[tuple] = []
+    by_fp: dict = {}  # identical leaves are computed once
+
+    def partial(agg):
+        fp = agg.fingerprint()
+        pname = by_fp.get(fp)
+        if pname is None:
+            pname = by_fp[fp] = f"__p{len(partials)}"
+            partials.append((pname, agg))
+        return E.UnresolvedColumn(pname)
+
+    def merge(e):
+        """``e`` with every aggregate leaf replaced by the merge of its
+        partial(s); None where a leaf has no exact merge."""
+        e = strip_alias(e)
+        if isinstance(e, AggregateExpression):
+            kind = type(e)
+            if kind is A.Sum:
+                # Sum's type is ten digits wider than a decimal input's:
+                # the sum of sums is a decimal past 18 digits, which is
+                # finalized on the host where no device cast back to
+                # level 1's type can read it.  Integers and floats keep
+                # their type
+                if bind(e, schema).dtype.is_decimal:
+                    return None
+                return A.Sum(partial(e))
+            if kind in (A.Count, A.CountStar):
+                # a count is never NULL; a sum over no level-1 groups is
+                return E.Coalesce(A.Sum(partial(e)), E.Literal(0, T.INT64))
+            if kind in (A.Min, A.Max):
+                return kind(partial(e))
+            if kind is A.Average:
+                # Average's own accumulator: a float64 sum and a count
+                x = e.children[0]
+                return E.Divide(
+                    A.Sum(partial(A.Sum(E.Cast(x, T.FLOAT64)))),
+                    A.Sum(partial(A.Count(x))))
+            return None
+        if not e.children:
+            return e
+        kids = [merge(c) for c in e.children]
+        if any(k is None for k in kids):
+            return None
+        node = copy.copy(e)
+        node.children = tuple(kids)
+        return node
+
+    finals = {}
+    for n_, e_ in plain:
+        finals[n_] = merge(e_)
+        if finals[n_] is None:
+            return None
+    dcols = _distinct_columns(0, cols)
+    finals[name] = _count_of_deduped(dcols, groupless=not keys).expr
+    level1 = L.Aggregate(child, group_exprs + dcols, partials)
+    # the aggregates AS WRITTEN, after the keys
+    node = _decompose_agg_exprs(
+        level1, [(k, E.UnresolvedColumn(k)) for k in keys],
+        [(n_, finals[n_]) for n_ in order])
+    level2 = node if isinstance(node, L.Aggregate) else node.children[0]
+    level2.distinct_one_pass = True
+    return node
+
+
+def _plan_count_distinct_join(df, group_exprs, cds, plain, order):
+    """The join form (Spark's RewriteDistinctAggregates, single-join
+    shape): the plain aggregates over the child, one dedup aggregation +
+    count per distinct set over the child AGAIN, joined back on the group
+    keys.  Reached with several distinct sets (each needs its own dedup;
+    one pass over them takes an Expand, which no plan here has yet) and
+    with a plain aggregate that cannot be merged from per-group partials
+    by an expression (first / last, the central moments, covariance,
+    percentiles, collect_*; the sum of a decimal).  Groupless aggregates
+    are each ONE row whatever the input holds (a count over no rows is 0,
+    a sum NULL) and join via a constant key put on after they are
+    computed."""
     from . import functions as F
 
     sess = df.session
@@ -502,20 +631,10 @@ def _plan_count_distinct(df, group_exprs, cds, plain, order):
         node = _decompose_agg_exprs(df._plan, group_exprs, plain)
         parts.append(one_row(DataFrame(node, sess)))
     for idx, (name, cols) in enumerate(cds):
-        # marker children are already expressions
-        dcols = [(f"__cd{idx}_{i}", c) for i, c in enumerate(cols)]
-        dedup_groups = group_exprs + [(n_, e_) for n_, e_ in dcols]
+        dcols = _distinct_columns(idx, cols)
         dedup = DataFrame(
-            _decompose_agg_exprs(df._plan, dedup_groups, []), sess)
-        # count rows whose EVERY distinct column is non-null (Spark
-        # count(distinct) semantics)
-        cond = None
-        for n_, _ in dcols:
-            c_ = F.col(n_).is_not_null()
-            cond = c_ if cond is None else (cond & c_)
-        cnt = F.sum(F.when(cond, F.lit(1)).otherwise(F.lit(0)))
-        if groupless:
-            cnt = F.coalesce(cnt, F.lit(0))
+            _decompose_agg_exprs(df._plan, group_exprs + dcols, []), sess)
+        cnt = _count_of_deduped(dcols, groupless)
         parts.append(one_row(dedup.group_by(*keys).agg(cnt.alias(name))))
     if groupless:
         keys = ["__cd_k"]

@@ -117,6 +117,12 @@ class QueryStats:
         # the parts a merge took where they were held (_HeldPartials)
         self.agg_merges = 0
         self.agg_merge_parts = 0
+        # count(DISTINCT) beside plain aggregates lowered to two stacked
+        # aggregates over ONE copy of the child (sql/dataframe.py
+        # _plan_distinct_one_pass): the level-2 aggregates of that form
+        # in plans that were converted (plan/overrides.py).  0 where the
+        # join form ran the child once a distinct set and once more
+        self.distinct_one_pass_aggs = 0
         # the reporting operators (plan/window_exec.py, plan/exec_nodes.py
         # ExpandExec): seconds inside ``window:exec`` spans (concat,
         # compact, the program, the gather) and the live rows that entered

@@ -212,6 +212,10 @@ METRICS = (
      "Partial results that entered those merges: over "
      "query_agg_merges_total, 2.0 is one merge a part, 9.0 a nine-set "
      "rollup merged once."),
+    ("query_distinct_one_pass_aggs_total", "counter", "",
+     "count(DISTINCT) aggregates planned as two stacked aggregates over "
+     "one copy of their child (none where the join form ran the child "
+     "once more a distinct set)."),
     # the reporting operators (window, expand) and CPU placement
     ("query_window_exec_seconds_total", "counter", "",
      "Seconds inside window:exec spans: a window's input concatenated "
@@ -433,6 +437,7 @@ _QS_FOLD = (
      "query_agg_dense_compacted_batches_total"),
     ("agg_merges", "query_agg_merges_total"),
     ("agg_merge_parts", "query_agg_merge_parts_total"),
+    ("distinct_one_pass_aggs", "query_distinct_one_pass_aggs_total"),
     ("query_wall_s", "query_wall_seconds_total"),
     ("acct_plan_s", "query_acct_plan_seconds_total"),
     ("acct_admit_s", "query_acct_admit_seconds_total"),

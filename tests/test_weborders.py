@@ -376,21 +376,62 @@ def test_q94_is_the_specifications_not_the_rewrite(session, bench_modules):
 
 def test_q94_runs_a_conditioned_semi_and_an_anti_join(fresh_session,
                                                      bench_modules):
-    """Two a selection; the plan as it stands runs the selection twice
-    (``count_distinct`` beside sums: two aggregates over two copies of the
-    child), so four a Q94."""
+    """One of each a selection, and the selection runs ONCE: the
+    ``count_distinct`` beside the sums is two stacked aggregates over one
+    copy of the child (``sql/dataframe._plan_distinct_one_pass``), not two
+    aggregates over two copies joined back, so two a Q94 and not four."""
     from spark_rapids_tpu.utils.metrics import QueryStats
     q = bench_modules[2]["q94"]
     tables = _order([(1, 10.0, 1.0), (2, 20.0, 2.0), (None, 40.0, 4.0)])
     p = {"year": 2000, "month": 3, "state": "TN"}
     dfs = {t: fresh_session.create_dataframe(v) for t, v in tables.items()}
     tree = fresh_session._plan_physical(q.plan(dfs, p)._plan).tree_string()
-    assert tree.count("[semi]") == 2 and tree.count("[anti]") == 2
+    assert tree.count("[semi]") == 1 and tree.count("[anti]") == 1
     with QueryStats.scoped() as qs:
         q.run(dfs, p)
-    assert qs.join_semi_anti == 4 and qs.cpu_fallback_nodes == 0
+    assert qs.join_semi_anti == 2 and qs.cpu_fallback_nodes == 0
+    assert qs.distinct_one_pass_aggs == 1
     # the conditioned semi join expands its candidates: three lines meet
-    # the order's three lines, in each copy
-    assert qs.join_pairs >= 2 * 9
+    # the order's three lines
+    assert qs.join_pairs >= 9
     names = {e[1] for e in fresh_session.last_trace().events}
     assert "join:pair" in names
+
+
+def test_q95_reads_its_selection_once(fresh_session, bench_modules,
+                                      monkeypatch):
+    """Q95's tree holds half the scans the join form's did: the selection
+    and both IN subqueries stand once.  ``ws_wh`` is still there twice,
+    alone under one IN and joined to ``web_returns`` under the other (a
+    subtree shared inside one execution is ROADMAP S12's, not this)."""
+    from spark_rapids_tpu.sql import dataframe as D
+    from spark_rapids_tpu.utils.metrics import QueryStats
+    q = bench_modules[2]["q95"]
+    tables = _order([(1, 10.0, 1.0), (2, 20.0, 2.0), (None, 40.0, 4.0)])
+    tables["web_returns"] = pa.table({
+        "wr_order_number": pa.array([42], type=pa.int64())})
+    p = {"year": 2000, "month": 3, "state": "TN"}
+    dfs = {t: fresh_session.create_dataframe(v) for t, v in tables.items()}
+    pds = {t: v.to_pandas() for t, v in tables.items()}
+
+    def trees():
+        plan = q.plan(dfs, p)
+        return (plan.explain_string(),
+                fresh_session._plan_physical(plan._plan).tree_string())
+    logical, physical = trees()
+    with QueryStats.scoped() as qs:
+        got = q.run(dfs, p)
+    # IN takes the order whole, its NULL-warehouse line too
+    assert got == q.reference(pds, p) == [(1, 70.0, 7.0)]
+    assert qs.distinct_one_pass_aggs == 1 and qs.cpu_fallback_nodes == 0
+    # the outer selection's four tables, ws_wh's two web_sales under one
+    # IN, web_returns with ws_wh's two more under the other
+    assert logical.count("Scan memory") == 4 + 2 + 3
+    assert logical.count("Project [ws_order_number, wh1]") == 2
+    assert logical.count("Join semi") == 2
+    # (a world this small answers its INs while it plans: the physical
+    # tree keeps the selection's four scans alone)
+    monkeypatch.setattr(D, "_plan_distinct_one_pass", lambda *a, **k: None)
+    logical2, physical2 = trees()
+    assert logical2.count("Scan memory") == 2 * logical.count("Scan memory")
+    assert physical2.count("TpuScan") == 2 * physical.count("TpuScan") > 0
