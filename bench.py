@@ -312,12 +312,8 @@ def _run_one(name: str, sf: float, iters: int) -> dict:
         "region_fetches_cold": cold_stats["region_fetches"],
         "fetch_mb_warm": round(warm_stats["fetch_bytes"] / 1e6, 3),
         # pipeline profile (round 6): time the pull loop blocked on a
-        # staged batch vs the staging work overlapped behind dispatch,
-        # plus the attributable D2H stall — overlap_s > 0 means the chip
-        # computed while the host decoded/uploaded
+        # staged batch, plus the attributable D2H stall
         "h2d_wait_s": warm_stats["h2d_wait_s"],
-        "overlap_s": round(max(0.0, warm_stats["pipeline_stage_s"]
-                               - warm_stats["h2d_wait_s"]), 4),
         "fetch_wait_s": warm_stats["fetch_wait_s"],
         # cross-query cache profile: hits per warm iteration and the MB
         # served from HBM instead of decode+upload (0s when

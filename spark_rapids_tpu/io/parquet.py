@@ -577,9 +577,7 @@ class ParquetSource:
                     continue
             return False
 
-        import contextvars
-
-        cctx = contextvars.copy_context()
+        from ..utils import tracing
 
         def producer():
             try:
@@ -592,13 +590,10 @@ class ParquetSource:
 
         # the producer runs in a COPY of the caller's context: its spans
         # and stats land in the calling query's trace/scope
-        th = threading.Thread(target=lambda: cctx.run(producer),
-                              daemon=True,
-                              name="srt-parquet-prefetch")
-        th.start()
+        prod = tracing.start_producer(producer, "srt-parquet-prefetch")
         try:
             while True:
-                item = next_prefetched(q)
+                item = next_prefetched(q, prod)
                 if item is _END:
                     break
                 if isinstance(item, BaseException):
@@ -625,10 +620,11 @@ def decoded(tables: Iterator) -> Iterator:
         yield t
 
 
-def next_prefetched(q: "queue.Queue"):
-    """The scan's wait for its prefetch thread, as a ``scan:wait`` span."""
+def next_prefetched(q: "queue.Queue", producer):
+    """The scan's wait for its prefetch thread (``producer``, from
+    ``tracing.start_producer``), as a ``scan:wait`` span."""
     from ..utils import tracing
-    with tracing.span(None, "scan:wait", "io"):
+    with tracing.span(None, "scan:wait", "io", on=producer):
         return q.get()
 
 

@@ -140,9 +140,7 @@ class FileSource:
         stop = threading.Event()
         _END = object()
 
-        import contextvars
-
-        cctx = contextvars.copy_context()
+        from ..utils import tracing
 
         def producer():
             try:
@@ -160,12 +158,10 @@ class FileSource:
                 q.put(e)
 
         # copied context: decode spans join the calling query's trace
-        th = threading.Thread(target=lambda: cctx.run(producer),
-                              daemon=True)
-        th.start()
+        prod = tracing.start_producer(producer, "srt-source-prefetch")
         try:
             while True:
-                item = next_prefetched(q)
+                item = next_prefetched(q, prod)
                 if item is _END:
                     return
                 if isinstance(item, BaseException):

@@ -17,10 +17,11 @@ compute idea, PAPERS.md, realized inside one process):
   * ``depth == 0`` reproduces today's serial pull loop byte-for-byte
     (the debugging escape hatch; ``spark.rapids.tpu.sql.pipeline.depth``).
 
-Wait/overlap accounting lands in :class:`..utils.metrics.QueryStats`
-(``h2d_wait_s`` = consumer blocked on a staged batch, ``pipeline_stage_s``
-= worker busy time); ``bench.py`` derives the per-query ``overlap_s``
-column from the two.
+The consumer's time blocked on a staged batch lands in
+:class:`..utils.metrics.QueryStats` ``h2d_wait_s``; the worker is the
+hand-off's producer (``tracing.start_producer``), so on the driving
+thread that wait is resolved into what the worker was doing meanwhile
+(``acct_h2d_<term>_s``).
 """
 
 from __future__ import annotations
@@ -137,9 +138,10 @@ def pipeline_map(src: Iterable[T], fn: Callable[[T], U],
 
     ``label`` names the consuming operator (its ``op_id``) so the stage/
     wait intervals land in the query trace as that operator's pipeline
-    phases.  The worker runs in a COPY of the caller's context: it writes
-    into the caller's query-scoped QueryStats and its spans join the
-    caller's active trace.
+    phases.  The worker runs in a COPY of the caller's context, as the
+    producer of the hand-off (``tracing.start_producer``): it writes into
+    the caller's query-scoped QueryStats, its spans join the caller's
+    active trace, and the consumer's wait is resolved through it.
     """
     from ..service import cancel
     if depth <= 0:
@@ -148,15 +150,12 @@ def pipeline_map(src: Iterable[T], fn: Callable[[T], U],
             yield fn(item)
         return
 
-    import contextvars
-
     from ..utils import tracing
     from ..utils.metrics import QueryStats
 
     slots = _Slots(depth)
     q: "queue.Queue" = queue.Queue()
     it = iter(src)
-    cctx = contextvars.copy_context()
     ctl = cancel.current()
     # cancellation wakes BOTH sides event-driven: the worker blocked on
     # a slot (slots re-checks the flag) and the consumer blocked on the
@@ -172,15 +171,13 @@ def pipeline_map(src: Iterable[T], fn: Callable[[T], U],
                 # items are ever live (queue + the one being produced)
                 if not slots.acquire(ctl):  # srtlint: ignore[release-paths] (cross-thread gate: the consumer loop releases per item and its finally stop()s the gate, freeing any held slot)
                     return  # stopped or cancelled
-                sp = tracing.span(label, "pipeline:stage", "pipeline")
                 try:
-                    with sp:
+                    with tracing.span(label, "pipeline:stage", "pipeline"):
                         item = next(it)
                         out = fn(item)
                 except StopIteration:
                     q.put(_END)
                     return
-                QueryStats.get().pipeline_stage_s += sp.dur
                 q.put(out)
         except BaseException as e:  # surfaced on the consumer side
             q.put(e)
@@ -192,9 +189,7 @@ def pipeline_map(src: Iterable[T], fn: Callable[[T], U],
                 except BaseException:  # fault-ok (teardown of an already-failed upstream)
                     pass
 
-    th = threading.Thread(target=lambda: cctx.run(worker), daemon=True,
-                          name="srt-pipeline-stage")
-    th.start()
+    prod = tracing.start_producer(worker, "srt-pipeline-stage")
     try:
         pending_release = False
         while True:
@@ -203,7 +198,8 @@ def pipeline_map(src: Iterable[T], fn: Callable[[T], U],
                 # comes back for more: staged batches + the one in the
                 # consumer's hands never exceed `depth` (strict HBM bound)
                 slots.release()
-            with tracing.span(label, "pipeline:wait", "pipeline") as sp:
+            with tracing.span(label, "pipeline:wait", "pipeline",
+                              on=prod) as sp:
                 item = q.get()
             QueryStats.get().h2d_wait_s += sp.dur
             if item is _END:

@@ -154,8 +154,8 @@ class QueryStats:
         # the query's host-time account (utils/tracing.account): nine
         # disjoint terms of the DRIVING thread's time, by span self
         # time, that sum to ``query_wall_s``.  Unlike fetch_wait_s /
-        # h2d_wait_s / pipeline_stage_s above and below, which sum the
-        # waits of every thread, these are shares of one wall
+        # h2d_wait_s above and below, which sum the waits of every
+        # thread, these are shares of one wall
         self.acct_plan_s = 0.0
         self.acct_admit_s = 0.0
         self.acct_compile_s = 0.0
@@ -166,14 +166,23 @@ class QueryStats:
         self.acct_host_exec_s = 0.0
         self.acct_unattributed_s = 0.0
         self.query_wall_s = 0.0
+        # ``acct_h2d_wait_s`` resolved (tracing.RESOLVED_TERMS): what the
+        # producer thread the driving thread waited on was doing
+        # meanwhile, through the producers upstream of it; the seven
+        # sum to acct_h2d_wait_s
+        self.acct_h2d_decode_s = 0.0
+        self.acct_h2d_convert_s = 0.0
+        self.acct_h2d_upload_s = 0.0
+        self.acct_h2d_dispatch_s = 0.0
+        self.acct_h2d_fetch_wait_s = 0.0
+        self.acct_h2d_host_exec_s = 0.0
+        self.acct_h2d_handoff_s = 0.0
         # bytes entering shuffle exchanges (device batch sizes at the
         # staging barrier) — BASELINE.json's shuffle-GB/s metric input
         self.shuffle_bytes = 0
         # execution-pipeline accounting (runtime/pipeline.py): time the
-        # consumer blocked waiting on a staged batch vs time the worker
-        # spent staging — bench derives overlap_s = stage - wait
+        # consumer blocked waiting on a staged batch, every thread's
         self.h2d_wait_s = 0.0
-        self.pipeline_stage_s = 0.0
         # wall-clock this query waited in the service admission queue
         # before starting (service/scheduler.py writes it; 0 for
         # synchronous queries) — the bench concurrency mode derives

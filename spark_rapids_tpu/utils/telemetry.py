@@ -268,6 +268,27 @@ METRICS = (
      "time)."),
     ("query_acct_unattributed_seconds_total", "counter", "",
      "Account: wall under no span at all."),
+    # the driving thread's h2d wait resolved through the producer threads
+    # it waited on (tracing.RESOLVED_TERMS); the seven sum to the wait
+    ("query_acct_h2d_decode_seconds_total", "counter", "",
+     "Account, h2d wait resolved: a producer in scan:decode."),
+    ("query_acct_h2d_convert_seconds_total", "counter", "",
+     "Account, h2d wait resolved: a producer in op:scanTime's self time "
+     "(Arrow to numpy, padding to the bucket)."),
+    ("query_acct_h2d_upload_seconds_total", "counter", "",
+     "Account, h2d wait resolved: a producer in scan:upload."),
+    ("query_acct_h2d_dispatch_seconds_total", "counter", "",
+     "Account, h2d wait resolved: a producer in its other dispatch "
+     "spans (program:*, eager:gather, fetch:start_copies)."),
+    ("query_acct_h2d_fetch_wait_seconds_total", "counter", "",
+     "Account, h2d wait resolved: a producer blocked on the device "
+     "(fetch:blocking, fetch:async)."),
+    ("query_acct_h2d_host_exec_seconds_total", "counter", "",
+     "Account, h2d wait resolved: a producer's other spanned time (op:* "
+     "self time, join:pair, window:exec, pipeline:stage, compiles)."),
+    ("query_acct_h2d_handoff_seconds_total", "counter", "",
+     "Account, h2d wait resolved: what no producer span covered (queue "
+     "hand-off, wake-up, the GIL, a producer under no span)."),
     ("query_shuffle_bytes_total", "counter", "",
      "Bytes entering shuffle exchanges."),
     ("query_h2d_wait_seconds_total", "counter", "",
@@ -448,6 +469,13 @@ _QS_FOLD = (
     ("acct_result_s", "query_acct_result_seconds_total"),
     ("acct_host_exec_s", "query_acct_host_exec_seconds_total"),
     ("acct_unattributed_s", "query_acct_unattributed_seconds_total"),
+    ("acct_h2d_decode_s", "query_acct_h2d_decode_seconds_total"),
+    ("acct_h2d_convert_s", "query_acct_h2d_convert_seconds_total"),
+    ("acct_h2d_upload_s", "query_acct_h2d_upload_seconds_total"),
+    ("acct_h2d_dispatch_s", "query_acct_h2d_dispatch_seconds_total"),
+    ("acct_h2d_fetch_wait_s", "query_acct_h2d_fetch_wait_seconds_total"),
+    ("acct_h2d_host_exec_s", "query_acct_h2d_host_exec_seconds_total"),
+    ("acct_h2d_handoff_s", "query_acct_h2d_handoff_seconds_total"),
     ("shuffle_bytes", "query_shuffle_bytes_total"),
     ("h2d_wait_s", "query_h2d_wait_seconds_total"),
     ("fused_regions", "query_fused_regions_total"),

@@ -30,7 +30,10 @@ any profiler session is live the program's spans sit in the same
 active :class:`QueryTrace`, if any; and on the query's DRIVING thread
 (the one that opened the scope) it charges its self time to one term of
 the query's host-time account (:func:`account`), which closes into
-``QueryStats.acct_*`` once per query.
+``QueryStats.acct_*`` once per query.  On a thread that stages for the
+query (:func:`start_producer`) it moves that thread's running account,
+which resolves a consumer's wait on the thread into what the thread
+was doing meanwhile (:data:`RESOLVED_TERMS`).
 
 This module is the ONE place exec-node timing may read the clock;
 srtlint's ``span-timing`` pass rejects raw ``time.perf_counter()`` in the
@@ -44,7 +47,7 @@ import contextvars
 import json
 import threading
 import time
-from typing import Dict, Iterator, List, Optional
+from typing import Callable, Dict, Iterator, List, Optional
 
 from jax.profiler import TraceAnnotation as _Annotation
 
@@ -52,7 +55,8 @@ from ..service import cancel as _cancel
 
 __all__ = ["QueryTrace", "active", "query_trace", "span", "record", "mark",
            "instrument_batches", "render_profiled", "SPANS",
-           "ACCOUNT_TERMS", "account", "charge", "suspended",
+           "ACCOUNT_TERMS", "RESOLVED_TERMS", "account", "charge",
+           "suspended", "start_producer",
            "merge_chrome", "write_merged", "trace_context",
            "shard_record", "shard_paths"]
 
@@ -130,8 +134,8 @@ SPANS = (
      "runtime/pipeline.py: the consumer blocked on a staged batch "
      "(QueryStats.h2d_wait_s sums it over threads)."),
     ("pipeline:stage", "host_exec",
-     "runtime/pipeline.py: the worker producing one staged batch "
-     "(QueryStats.pipeline_stage_s)."),
+     "runtime/pipeline.py: the worker producing one staged batch (its "
+     "self time is the worker's host_exec in a resolved wait)."),
     ("fetch:blocking", "fetch_wait",
      "utils/metrics.py fetch: jax.device_get behind the dispatch front "
      "(QueryStats.fetch_wait_s sums it over threads)."),
@@ -218,33 +222,162 @@ def _term_of(name: str) -> Optional[str]:
         return term
 
 
+# The driving thread's ``h2d_wait`` resolved: what the producer it waited
+# on (and, through that producer's own waits, the producers upstream of
+# it) was doing meanwhile.  ``handoff`` is producer time under no span
+# (queue hand-off, wake-up, the GIL, thread start) and whatever of the
+# wait the producers' totals did not cover.  QueryStats.acct_h2d_<t>_s.
+RESOLVED_TERMS = ("decode", "convert", "upload", "dispatch", "fetch_wait",
+                  "host_exec", "handoff")
+(_DECODE, _CONVERT, _UPLOAD, _DISPATCH, _FETCH_WAIT, _HOST_EXEC,
+ _HANDOFF) = range(len(RESOLVED_TERMS))
+_WAIT = len(RESOLVED_TERMS)  # a producer's own wait: resolved upstream
+
+_SLOT_OF = {"scan:decode": _DECODE, "op:scanTime": _CONVERT,
+            "scan:upload": _UPLOAD}
+_SLOT_OF_TERM = {"dispatch": _DISPATCH, "fetch_wait": _FETCH_WAIT}
+
+
+def _slot_of(name: str) -> Optional[int]:
+    """The resolved term a producer's span charges its self time to
+    (None: transparent).  Memoised like :func:`_term_of`."""
+    try:
+        return _SLOT_OF[name]
+    except KeyError:
+        term = _term_of(name)
+        slot = None if term is None else _SLOT_OF_TERM.get(term, _HOST_EXEC)
+        _SLOT_OF[name] = slot
+        return slot
+
+
 class _Account:
     """One query's host-time account, kept on the driving thread.
 
     Self time without a stack: ``child`` is the time that spans closed
     so far cover under the span now open.  A span saves it on entry and
     zeroes it; on exit its self time is its duration less ``child``, and
-    ``child`` becomes the saved value plus its whole duration."""
+    ``child`` becomes the saved value plus its whole duration.  ``h2d``
+    holds the ``h2d_wait`` term resolved into :data:`RESOLVED_TERMS`."""
 
-    __slots__ = ("tid", "t0", "child", "excluded", "terms")
+    __slots__ = ("tid", "t0", "child", "excluded", "terms", "h2d")
 
     def __init__(self):
         self.tid = threading.get_ident()
         self.child = 0.0
         self.excluded = 0.0
         self.terms = dict.fromkeys(ACCOUNT_TERMS[:-1], 0.0)
+        self.h2d = [0.0] * len(RESOLVED_TERMS)
         self.t0 = _pc()
 
 
-_ACCT: "contextvars.ContextVar[Optional[_Account]]" = \
+class _Producer:
+    """The running account of a thread that stages for a query.
+
+    A state machine under the same self-time rule: at every span
+    boundary on the thread the segment since the last one (``last``) is
+    added to ``totals[slot]``, the slot of the innermost open span, or
+    ``_HANDOFF`` under none.  While the thread itself waits on its
+    upstream (``slot == _WAIT``) the segment is resolved through that
+    upstream's totals (``on``, read as ``snap`` at the wait's entry).
+    Only the thread writes; :meth:`read` may be called from any thread
+    without a lock, and a torn read misplaces at most one segment."""
+
+    __slots__ = ("tid", "name", "last", "slot", "totals", "on", "snap")
+
+    def __init__(self, name: str):
+        self.tid = None  # the thread's, once it runs
+        self.name = name
+        self.totals = [0.0] * len(RESOLVED_TERMS)
+        self.slot = _HANDOFF
+        self.on = self.snap = None
+        self.last = _pc()
+
+    def read(self, now: float) -> List[float]:
+        """The totals as they are at ``now``, the open segment included."""
+        tot = self.totals[:]
+        slot, last = self.slot, self.last
+        if slot == _WAIT:
+            for i, v in enumerate(_resolve(self.on, self.snap, now,
+                                           now - last)):
+                tot[i] += v
+        else:
+            tot[slot] += now - last
+        return tot
+
+    def open(self, slot: Optional[int], t0: float, on, snap) -> int:
+        prev = self.slot
+        self.totals[prev] += t0 - self.last
+        self.last = t0
+        if on is not None:
+            self.on, self.snap = on, snap
+            self.slot = _WAIT
+        elif slot is not None:
+            self.slot = slot
+        return prev
+
+    def close(self, t1: float, prev: int, on, snap) -> Optional[List[float]]:
+        own = t1 - self.last
+        parts = None
+        if on is None:
+            self.totals[self.slot] += own
+        else:
+            parts = _resolve(on, snap, t1, own)
+            for i, v in enumerate(parts):
+                self.totals[i] += v
+        self.last = t1
+        self.slot = prev
+        return parts
+
+
+def _resolve(on: _Producer, snap: List[float], now: float,
+             dur: float) -> List[float]:
+    """``dur`` seconds of a wait on ``on`` since its totals read ``snap``,
+    split by what ``on`` did meanwhile; the parts sum to ``dur``."""
+    if dur <= 0.0:
+        return [0.0] * len(RESOLVED_TERMS)
+    parts = [c - s if c > s else 0.0 for c, s in zip(on.read(now), snap)]
+    covered = sum(parts)
+    if covered > dur:  # a torn read, or the reads' clocks apart
+        k = dur / covered
+        parts = [p * k for p in parts]
+        covered = dur
+    parts[_HANDOFF] += dur - covered
+    return parts
+
+
+_ACCT: "contextvars.ContextVar[Optional[_Account | _Producer]]" = \
     contextvars.ContextVar("srt_query_account", default=None)
 
 
-def _my_account() -> Optional[_Account]:
+def _my_account():
+    """The account this thread keeps: the query's (driving thread), a
+    producer's (a staging thread), or None."""
     acct = _ACCT.get()
     if acct is not None and acct.tid == threading.get_ident():
         return acct
     return None
+
+
+def start_producer(target: Callable[[], None], name: str) \
+        -> Optional[_Producer]:
+    """Run ``target`` on a new daemon thread named ``name``, in a COPY of
+    the caller's context (its spans join the caller's trace, its counters
+    the caller's QueryStats), as the producer of a hand-off: the thread
+    keeps a running account, returned here, that a consumer's wait on
+    the hand-off reads (``span(..., on=producer)``).  None, and no
+    account, where no query account is open in the caller's context."""
+    cctx = contextvars.copy_context()
+    prod = _Producer(name) if _ACCT.get() is not None else None
+
+    def run():
+        if prod is not None:
+            prod.tid = threading.get_ident()
+            _ACCT.set(prod)
+        target()
+
+    threading.Thread(target=lambda: cctx.run(run), daemon=True,
+                     name=name).start()
+    return prod
 
 
 @contextlib.contextmanager
@@ -272,16 +405,25 @@ def account(stats):
             rest -= v
         stats.acct_unattributed_s += max(0.0, rest)
         stats.query_wall_s += wall
+        for term, v in zip(RESOLVED_TERMS, acct.h2d):
+            setattr(stats, f"acct_h2d_{term}_s",
+                    getattr(stats, f"acct_h2d_{term}_s") + v)
 
 
 def charge(term: str, dur: float) -> None:
     """Charge an interval that someone else measured on this thread (a
     backend compile, reported by jax.monitoring when it ends) to
-    ``term``, and take it out of the open span's self time."""
+    ``term``, and take it out of the open span's self time.  On a
+    producer's thread it is ``host_exec``."""
     acct = _my_account()
-    if acct is not None:
+    if acct is None:
+        return
+    if acct.__class__ is _Account:
         acct.terms[term] += dur
         acct.child += dur
+    else:
+        acct.totals[_HOST_EXEC] += dur
+        acct.last += dur
 
 
 @contextlib.contextmanager
@@ -290,7 +432,7 @@ def suspended():
     holds the thread (the ``yield`` of a generator-shaped entry point):
     that time is neither the query's wall nor any span's."""
     acct = _my_account()
-    if acct is None:
+    if acct is None or acct.__class__ is not _Account:
         yield
         return
     t0 = _pc()
@@ -304,18 +446,21 @@ def suspended():
 
 class _Span:
     """A live timed span: a profiler annotation, one QueryTrace event on
-    exit, and on the driving thread a charge to the query's account.
+    exit, and a charge to the account its thread keeps: the query's on
+    the driving thread, a producer's on a staging thread.  A wait on a
+    hand-off names its producer (``on``) and is resolved through it.
     ``dur`` holds its seconds once it has closed."""
 
     __slots__ = ("_op", "_name", "_cat", "_ann_name", "_args", "_t0",
-                 "_ann", "_acct", "_saved", "dur")
+                 "_ann", "_acct", "_saved", "_on", "_snap", "dur")
 
-    def __init__(self, op_id, name, cat, ann=None):
+    def __init__(self, op_id, name, cat, ann=None, on=None):
         self._op = op_id
         self._name = name
         self._cat = cat
         self._ann_name = ann or name
         self._args = None
+        self._on = on
 
     def set(self, **attrs):
         if self._args is None:
@@ -325,31 +470,52 @@ class _Span:
 
     def __enter__(self):
         acct = self._acct = _my_account()
-        self._t0 = _pc()
+        t0 = self._t0 = _pc()
         if acct is not None:
-            self._saved = acct.child
-            acct.child = 0.0
+            on = self._on
+            snap = self._snap = None if on is None else on.read(t0)
+            if acct.__class__ is _Account:
+                self._saved = acct.child
+                acct.child = 0.0
+            else:
+                self._saved = acct.open(_slot_of(self._ann_name), t0, on,
+                                        snap)
         self._ann = _Annotation(self._ann_name)
         self._ann.__enter__()
         return self
 
     def __exit__(self, et=None, ev=None, tb=None):
         self._ann.__exit__(et, ev, tb)
-        dur = self.dur = _pc() - self._t0
+        t1 = _pc()
+        dur = self.dur = t1 - self._t0
         acct = self._acct
+        parts = None
         if acct is not None:
-            term = _term_of(self._ann_name)
-            if term is None:
-                acct.child += self._saved
+            if acct.__class__ is _Account:
+                term = _term_of(self._ann_name)
+                if term is None:
+                    acct.child += self._saved
+                else:
+                    own = dur - acct.child
+                    acct.terms[term] += own
+                    acct.child = self._saved + dur
+                    if self._on is not None:
+                        parts = _resolve(self._on, self._snap, t1, own)
+                        h2d = acct.h2d
+                        for i, v in enumerate(parts):
+                            h2d[i] += v
             else:
-                acct.terms[term] += dur - acct.child
-                acct.child = self._saved + dur
+                parts = acct.close(t1, self._saved, self._on, self._snap)
         # a pull that only found its stream ended leaves no event
         if et is not StopIteration:
             tr = _ACTIVE.get()
             if tr is not None:
+                args = self._args
+                if parts is not None:
+                    args = dict(args or (), on=self._on.name,
+                                **dict(zip(RESOLVED_TERMS, parts)))
                 tr.add_event(self._op, self._name, self._cat, self._t0,
-                             dur, self._args)
+                             dur, args)
         return False
 
 
@@ -603,13 +769,15 @@ def query_trace(label: str, enabled: bool = True,
 
 
 def span(op_id: Optional[str], name: str, cat: str = "phase",
-         ann: Optional[str] = None) -> _Span:
+         ann: Optional[str] = None, on: Optional[_Producer] = None) -> _Span:
     """THE timed span: a context manager attributed to ``op_id`` (None
     for query-level work), live at every metrics level and with or
     without a QueryTrace.  ``name`` is the QueryTrace event's name and,
     unless ``ann`` gives another, the profiler annotation's: one of
-    :data:`SPANS`."""
-    return _Span(op_id, name, cat, ann)
+    :data:`SPANS`.  ``on`` is the producer a wait on a hand-off waits
+    for (:func:`start_producer`): the wait is resolved through it, and
+    its QueryTrace event carries the producer's name and the parts."""
+    return _Span(op_id, name, cat, ann, on)
 
 
 def record(op_id: Optional[str], name: str, cat: str, t0: float,

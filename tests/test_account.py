@@ -6,10 +6,13 @@ The account's contract: on the driving thread every second of a query
 belongs to exactly one of nine terms, written once per query into
 ``QueryStats.acct_*_s``, and the nine sum to ``query_wall_s``.  What a
 worker thread waits moves the all-thread sums (``fetch_wait_s``,
-``h2d_wait_s``) and never the account.
+``h2d_wait_s``) and never the account.  The driving thread's
+``h2d_wait`` is resolved through the producer threads it waited on into
+seven parts (``acct_h2d_<term>_s``) that sum to it.
 """
 
 import contextvars
+import queue
 import re
 import threading
 
@@ -29,6 +32,7 @@ from spark_rapids_tpu.utils.metrics import QueryStats
 
 DEPTH_KEY = "spark.rapids.tpu.sql.pipeline.depth"
 ACCT = [f"acct_{t}_s" for t in tracing.ACCOUNT_TERMS]
+RESOLVED = [f"acct_h2d_{t}_s" for t in tracing.RESOLVED_TERMS]
 
 
 @pytest.fixture(scope="module")
@@ -53,6 +57,16 @@ def _closed(st, rel=0.01):
     return terms
 
 
+def _resolved(st):
+    """The seven parts of the driving thread's h2d wait: each >= 0, and
+    together the wait, to float rounding."""
+    parts = {k: getattr(st, k) for k in RESOLVED}
+    assert all(v >= 0.0 for v in parts.values()), parts
+    assert sum(parts.values()) == pytest.approx(st.acct_h2d_wait_s,
+                                                rel=1e-9, abs=1e-12)
+    return parts
+
+
 @pytest.mark.parametrize("depth", [0, 2])
 @pytest.mark.parametrize("query", [("tpch", "q3"), ("tpch", "q13"),
                                    ("tpcds", "ds_q42")],
@@ -70,9 +84,12 @@ def test_nine_terms_sum_to_the_wall(session, dbs, query, depth):
     # a join query plans, runs programs and materialises rows
     assert terms["acct_plan_s"] > 0 and terms["acct_dispatch_s"] > 0
     assert terms["acct_result_s"] > 0 and terms["acct_host_exec_s"] > 0
+    parts = _resolved(st)
     if depth:
-        # the scans stage on workers: the driving thread waited for them
+        # the scans stage on workers: the driving thread waited for them,
+        # and for what they did under their spans
         assert st.h2d_wait_s > 0
+        assert sum(parts.values()) - parts["acct_h2d_handoff_s"] > 0
 
 
 def test_first_run_charges_its_compiles_to_the_compile_term(session):
@@ -144,13 +161,89 @@ def test_a_workers_pipeline_wait_moves_the_sum_and_not_the_account():
     with QueryStats.scoped() as st:
         with tracing.account(st):
             _on_worker(consume)
-    assert st.h2d_wait_s > 0 and st.pipeline_stage_s > 0
+    assert st.h2d_wait_s > 0
     assert st.acct_h2d_wait_s == 0.0
+    assert not any(_resolved(st).values())
     with QueryStats.scoped() as st2:
         with tracing.account(st2):
             consume()
     assert st2.acct_h2d_wait_s == pytest.approx(st2.h2d_wait_s, rel=1e-6)
     _closed(st2, rel=1e-6)
+    # the worker slept under pipeline:stage while the consumer waited
+    assert _resolved(st2)["acct_h2d_host_exec_s"] > 0
+
+
+def test_a_two_level_wait_lands_on_the_decode_it_waited_for(monkeypatch):
+    """consumer -> pipeline_map worker -> prefetch thread: the driving
+    thread's pipeline:wait is resolved through the worker, whose own
+    scan:wait is resolved through the prefetch thread's scan:decode."""
+    from spark_rapids_tpu.io.parquet import decoded, next_prefetched
+    from spark_rapids_tpu.runtime.pipeline import pipeline_map
+
+    # tracing's clock, moved by hand: time passes only where the test
+    # says, so the resolution is exact and no wait depends on the wall
+    clock = [100.0]
+    monkeypatch.setattr(tracing, "_pc", lambda: clock[0])
+    # a wait reads its producer as it opens: the decode moves the clock
+    # once the consumer waits on the worker and the worker on it
+    in_wait = {"srt-pipeline-stage": threading.Event(),
+               "srt-parquet-prefetch": threading.Event()}
+    read = tracing._Producer.read
+
+    def spy(self, now):
+        in_wait[self.name].set()
+        return read(self, now)
+
+    monkeypatch.setattr(tracing._Producer, "read", spy)
+    end = object()
+
+    table = pa.table({"a": [1, 2]})
+
+    def tables():  # under scan:decode on the prefetch thread
+        assert all(e.wait(10) for e in in_wait.values())
+        clock[0] += 1.5
+        yield table
+
+    def src():  # on the pipeline worker
+        q = queue.Queue()
+
+        def prefetch():
+            try:
+                for t in decoded(tables()):
+                    q.put(t)
+            finally:
+                q.put(end)
+
+        prod = tracing.start_producer(prefetch, "srt-parquet-prefetch")
+        while (item := next_prefetched(q, prod)) is not end:
+            yield item
+
+    with QueryStats.scoped() as st:
+        with tracing.query_trace("two-level") as tr:
+            with tracing.account(st):
+                assert list(pipeline_map(src(), lambda t: t, 1)) == [table]
+    assert st.acct_h2d_wait_s == 1.5 and st.query_wall_s == 1.5
+    assert _resolved(st) == dict.fromkeys(RESOLVED, 0.0) | {
+        "acct_h2d_decode_s": 1.5}
+    # the trace follows the hand-off: each wait names the thread it
+    # waited on, with the parts
+    waits = {e[1]: e[6] for e in tr.events if e[4] > 0
+             and e[1] in ("pipeline:wait", "scan:wait")}
+    assert waits["pipeline:wait"]["on"] == "srt-pipeline-stage"
+    assert waits["scan:wait"]["on"] == "srt-parquet-prefetch"
+    for args in waits.values():
+        assert args["decode"] == 1.5 and args["handoff"] == 0.0
+
+
+def test_a_wait_with_no_driving_account_open_charges_nothing():
+    from spark_rapids_tpu.runtime.pipeline import pipeline_map
+    assert tracing.start_producer(lambda: None, "srt-idle") is None
+    with QueryStats.scoped() as st:
+        with tracing.query_trace("no-account") as tr:
+            assert list(pipeline_map(range(3), lambda x: x, 2)) == [0, 1, 2]
+    assert st.acct_h2d_wait_s == 0.0
+    assert not any(_resolved(st).values())
+    assert not any("on" in (e[6] or {}) for e in tr.events)
 
 
 def test_self_time_and_charged_intervals():
@@ -360,3 +453,7 @@ def test_the_docs_list_every_span_and_every_term():
         assert f"| `{name}" in table and f"`{term}`" in table, name
     for term in tracing.ACCOUNT_TERMS:
         assert f"| `{term}` |" in doc, term
+    resolved = doc[doc.index("### The wait resolved"):]
+    for field in RESOLVED:
+        metric = field.replace("acct_h2d_", "h2d_on_")[:-2] + "_pct"
+        assert f"| `{field}` |" in resolved and f"`{metric}`" in resolved

@@ -248,8 +248,16 @@ def test_trace_spans_cross_pipeline_threads(sess):
     names = [e for e in tr.to_chrome()["traceEvents"]
              if e["ph"] == "M" and e["name"] == "thread_name"]
     assert any("pipeline" in e["args"]["name"] for e in names)
-    # the query-scoped stats saw the pipeline accounting
-    assert tr.attrs.get("pipeline_stage_s", 0) > 0
+    # the driving thread's waits were resolved through the workers: the
+    # events name the thread waited on, the stats split the wait
+    from spark_rapids_tpu.utils.tracing import RESOLVED_TERMS
+    assert any(e[1] == "pipeline:wait" and (e[6] or {}).get("on")
+               == "srt-pipeline-stage" for e in tr.events)
+    parts = [tr.attrs[f"acct_h2d_{t}_s"] for t in RESOLVED_TERMS]
+    # the snapshot rounds each field to a tenth of a millisecond
+    assert sum(parts) == pytest.approx(tr.attrs["acct_h2d_wait_s"],
+                                       abs=1e-3)
+    assert sum(parts) > 0
 
 
 def test_trace_event_cap_drops_not_grows(sess):
