@@ -35,7 +35,8 @@ from . import types as T
 from .types import DataType
 
 __all__ = [
-    "Schema", "Field", "DeviceColumn", "HostStringColumn", "ColumnBatch",
+    "Schema", "Field", "DeviceColumn", "HostStringColumn",
+    "PageCodedStringColumn", "ColumnBatch",
     "bucket_capacity", "from_arrow", "to_arrow", "to_arrow_async",
     "from_numpy",
 ]
@@ -246,6 +247,67 @@ class DictStringColumn(HostStringColumn):
         self._decoded = value
 
 
+class PageCodedStringColumn(HostStringColumn):
+    """A string column as the parquet file stored it: for each run of
+    rows that one dictionary page coded, the page's int32 indices and its
+    dictionary (``chunks``, arrow ``DictionaryArray``s in row order).
+
+    Only the indices count toward the capacity: the column holds no
+    string until a consumer reads ``.array``, which decodes every chunk
+    once (remembered) and pads with nulls to the capacity, so it reads
+    exactly what the plain column would.  An aggregate's string keys skip
+    the decode: ``StringDictionary.encode_page_codes`` maps each chunk's
+    few dictionary values to the query's codes and gathers the indices
+    through that map, hashing no row.
+    """
+
+    def __init__(self, chunks, capacity: int):
+        self.chunks = list(chunks)
+        self.num_rows = sum(len(c) for c in self.chunks)
+        self._capacity = int(capacity)
+        self.dtype = T.STRING
+        self._decoded = None
+
+    @property
+    def capacity(self) -> int:
+        return self._capacity
+
+    @property
+    def nullable(self) -> bool:
+        # as the decoded array's null count: the padding is null
+        return self.num_rows < self._capacity or \
+            any(c.null_count for c in self.chunks)
+
+    @property
+    def array(self):
+        if self._decoded is None:
+            import pyarrow as pa
+            parts = [c.dictionary_decode() for c in self.chunks]
+            if self.num_rows < self._capacity:
+                parts.append(pa.nulls(self._capacity - self.num_rows,
+                                      type=pa.string()))
+            self._decoded = parts[0] if len(parts) == 1 \
+                else pa.concat_arrays(parts)
+        return self._decoded
+
+    @array.setter
+    def array(self, value):  # pragma: no cover - defensive
+        self._decoded = value
+
+
+def _page_chunks(col):
+    """The chunks of a ``dictionary<int32, string>`` column, as the
+    parquet reader's ``read_dictionary`` makes it, or None for any other
+    column (another dictionary is decoded like a plain column)."""
+    import pyarrow as pa
+    if col.type != pa.dictionary(pa.int32(), pa.string()):
+        return None
+    chunks = col.chunks if isinstance(col, pa.ChunkedArray) else [col]
+    if any(c.dictionary.null_count for c in chunks):
+        return None
+    return chunks
+
+
 def decode_dict_codes(codes, valid, dictionary):
     """HOST int32 codes (+validity) + arrow dictionary → plain
     StringArray; out-of-range codes are nulls."""
@@ -343,6 +405,9 @@ class ColumnBatch:
 
 def _arrow_to_logical(pa_type) -> DataType:
     import pyarrow as pa
+    if pa.types.is_dictionary(pa_type):
+        # a dictionary-coded column is its values' type
+        return _arrow_to_logical(pa_type.value_type)
     if pa.types.is_boolean(pa_type):
         return T.BOOLEAN
     if pa.types.is_int8(pa_type):
@@ -430,8 +495,16 @@ def from_arrow(table, min_capacity: int = 1024, device=None) -> ColumnBatch:
     cols: List[Column] = []
     from .utils.metrics import upload
     for name, col in zip(table.column_names, table.columns):
+        chunks = _page_chunks(col)
+        if chunks is not None:
+            # the file's page codes: no string is made or padded here
+            fields.append(Field(name, T.STRING, col.null_count > 0))
+            cols.append(PageCodedStringColumn(chunks, cap))
+            continue
         if isinstance(col, pa.ChunkedArray):
             col = col.combine_chunks() if col.num_chunks != 1 else col.chunk(0)
+        if pa.types.is_dictionary(col.type):
+            col = col.dictionary_decode()
         dt = _arrow_to_logical(col.type)
         fields.append(Field(name, dt, col.null_count > 0))
         if dt.is_string or dt.is_nested or \

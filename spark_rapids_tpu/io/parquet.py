@@ -169,6 +169,92 @@ def prune_row_groups(pq_file, predicates: Sequence[Predicate]) -> List[int]:
     return keep
 
 
+# A PLAIN BYTE_ARRAY value takes its 4-byte length and then its bytes: a
+# chunk under this many uncompressed bytes a value is dictionary-coded
+_PLAIN_VALUE_MIN_BYTES = 4
+
+
+def page_coded_columns(pq_file, columns: Optional[List[str]]) -> List[str]:
+    """The top-level string columns of ``columns`` (None: all) to read as
+    their dictionary pages' codes: every row group's chunk has a
+    dictionary page and fewer uncompressed bytes a value than any PLAIN
+    value takes.  A chunk whose writer fell back to PLAIN, or a
+    near-unique column's, takes more.  Decided from the footer alone."""
+    import pyarrow as pa
+    md = pq_file.metadata
+    index = {md.schema.column(i).path: i for i in range(md.num_columns)}
+    out = []
+    for f in pq_file.schema_arrow:
+        if columns is not None and f.name not in columns:
+            continue
+        if not pa.types.is_string(f.type):
+            continue
+        ci = index.get(f.name)
+        if ci is None:
+            continue
+        chunks = [md.row_group(rg).column(ci)
+                  for rg in range(md.num_row_groups)]
+        if all(c.has_dictionary_page and c.total_uncompressed_size
+               < _PLAIN_VALUE_MIN_BYTES * max(c.num_values, 1)
+               for c in chunks):
+            out.append(f.name)
+    return out
+
+
+def _aligned_tables(pf, pf_coded, coded: List[str],
+                    columns: Optional[List[str]], batch_rows: int,
+                    rgs: List[int]) -> Iterator:
+    """Tables of the batches the plain read makes, whose ``coded`` columns
+    are ``pf_coded``'s (opened with ``read_dictionary``) page codes.
+
+    pyarrow ends a dictionary-coded batch where a row group ends, so the
+    coded columns come from a reader of their own and their runs are cut
+    to the plain columns' batch lengths: a batch may hold the codes of two
+    pages, as a ChunkedArray of two chunks."""
+    import pyarrow as pa
+    names = list(columns) if columns is not None \
+        else pf.schema_arrow.names
+    plain = [c for c in names if c not in coded]
+    runs = pf_coded.iter_batches(batch_size=batch_rows, row_groups=rgs,
+                                 columns=[c for c in names if c in coded],
+                                 use_threads=True)
+    cur, pos = None, 0
+
+    def take(n: int) -> list:
+        nonlocal cur, pos
+        pieces = []
+        while n > 0:
+            if cur is None or pos == cur.num_rows:
+                cur, pos = next(runs, None), 0
+                if cur is None:
+                    break
+                continue
+            k = min(n, cur.num_rows - pos)
+            pieces.append(cur.slice(pos, k))
+            pos += k
+            n -= k
+        return pieces
+
+    def table(rb, pieces):
+        cols = {name: pa.chunked_array([p.column(name) for p in pieces])
+                for name in coded}
+        if rb is not None:
+            for name in plain:
+                cols[name] = rb.column(name)
+        return pa.Table.from_arrays([cols[n] for n in names], names=names)
+
+    if plain:
+        for rb in pf.iter_batches(batch_size=batch_rows, row_groups=rgs,
+                                  columns=plain, use_threads=True):
+            yield table(rb, take(rb.num_rows))
+        return
+    while True:
+        pieces = take(batch_rows)
+        if not pieces:
+            return
+        yield table(None, pieces)
+
+
 def _exact_filter_mask(table, predicates: Sequence[Predicate]):
     """Kleene-AND mask of the pushed conjuncts over a decoded host table.
 
@@ -195,8 +281,10 @@ def _exact_filter_mask(table, predicates: Sequence[Predicate]):
                 # result), which the filter drops either way
                 import pyarrow as pa
                 vals = [v for v in value if v is not None]
-                m = pc.is_in(col, value_set=pa.array(
-                    vals, type=col.type if hasattr(col, "type") else None))
+                ty = col.type if hasattr(col, "type") else None
+                if ty is not None and pa.types.is_dictionary(ty):
+                    ty = ty.value_type  # page codes: match their values
+                m = pc.is_in(col, value_set=pa.array(vals, type=ty))
             elif op == "isnotnull":
                 m = pc.is_valid(col)
             else:
@@ -481,11 +569,22 @@ class ParquetSource:
         acc = [] if (cache is not None and key is not None) else None
         arrow_part = {"int64": pa.int64(), "float64": pa.float64(),
                       "string": pa.string()}
-        if skips is None:
-            batches = ((rb, None) for rb in pf.iter_batches(
-                batch_size=self.batch_rows, row_groups=rgs,
-                columns=file_columns, use_threads=True))
+        # low-cardinality string columns stay as their pages' codes
+        coded = page_coded_columns(pf, file_columns)
+        pf_coded = pq.ParquetFile(path, metadata=pf.metadata,
+                                  read_dictionary=coded) if coded else None
+        if skips is None and coded and len(rgs) > 1:
+            batches = ((t, None) for t in _aligned_tables(
+                pf, pf_coded, coded, file_columns, self.batch_rows, rgs))
+        elif skips is None:
+            # one row group: the coded reader cuts the plain read's batches
+            batches = ((pa.Table.from_batches([rb]), None)
+                       for rb in (pf_coded or pf).iter_batches(
+                           batch_size=self.batch_rows, row_groups=rgs,
+                           columns=file_columns, use_threads=True))
         else:
+            # one row group at a time: coded batches end where it does
+            pf = pf_coded or pf
             # DV positions index the RAW file row order; pruning survives
             # because each kept group's start offset is in the metadata
             group_starts = np.cumsum(
@@ -498,11 +597,10 @@ class ParquetSource:
                     for rb in pf.iter_batches(
                             batch_size=self.batch_rows, row_groups=[g],
                             columns=file_columns, use_threads=True):
-                        yield rb, off
+                        yield pa.Table.from_batches([rb]), off
                         off += rb.num_rows
             batches = _dv_batches()
-        for rb, row_off in batches:
-            t = pa.Table.from_batches([rb])
+        for t, row_off in batches:
             if skips is not None:
                 nrows = t.num_rows
                 lo = int(np.searchsorted(skips, row_off))

@@ -69,26 +69,66 @@ class StringDictionary:
         # per-batch arrow dictionary encode gives local codes fast (C++),
         # then only the (small) local dictionary goes through the python map
         denc = arr.dictionary_encode()
-        local_vals = denc.dictionary.to_pylist()
+        remap = self._remap(denc.dictionary.to_pylist())
+        # null indices read 0: int32 straight out of arrow, never float64
+        codes = remap[denc.indices.fill_null(0).to_numpy(
+            zero_copy_only=False)]
+        valid = np.asarray(arr.is_valid()) if arr.null_count > 0 else None
         with self._lock:
-            remap = np.zeros(max(len(local_vals), 1), dtype=np.int32)
-            for i, v in enumerate(local_vals):
+            if len(self._memo) >= self._MEMO_MAX:
+                self._memo.clear()
+            self._memo[id(arr)] = (arr, codes, valid)
+        return codes, valid
+
+    def _remap(self, values) -> np.ndarray:
+        """remap[i] = the code of ``values[i]``, new values appended in
+        the order given (one lock for all of them)."""
+        with self._lock:
+            remap = np.zeros(max(len(values), 1), dtype=np.int32)
+            for i, v in enumerate(values):
                 code = self._code_of.get(v)
                 if code is None:
                     code = len(self._values)
                     self._code_of[v] = code
                     self._values.append(v)
                 remap[i] = code
-        local_codes = denc.indices.to_numpy(zero_copy_only=False)
+        return remap
+
+    def encode_page_codes(self, col) -> Tuple[np.ndarray,
+                                              Optional[np.ndarray]]:
+        """A ``PageCodedStringColumn`` → (int32 codes over its capacity,
+        validity-or-None), hashing no row: each chunk's page dictionary
+        goes through the mapping (the values its rows use, in the page's
+        order, which a writer fills in order of first occurrence), and its
+        indices are gathered through that small map.  Padding and null
+        rows read code 0.  The validity is None unless a live row is null:
+        the padding lies past ``num_rows``, which every program that reads
+        the codes masks."""
+        cap = col.capacity
+        codes = np.zeros(cap, dtype=np.int32)
         valid = None
-        if arr.null_count > 0:
-            valid = np.asarray(arr.is_valid())
-            local_codes = np.where(valid, local_codes, 0).astype(np.int64)
-        codes = remap[local_codes.astype(np.int64)].astype(np.int32)
-        with self._lock:
-            if len(self._memo) >= self._MEMO_MAX:
-                self._memo.clear()
-            self._memo[id(arr)] = (arr, codes, valid)
+        pos = 0
+        for chunk in col.chunks:
+            n = len(chunk)
+            idx = chunk.indices.fill_null(0).to_numpy(zero_copy_only=False)
+            live = None
+            if chunk.null_count:
+                live = np.asarray(chunk.indices.is_valid())
+                if valid is None:
+                    valid = np.zeros(cap, dtype=bool)
+                    valid[:col.num_rows] = True
+                valid[pos:pos + n] = live
+            used = np.bincount(idx if live is None else idx[live],
+                               minlength=len(chunk.dictionary)) > 0
+            which = np.flatnonzero(used)
+            remap = np.zeros(max(len(chunk.dictionary), 1), dtype=np.int32)
+            remap[which] = self._remap(
+                chunk.dictionary.take(which).to_pylist())[:len(which)]
+            out = codes[pos:pos + n]
+            np.take(remap, idx, out=out)
+            if live is not None:
+                out[~live] = 0
+            pos += n
         return codes, valid
 
     def to_arrow(self):

@@ -2003,7 +2003,7 @@ class AggregateExec(TpuExec):
         refs = self._string_key_refs()
         if not refs:
             return batch
-        from ..batch import DictStringColumn
+        from ..batch import DictStringColumn, PageCodedStringColumn
         from ..ops.strings import StringDictionary
         cols = list(batch.columns)
         changed = False
@@ -2038,7 +2038,13 @@ class AggregateExec(TpuExec):
                 if d is None:
                     d = StringDictionary()
                     self.string_dicts[gi] = d
-                codes, valid = d.encode(col.array)
+                if isinstance(col, PageCodedStringColumn):
+                    # the file's page codes: its dictionaries remapped,
+                    # no row hashed, no string made
+                    codes, valid = d.encode_page_codes(col)
+                    QueryStats.get().page_coded_keys += 1
+                else:
+                    codes, valid = d.encode(col.array)
                 jcodes, jvalid = upload((codes, valid), ctx.device)
                 col._enc_cache = (d, jcodes, jvalid)
             cols[ordn] = DeviceColumn(T.STRING, jcodes, jvalid)

@@ -123,6 +123,11 @@ class QueryStats:
         # in plans that were converted (plan/overrides.py).  0 where the
         # join form ran the child once a distinct set and once more
         self.distinct_one_pass_aggs = 0
+        # string group keys an aggregate coded from the parquet file's
+        # page codes (PageCodedStringColumn; plan/physical.py
+        # _encode_string_keys): one a key column a batch, each coded by
+        # remapping the page dictionaries, no row hashed
+        self.page_coded_keys = 0
         # the reporting operators (plan/window_exec.py, plan/exec_nodes.py
         # ExpandExec): seconds inside ``window:exec`` spans (concat,
         # compact, the program, the gather) and the live rows that entered

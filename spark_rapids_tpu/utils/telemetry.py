@@ -216,6 +216,10 @@ METRICS = (
      "count(DISTINCT) aggregates planned as two stacked aggregates over "
      "one copy of their child (none where the join form ran the child "
      "once more a distinct set)."),
+    ("query_page_coded_keys_total", "counter", "",
+     "String group keys an aggregate coded from the parquet pages' "
+     "dictionary codes, one a key column a batch: the page dictionaries "
+     "remapped, no row hashed."),
     # the reporting operators (window, expand) and CPU placement
     ("query_window_exec_seconds_total", "counter", "",
      "Seconds inside window:exec spans: a window's input concatenated "
@@ -459,6 +463,7 @@ _QS_FOLD = (
     ("agg_merges", "query_agg_merges_total"),
     ("agg_merge_parts", "query_agg_merge_parts_total"),
     ("distinct_one_pass_aggs", "query_distinct_one_pass_aggs_total"),
+    ("page_coded_keys", "query_page_coded_keys_total"),
     ("query_wall_s", "query_wall_seconds_total"),
     ("acct_plan_s", "query_acct_plan_seconds_total"),
     ("acct_admit_s", "query_acct_admit_seconds_total"),
