@@ -24,6 +24,7 @@ from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from . import types as T
 from .exprs import AggregateExpression, EvalContext, Expression, Value
@@ -44,14 +45,88 @@ def _valid_indicator(v: Optional[jax.Array], ctx: EvalContext) -> jax.Array:
     return v.astype(jnp.int64)
 
 
+def _decimal_sum_lanes(d, v, ctx, wide_in: bool) -> List[Value]:
+    """The int64 lanes of an exact decimal sum, the valid count last.  A
+    narrow input (scaled int64) splits into lo 32 bits and the signed rest;
+    a wide one (two limbs) into four carry-free 32-bit chunks (lo0, lo1,
+    hi0, hi1-signed).  Every lane sum stays under 2^63 for up to 2^31 rows,
+    so the host rebuilds the total exactly whatever cancels."""
+    if wide_in:
+        lo, hi = d[..., 0], d[..., 1]
+        if v is not None:
+            z = jnp.zeros_like(lo)
+            lo = jnp.where(v, lo, z)
+            hi = jnp.where(v, hi, z)
+        m32 = np.int64(0xFFFFFFFF)
+        l0 = lo & m32
+        l1 = jax.lax.shift_right_logical(lo, np.int64(32))
+        h0 = hi & m32
+        h1 = hi >> np.int64(32)  # arithmetic: keeps the sign
+        return [(l0, None), (l1, None), (h0, None), (h1, None),
+                (_valid_indicator(v, ctx), None)]
+    d = d.astype(jnp.int64)
+    if v is not None:
+        d = jnp.where(v, d, jnp.zeros_like(d))
+    hi = d >> np.int64(32)
+    lo = d - (hi << np.int64(32))  # in [0, 2^32)
+    return [(lo, None), (hi, None), (_valid_indicator(v, ctx), None)]
+
+
+def _decimal_sum_buffers(wide_in: bool):
+    return [(T.INT64, "sum")] * (5 if wide_in else 3)
+
+
+def _decimal_sums_host(buffers, n_rows: int, wide_in: bool):
+    """(positions of the groups that hold rows, their python-int totals,
+    their counts) among the first ``n_rows`` groups, from the lanes of
+    :func:`_decimal_sum_lanes` (host arrays or device ones).  A grid's
+    output holds its few groups among capacity slots: the empty slots
+    cost no python."""
+    lanes = [np.asarray(b[0][:n_rows]) for b in buffers]
+    live = np.flatnonzero(lanes[-1] > 0)
+    lanes = [lane[live] for lane in lanes]
+    if wide_in:
+        l0, l1, h0, h1, cnt = lanes
+        return live, ((h1.astype(object) << 96) + (h0.astype(object) << 64)
+                      + (l1.astype(object) << 32) + l0.astype(object)), cnt
+    lo, hi, cnt = lanes
+    return live, (hi.astype(object) << 32) + lo.astype(object), cnt
+
+
+def _half_up_div(num: int, den: int) -> int:
+    """num / den rounded HALF_UP (ties away from zero), exactly."""
+    q = (abs(num) * 2 + den) // (2 * den)
+    return q if num >= 0 else -q
+
+
+def _decimal_array(values, dtype):
+    """Python ints at ``dtype``'s scale (None for NULL) -> arrow decimal128."""
+    import pyarrow as pa
+    return pa.array([None if x is None else T.decimal_value(x, dtype.scale)
+                     for x in values],
+                    type=pa.decimal128(dtype.precision, dtype.scale))
+
+
+def _overflow(totals, dtype, ansi: bool, what: str):
+    """Groups whose total passes ``dtype``'s precision: NULL, or the raise
+    under ANSI."""
+    bound = 10 ** dtype.precision
+    over = np.array([abs(t) >= bound for t in totals], dtype=bool)
+    if ansi and over.any():
+        raise OverflowError(
+            f"{what} overflowed decimal({dtype.precision},{dtype.scale}) "
+            "(ANSI mode)")
+    return over
+
+
 class Sum(AggregateExpression):
     """SUM with Spark result types: decimal(p,s) → decimal(min(38,p+10),s)
     (TypeChecks.scala:626 DECIMAL_128, decimalExpressions.scala).  Wide
-    results (precision > 18) accumulate ON DEVICE as two int64 limbs of
-    the scaled value (lo 32 bits / hi bits sum separately, each safe for
-    2^31 rows) and reconstruct EXACTLY on the host at finalize — python
-    ints are arbitrary precision, so no 128-bit device kernel is needed;
-    overflow past the result precision raises under ANSI, else NULL."""
+    results (precision > 18) accumulate ON DEVICE as int64 lanes of the
+    scaled value (:func:`_decimal_sum_lanes`) and reconstruct EXACTLY on
+    the host at finalize — python ints are arbitrary precision, so no
+    128-bit device kernel is needed; overflow past the result precision
+    raises under ANSI, else NULL."""
 
     func = "sum"
     input_sig = (T.TypeSig.device_compute
@@ -70,10 +145,9 @@ class Sum(AggregateExpression):
         elif c.is_floating:
             self.dtype = T.FLOAT64
         elif c.is_decimal:
-            rp = min(c.precision + 10, 38)
-            self.dtype = T.decimal(rp, c.scale)
-            self._wide = rp > 18
-            self._wide_in = getattr(c, "is_wide_decimal", False)
+            self.dtype = T.decimal_sum_type(c)
+            self._wide = self.dtype.precision > 18
+            self._wide_in = c.is_wide_decimal
         else:
             raise TypeError(f"sum of {c} not supported")
         self.nullable = True
@@ -83,41 +157,14 @@ class Sum(AggregateExpression):
         return getattr(self, "_wide", False)
 
     def buffers(self):
-        if getattr(self, "_wide_in", False):
-            # wide INPUT (two-limb columns): four carry-free 32-bit-chunk
-            # lanes (lo0, lo1, hi0, hi1-signed) — every lane sum is
-            # < 2^63 for up to 2^31 rows, so reconstruction at finalize
-            # is exact for ANY summable input, cancellation included
-            return [(T.INT64, "sum"), (T.INT64, "sum"), (T.INT64, "sum"),
-                    (T.INT64, "sum"), (T.INT64, "sum")]
         if getattr(self, "_wide", False):
-            return [(T.INT64, "sum"), (T.INT64, "sum"), (T.INT64, "sum")]
+            return _decimal_sum_buffers(self._wide_in)
         return [(self.dtype, "sum"), (T.INT64, "sum")]
 
     def update(self, ctx) -> List[Value]:
         d, v = self.children[0].eval(ctx)
-        if getattr(self, "_wide_in", False):
-            import jax
-            lo, hi = d[..., 0], d[..., 1]
-            if v is not None:
-                z = jnp.zeros_like(lo)
-                lo = jnp.where(v, lo, z)
-                hi = jnp.where(v, hi, z)
-            m32 = jnp.int64(0xFFFFFFFF)
-            l0 = lo & m32
-            l1 = jax.lax.shift_right_logical(lo, jnp.int64(32))
-            h0 = hi & m32
-            h1 = hi >> jnp.int64(32)  # arithmetic: keeps the sign
-            return [(l0, None), (l1, None), (h0, None), (h1, None),
-                    (_valid_indicator(v, ctx), None)]
         if getattr(self, "_wide", False):
-            d = d.astype(jnp.int64)  # scaled ints (input precision <= 18)
-            if v is not None:
-                d = jnp.where(v, d, jnp.zeros_like(d))
-            hi = d >> jnp.int64(32)
-            lo = d - (hi << jnp.int64(32))  # in [0, 2^32)
-            return [(lo, None), (hi, None), (_valid_indicator(v, ctx),
-                                             None)]
+            return _decimal_sum_lanes(d, v, ctx, self._wide_in)
         d = d.astype(self.dtype.numpy_dtype)
         if v is not None:
             d = jnp.where(v, d, jnp.zeros_like(d))
@@ -128,33 +175,14 @@ class Sum(AggregateExpression):
         return s, cnt > 0
 
     def finalize_host(self, buffers, n_rows: int, ansi: bool):
-        """Exact host reconstruction of wide sums: arrow decimal128.
-        Vectorized in object space — python ints are arbitrary precision,
-        so the limb recombination is exact past int64."""
-        import decimal as _dec
-
-        import numpy as np
-        import pyarrow as pa
-        if getattr(self, "_wide_in", False):
-            l0, l1, h0, h1, cnt = [np.asarray(b[0][:n_rows])
-                                   for b in buffers]
-            totals = ((h1.astype(object) << 96) + (h0.astype(object) << 64)
-                      + (l1.astype(object) << 32) + l0.astype(object))
-        else:
-            lo, hi, cnt = [np.asarray(b[0][:n_rows]) for b in buffers]
-            totals = (hi.astype(object) << 32) + lo.astype(object)
-        bound = 10 ** self.dtype.precision
-        over = np.array([abs(t) >= bound for t in totals]) & (cnt > 0)
-        if ansi and over.any():
-            raise OverflowError(
-                f"sum overflowed decimal({self.dtype.precision},"
-                f"{self.dtype.scale}) (ANSI mode)")
-        scale = self.dtype.scale
-        out = [None if (cnt[i] <= 0 or over[i])
-               else _dec.Decimal(int(totals[i])).scaleb(-scale)
-               for i in range(n_rows)]
-        return pa.array(out, type=pa.decimal128(self.dtype.precision,
-                                                self.dtype.scale))
+        """Exact host reconstruction of wide sums: arrow decimal128."""
+        live, totals, _ = _decimal_sums_host(buffers, n_rows, self._wide_in)
+        over = _overflow(totals, self.dtype, ansi, "sum")
+        out = [None] * n_rows
+        for i, t, o in zip(live, totals, over):
+            if not o:
+                out[i] = int(t)
+        return _decimal_array(out, self.dtype)
 
 
 class Count(AggregateExpression):
@@ -223,24 +251,38 @@ class Max(_MinMax):
 
 
 class Average(AggregateExpression):
-    """AVG: tracked as (sum, count); int/float → double, decimal → double for
-    now (the reference returns decimal(p+4,s+4); planner notes the difference)."""
+    """AVG: int/float -> double over (sum, count).  A decimal(p,s) ->
+    decimal(min(38,p+4), min(38,s+4)), exact, as Spark's Average does: the
+    sum (decimal(min(38,p+10),s), NULL past it) divided by the count as
+    decimal(20,0) at Divide's type, then cast to the result, HALF_UP at each
+    step.  The sum rides as :class:`Sum`'s int64 lanes and the division
+    runs on the host, in python ints, where the groups are few."""
 
     func = "avg"
+    input_sig = Sum.input_sig
+    output_sig = Sum.output_sig
 
     def _resolve(self):
-        self.dtype = T.FLOAT64
+        c = self.children[0].dtype
+        self._decimal = c.is_decimal
+        self.dtype = T.decimal_avg_type(c) if c.is_decimal else T.FLOAT64
         self.nullable = True
 
+    @property
+    def host_finalize(self) -> bool:
+        return getattr(self, "_decimal", False)
+
     def buffers(self):
+        if getattr(self, "_decimal", False):
+            return _decimal_sum_buffers(self.children[0].dtype.is_wide_decimal)
         return [(T.FLOAT64, "sum"), (T.INT64, "sum")]
 
     def update(self, ctx):
         d, v = self.children[0].eval(ctx)
         src = self.children[0].dtype
+        if getattr(self, "_decimal", False):
+            return _decimal_sum_lanes(d, v, ctx, src.is_wide_decimal)
         d = d.astype(jnp.float64)
-        if src.is_decimal:
-            d = d / (10.0 ** src.scale)
         if v is not None:
             d = jnp.where(v, d, jnp.zeros_like(d))
         return [(d, None), (_valid_indicator(v, ctx), None)]
@@ -249,6 +291,33 @@ class Average(AggregateExpression):
         (s, _), (cnt, _) = values
         ok = cnt > 0
         return s / jnp.where(ok, cnt, 1).astype(jnp.float64), ok
+
+    def decimal_value(self, total: int, n: int) -> Optional[int]:
+        """The average of ``n`` values summing to ``total`` (python ints at
+        the input's scale), at the result's scale; None for NULL."""
+        sum_t = T.decimal_sum_type(self.children[0].dtype)
+        div_t = T.decimal_divide_type(sum_t, T.decimal(20, 0))
+        res = self.dtype
+        if n <= 0 or abs(total) >= 10 ** sum_t.precision:
+            return None
+        # Divide: sum / count at div_t's scale, HALF_UP
+        q = _half_up_div(total * 10 ** (div_t.scale - sum_t.scale), n)
+        if abs(q) >= 10 ** div_t.precision:
+            return None
+        # Cast to the result type, HALF_UP
+        k = div_t.scale - res.scale
+        q = _half_up_div(q, 10 ** k) if k >= 0 else q * 10 ** -k
+        return None if abs(q) >= 10 ** res.precision else q
+
+    def finalize_host(self, buffers, n_rows: int, ansi: bool):
+        src = self.children[0].dtype
+        live, totals, cnt = _decimal_sums_host(buffers, n_rows,
+                                               src.is_wide_decimal)
+        _overflow(totals, T.decimal_sum_type(src), ansi, "avg's sum")
+        out = [None] * n_rows
+        for i, t, n in zip(live, totals, cnt):
+            out[i] = self.decimal_value(int(t), int(n))
+        return _decimal_array(out, self.dtype)
 
 
 class First(AggregateExpression):

@@ -514,18 +514,12 @@ def from_arrow(table, min_capacity: int = 1024, device=None) -> ColumnBatch:
             # compute over these off the device
             cols.append(HostStringColumn(col, capacity=cap))
             continue
-        if dt.is_wide_decimal:
-            # Arrow decimal128 → (n, 2) int64 limbs [lo, hi] of the
-            # scaled two's-complement value (emulated int128)
-            data = _pad_to(wide_decimal_limbs(col, dt.scale), cap)
-            valid_np = np.asarray(col.is_valid())
-        elif dt.is_decimal:
-            # Arrow decimal128 → scaled int64 (precision <= 18 here).
-            scaled = np.array(
-                [int(v.scaleb(dt.scale)) if v is not None else 0
-                 for v in (x.as_py() for x in col)], dtype=np.int64)
-            data = _pad_to(scaled, cap)
-            valid_np = np.asarray(col.is_valid())
+        if dt.is_decimal:
+            # the scaled values straight from the arrow buffer: int64 for
+            # p <= 18, (n, 2) int64 limbs [lo, hi] of the 128-bit value
+            # past it (emulated int128)
+            data, valid_np = decimal_scaled(col, dt.is_wide_decimal)
+            data = _pad_to(data, cap)
         else:
             # null payload slots are masked by the validity array; fill them
             # with a typed zero so integer casts are well-defined (float NaN
@@ -575,30 +569,67 @@ def from_numpy(data: Dict[str, np.ndarray], min_capacity: int = 1024) -> ColumnB
     return ColumnBatch(Schema(fields), cols, n)
 
 
-def wide_decimal_limbs(col, scale: int) -> np.ndarray:
-    """pyarrow decimal128 array → (n, 2) int64 [lo, hi] limbs of the
-    scaled value (python ints are arbitrary precision, so the split is
-    exact; nulls become zero limbs under their validity mask)."""
-    n = len(col)
-    out = np.zeros((n, 2), dtype=np.int64)
-    mask64 = (1 << 64) - 1
-    for i, x in enumerate(col):
-        v = x.as_py()
-        if v is None:
-            continue
-        u = int(v.scaleb(scale)) & ((1 << 128) - 1)
-        lo = u & mask64
-        hi = u >> 64
-        out[i, 0] = lo - (1 << 64) if lo >= (1 << 63) else lo
-        out[i, 1] = hi - (1 << 64) if hi >= (1 << 63) else hi
+def decimal_scaled(col, wide: bool):
+    """An arrow decimal array's scaled values, read as a view of its value
+    buffer (no value goes through python): (n,) int64 for p <= 18, which
+    is the low word of each 128-bit value, or (n, 2) int64 limbs [lo, hi];
+    NULL slots zero.  Returns (values, validity or None).  Both parquet
+    encodings of a decimal (INT64 / FIXED_LEN_BYTE_ARRAY) arrive here as
+    the same arrow array; the decode is a ``scan:decimal`` span whose
+    seconds add to ``QueryStats.decimal_decode_s``."""
+    from .utils import tracing
+    from .utils.metrics import QueryStats
+    with tracing.span(None, "scan:decimal", "io") as sp:
+        out = decimal_words(col, wide)
+        sp.set(rows=len(col))
+    QueryStats.get().decimal_decode_s += sp.dur
     return out
 
 
-def wide_limbs_to_ints(data: np.ndarray) -> np.ndarray:
-    """(n, 2) int64 limbs → object array of exact python ints."""
-    lo = data[:, 0].astype(object) & ((1 << 64) - 1)
-    hi = data[:, 1].astype(object)
-    return (hi << 64) + lo
+def decimal_view(col) -> np.ndarray:
+    """An arrow decimal array's value buffer as it lies, with no copy:
+    (n,) words for a 4- or 8-byte decimal, (n, 2) int64 [lo, hi] for
+    decimal128, whose low word alone is the scaled value at p <= 18.
+    NULL slots hold whatever the buffer holds."""
+    n, off, width = len(col), col.offset, col.type.byte_width
+    words = np.frombuffer(col.buffers()[1], dtype=np.int64
+                          if width >= 8 else np.int32,
+                          count=(off + n) * max(width // 8, 1))
+    if width == 16:
+        words = words.reshape(-1, 2)
+    return words[off:off + n]
+
+
+def decimal_words(col, wide: bool):
+    """:func:`decimal_scaled` with no span: for arrays that are no scan's."""
+    words = decimal_view(col).astype(np.int64, copy=False)
+    if wide and words.ndim == 1:
+        words = np.stack([words, words >> 63], axis=-1)
+    elif not wide and words.ndim == 2:
+        words = words[:, 0]
+    if not col.null_count:
+        return np.ascontiguousarray(words), None
+    valid = np.asarray(col.is_valid())
+    return np.where(valid.reshape((-1,) + (1,) * (words.ndim - 1)),
+                    words, 0), valid
+
+
+def decimal_array(data: np.ndarray, valid: Optional[np.ndarray],
+                  dtype: DataType):
+    """Scaled values ((n,) int64 or (n, 2) limbs) -> an arrow decimal128
+    array, built on their buffer: the inverse of :func:`decimal_scaled`."""
+    import pyarrow as pa
+    n = len(data)
+    words = np.empty((n, 2), dtype=np.int64)
+    if data.ndim == 1:
+        words[:, 0] = data
+        words[:, 1] = data >> 63
+    else:
+        words[:] = data
+    vbuf = None if valid is None else pa.py_buffer(
+        np.packbits(np.asarray(valid, dtype=bool), bitorder="little"))
+    return pa.Array.from_buffers(logical_to_arrow(dtype), n,
+                                 [vbuf, pa.py_buffer(words)])
 
 
 def _to_arrow_tree(batch: ColumnBatch) -> dict:
@@ -693,21 +724,8 @@ def _host_to_arrow(batch: ColumnBatch, host: dict):
             arrays.append(pa.array(data.astype("datetime64[us]"),
                                    type=pa.timestamp("us"),
                                    mask=(~valid if valid is not None else None)))
-        elif f.dtype.is_wide_decimal:
-            from decimal import Decimal
-            scale = f.dtype.scale
-            ints = wide_limbs_to_ints(data)
-            vals = [None if (valid is not None and not valid[i])
-                    else Decimal(int(ints[i])).scaleb(-scale)
-                    for i in range(len(data))]
-            arrays.append(pa.array(vals, type=logical_to_arrow(f.dtype)))
         elif f.dtype.is_decimal:
-            from decimal import Decimal
-            scale = f.dtype.scale
-            vals = [None if (valid is not None and not valid[i])
-                    else Decimal(int(data[i])).scaleb(-scale)
-                    for i in range(len(data))]
-            arrays.append(pa.array(vals, type=logical_to_arrow(f.dtype)))
+            arrays.append(decimal_array(data, valid, f.dtype))
         else:
             arrays.append(pa.array(data, type=logical_to_arrow(f.dtype),
                                    mask=(~valid if valid is not None else None)))

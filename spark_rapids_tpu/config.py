@@ -570,7 +570,9 @@ SCAN_EXACT_FILTER = register(
     "C++ kernels) so filtered-out rows never pay the host→HBM upload. The "
     "device filter still evaluates the complete condition; this is the "
     "late-materialization analog of the reference pushing predicates into "
-    "the device decode.")
+    "the device decode. A batch whose filter keeps 90% of its rows or more "
+    "and leaves it in the same capacity bucket is not filtered on host: "
+    "it would upload the same slots.")
 
 FILE_CACHE_ENABLED = register(
     "spark.rapids.tpu.sql.fileCache.enabled", False,

@@ -83,17 +83,10 @@ def eval_cpu(expr: E.Expression, arrays, n: int) -> Value:
         d, v = ev(expr.children[0])
         return _cast_cpu(d, v, expr.children[0].dtype, expr.dtype)
 
-    if isinstance(expr, E.Multiply) and expr.dtype.is_decimal:
-        l, r = expr.children
-        ld, lv = ev(l)
-        rd, rv = ev(r)
-        ls = l.dtype.scale if l.dtype.is_decimal else 0
-        rs = r.dtype.scale if r.dtype.is_decimal else 0
-        prod = ld.astype(np.int64) * rd.astype(np.int64)
-        drop = ls + rs - expr.dtype.scale
-        if drop > 0:
-            prod = _round_div(prod, 10 ** drop)
-        return prod, _and(lv, rv)
+    if isinstance(expr, (E.Add, E.Subtract, E.Multiply)) \
+            and expr.decimal_operands() is not None:
+        return _decimal_arith_cpu(expr, ev(expr.children[0]),
+                                  ev(expr.children[1]))
     if isinstance(expr, (E.Add, E.Subtract, E.Multiply)):
         ld, lv = ev(expr.children[0])
         rd, rv = ev(expr.children[1])
@@ -270,6 +263,42 @@ def _round_div(x: np.ndarray, d: int) -> np.ndarray:
     numpy twin of exprs._round_div."""
     sign = np.where(x >= 0, 1, -1)
     return sign * ((np.abs(x) + d // 2) // d)
+
+
+def _half_up_obj(x: np.ndarray, k: int) -> np.ndarray:
+    """Python-int array / 10^k, rounded HALF_UP (exact)."""
+    if k <= 0:
+        return x * 10 ** -k
+    d = 10 ** k
+    return np.array([(abs(v) * 2 + d) // (2 * d) * (1 if v >= 0 else -1)
+                     for v in x], dtype=object)
+
+
+def _decimal_arith_cpu(expr, left, right) -> Value:
+    """CPU mirror of the device's decimal ``+ - *`` (exprs._DecimalArithmetic):
+    exact in python ints, HALF_UP to the result scale, NULL (or the ANSI
+    raise) past the result's precision."""
+    (ld, lv), (rd, rv) = left, right
+    dl, dr = expr.decimal_operands()
+    res = expr.dtype
+    a, b = ld.astype(object), rd.astype(object)
+    if isinstance(expr, E.Multiply):
+        out = _half_up_obj(a * b, dl.scale + dr.scale - res.scale)
+    else:  # both operands cast to the result type first (Spark 3.x)
+        a = _half_up_obj(a, dl.scale - res.scale)
+        b = _half_up_obj(b, dr.scale - res.scale)
+        out = a + b if isinstance(expr, E.Add) else a - b
+    valid = _and(lv, rv)
+    over = np.array([abs(v) >= 10 ** res.precision for v in out], dtype=bool)
+    if over.any():
+        live = np.ones(len(out), bool) if valid is None else valid
+        if ansi_enabled() and bool((over & live).any()):
+            raise ArithmeticError(
+                f"ANSI mode: decimal overflow past {res}")
+        out = np.where(over, 0, out)
+        valid = ~over if valid is None else (valid & ~over)
+    return out.astype(_np_dtype(res) if res.precision <= 18 else object), \
+        valid
 
 
 def _promote_cpu(data: np.ndarray, src: T.DataType, dst: T.DataType) -> np.ndarray:

@@ -53,7 +53,8 @@ def arrow_to_values(table, schema: Schema):
             # scaled ints; beyond 64-bit range keep python ints (object) —
             # exact compare/sort, no overflow (decimal128 fallback tier)
             kind = object if f.dtype.precision > 18 else np.int64
-            np_arr = np.array([0 if x is None else int(x.scaleb(f.dtype.scale))
+            np_arr = np.array([0 if x is None
+                               else T.unscaled_value(x, f.dtype.scale)
                                for x in arr.to_pylist()], dtype=kind)
         else:
             np_arr = np_arr.astype(f.dtype.numpy_dtype)
@@ -87,9 +88,8 @@ def values_to_arrow(schema: Schema, values, n: int):
             arrays.append(pa.array(data[:n].astype("datetime64[us]"),
                                    type=pa.timestamp("us"), mask=mask))
         elif f.dtype.is_decimal:
-            from decimal import Decimal
             pl = [None if (mask is not None and mask[i])
-                  else Decimal(int(data[i])).scaleb(-f.dtype.scale)
+                  else T.decimal_value(data[i], f.dtype.scale)
                   for i in range(n)]
             arrays.append(pa.array(pl, type=logical_to_arrow(f.dtype)))
         else:
@@ -364,6 +364,9 @@ class CpuOpExec(TpuExec):
             return x.min(), True
         if isinstance(b, A.Max):
             return x.max(), True
+        if isinstance(b, A.Average) and b.dtype.is_decimal:
+            q = b.decimal_value(sum(int(v) for v in x), len(sel))
+            return (0, False) if q is None else (q, True)
         if isinstance(b, A.Average):
             src = b.children[0].dtype
             xf = x.astype(np.float64)

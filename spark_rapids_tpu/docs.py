@@ -154,7 +154,29 @@ def supported_ops_md() -> str:
     for name, group, where, in_types, out_types in _expr_rows():
         lines.append(f"| {name} | {group} | {where} "
                      f"| {in_types} | {out_types} |")
+    lines += ["", "## Decimal arithmetic", "", _DECIMAL_NOTES]
     return "\n".join(lines) + "\n"
+
+
+_DECIMAL_NOTES = """\
+A decimal of 18 digits or fewer is one scaled int64 lane on the device, a
+wider one (up to 38) two int64 limbs (`ops/wide_decimal.py`).  These follow
+Spark 3.x's result types (`DecimalPrecision`, with
+`spark.sql.decimalOperations.allowPrecisionLoss` on, its default), exactly:
+
+| Expression | Result type | Value |
+|---|---|---|
+| `a + b`, `a - b` | p = max(s1, s2) + max(p1 - s1, p2 - s2) + 1, s = max(s1, s2) | both operands cast to the result type, then added (Spark 3.0-3.3) |
+| `a * b` | p = p1 + p2 + 1, s = s1 + s2 | the exact product (64 x 64 and 128 x 64 bits, and 128 x 128), HALF_UP where the scale drops |
+| past 38 digits | precision 38; the integer digits are kept and the scale gives way, down to min(s, 6) | NULL where a value passes the precision; raises under ANSI |
+| integral operand | a literal at its own digits (`1` is decimal(1,0)); a column decimal(3/5/10/20, 0) | |
+| `sum(decimal(p,s))` | decimal(min(38, p + 10), s) | exact; NULL past the precision (raises under ANSI) |
+| `avg(decimal(p,s))` | decimal(min(38, p + 4), min(38, s + 4)) | Spark's Average: the sum over the count as decimal(20,0) at Divide's type, then cast to the result, HALF_UP at each step |
+
+Not yet Spark's (stated gaps): `/` with a decimal operand returns a double
+(Spark: a decimal at Divide's type); `%`, `pmod` and `div` keep the older
+common-type rules.  Variance, stddev, covariance, correlation and the
+percentiles of a decimal compute in double, as Spark's do."""
 
 
 def configs_md() -> str:

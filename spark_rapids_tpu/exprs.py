@@ -198,7 +198,7 @@ def physical_literal(v: Any, dtype: DataType):
     if dtype.is_decimal:
         from decimal import Decimal
         if isinstance(v, Decimal):
-            return int(v.scaleb(dtype.scale).to_integral_value())
+            return T.unscaled_value(v, dtype.scale)
         return int(round(float(v) * 10 ** dtype.scale))
     if dtype.kind == T.TypeKind.DATE:
         if isinstance(v, datetime.date):
@@ -452,63 +452,193 @@ class BinaryExpression(Expression):
         return T.common_type(self.children[0].dtype, self.children[1].dtype)
 
 
-class Add(BinaryExpression):
+def _decimal_operand(e: Expression) -> Optional[DataType]:
+    """The decimal type an operand takes in decimal arithmetic (Spark's
+    DecimalPrecision): a decimal as it is, an integral literal at its own
+    digits, any other integral at DecimalType.forType; None otherwise."""
+    dt = e.dtype
+    if dt.is_decimal:
+        return dt
+    if dt.is_integral:
+        if isinstance(e, Literal) and e.value is not None:
+            return T.decimal_of_literal(e.value)
+        return T.decimal_for_integral(dt)
+    return None
+
+
+def rescale_decimal(data: jax.Array, src: DataType, dst: DataType,
+                    limbs: bool):
+    """A decimal value (an integral one at scale 0) in ``dst``'s scale and
+    representation, HALF_UP where the scale drops; ``limbs`` says whether
+    ``data`` is (n, 2) limbs or one int lane.  Returns (data, overflow or
+    None): the overflow mask is computed only where ``dst`` may not hold
+    the value (fewer integer digits, or as many and a rounding that can
+    carry into a new one), and then through 128 bits, so that no int64
+    step can wrap unseen."""
+    from .ops import wide_decimal as _wd
+    delta = dst.scale - src.scale
+    room = (dst.precision - dst.scale) - (src.precision - src.scale)
+    if (room > 0 or (room == 0 and delta >= 0)) \
+            and (dst.is_wide_decimal or not limbs):
+        if dst.is_wide_decimal:
+            x = data if limbs else _wd.from_scaled64(data.astype(jnp.int64))
+            return (_wd.mul_pow10(x, delta) if delta >= 0
+                    else _wd.round_pow10(x, -delta)), None
+        x = data.astype(jnp.int64)
+        return (x * np.int64(10 ** delta) if delta >= 0
+                else _round_div(x, 10 ** -delta)), None
+    x = data if limbs else _wd.from_scaled64(data.astype(jnp.int64))
+    x = _wd.round_pow10(x, -delta) if delta < 0 else x
+    over = ~_wd.fits(x, dst.precision - max(delta, 0))
+    x = _wd.mul_pow10(x, max(delta, 0))
+    return (x if dst.is_wide_decimal else x[..., 0]), over
+
+
+class _DecimalArithmetic(BinaryExpression):
+    """``+ - *`` with Spark 3.x's decimal result types: operands typed by
+    :func:`_decimal_operand`, the result by ``types.decimal_*_type`` up to
+    precision 38 with the precision-loss adjustment, a value past the
+    result's precision NULL (raised under ANSI).  Non-decimal operands
+    keep the plain path."""
+
+    def decimal_operands(self) -> Optional[Tuple[DataType, DataType]]:
+        l, r = self.children
+        if not (l.dtype.is_decimal or r.dtype.is_decimal):
+            return None
+        dl, dr = _decimal_operand(l), _decimal_operand(r)
+        return None if dl is None or dr is None else (dl, dr)
+
+    def _resolve(self):
+        super()._resolve()
+        ops = self.decimal_operands()
+        if ops is not None:
+            self.dtype = self._decimal_type(*ops)
+            self.nullable = self.nullable or self.may_overflow()
+
+    def may_overflow(self) -> bool:
+        """Whether a value can pass the result's precision: only where the
+        adjustment cut the precision Spark's rule asked for."""
+        raw = self._decimal_raw_precision(*self.decimal_operands())
+        return raw > T.MAX_DECIMAL_PRECISION
+
+    def _null_on_overflow(self, ctx, data, valid, over):
+        if over is None:
+            return data, valid
+        if ctx.ansi:
+            ctx.record_error(over, valid)
+        return data, _and_valid(valid, ~over)
+
+
+class _DecimalAddSub(_DecimalArithmetic):
+    input_sig = TypeSig.device_compute + TypeSig.decimal128
+    output_sig = TypeSig.device_compute + TypeSig.decimal128
+
+    def _decimal_type(self, dl, dr):
+        return T.decimal_add_type(dl, dr)
+
+    @staticmethod
+    def _decimal_raw_precision(dl, dr):
+        s = max(dl.scale, dr.scale)
+        return s + max(dl.precision - dl.scale, dr.precision - dr.scale) + 1
+
+    def eval(self, ctx):
+        ops = self.decimal_operands()
+        if ops is None:
+            ld, rd, v = self._eval_children_promoted(ctx)
+            if getattr(self._operand_type(), "is_wide_decimal", False):
+                from .ops import wide_decimal as _wd
+                return getattr(_wd, self.wide_op)(ld, rd), v
+            return self.narrow_op(ld, rd), v
+        # Spark 3.x casts both operands to the result type, then adds
+        (l, r), res = self.children, self.dtype
+        (ld, lv), (rd, rv) = l.eval(ctx), r.eval(ctx)
+        ld, lo = rescale_decimal(ld, ops[0], res, l.dtype.is_wide_decimal)
+        rd, ro = rescale_decimal(rd, ops[1], res, r.dtype.is_wide_decimal)
+        over = lo if ro is None else (ro if lo is None else lo | ro)
+        if not res.is_wide_decimal:
+            return self._null_on_overflow(
+                ctx, self.narrow_op(ld, rd), _and_valid(lv, rv), over)
+        from .ops import wide_decimal as _wd
+        out = getattr(_wd, self.wide_op)(ld, rd)
+        if self.may_overflow():
+            rd_ = rd if type(self) is Add else _wd.neg(rd)
+            bad = _wd.add_overflows(ld, rd_, out) \
+                | ~_wd.fits(out, res.precision)
+            over = bad if over is None else over | bad
+        return self._null_on_overflow(ctx, out, _and_valid(lv, rv), over)
+
+    def _operand_type(self) -> DataType:
+        if self.decimal_operands() is not None:
+            return self.dtype
+        return super()._operand_type()
+
+
+class Add(_DecimalAddSub):
     symbol = "+"
-    input_sig = TypeSig.device_compute + TypeSig.decimal128
-    output_sig = TypeSig.device_compute + TypeSig.decimal128
-
-    def eval(self, ctx):
-        ld, rd, v = self._eval_children_promoted(ctx)
-        if getattr(self._operand_type(), "is_wide_decimal", False):
-            from .ops import wide_decimal as _wd
-            return _wd.add(ld, rd), v
-        return ld + rd, v
+    narrow_op = staticmethod(lambda a, b: a + b)
+    wide_op = "add"
 
 
-class Subtract(BinaryExpression):
+class Subtract(_DecimalAddSub):
     symbol = "-"
+    narrow_op = staticmethod(lambda a, b: a - b)
+    wide_op = "sub"
+
+
+class Multiply(_DecimalArithmetic):
+    symbol = "*"
     input_sig = TypeSig.device_compute + TypeSig.decimal128
     output_sig = TypeSig.device_compute + TypeSig.decimal128
 
-    def eval(self, ctx):
-        ld, rd, v = self._eval_children_promoted(ctx)
-        if getattr(self._operand_type(), "is_wide_decimal", False):
-            from .ops import wide_decimal as _wd
-            return _wd.sub(ld, rd), v
-        return ld - rd, v
+    def _decimal_type(self, dl, dr):
+        return T.decimal_multiply_type(dl, dr)
 
-
-class Multiply(BinaryExpression):
-    symbol = "*"
+    @staticmethod
+    def _decimal_raw_precision(dl, dr):
+        return dl.precision + dr.precision + 1
 
     def eval(self, ctx):
-        if self.dtype.is_decimal:
-            # Evaluate operands at their OWN scales (promotion to a common
-            # scale would inflate the product scale): scaled-int product has
-            # scale ls+rs; rescale to the result scale (round half up).
-            l, r = self.children
-            ld, lv = l.eval(ctx)
-            rd, rv = r.eval(ctx)
-            ls = l.dtype.scale if l.dtype.is_decimal else 0
-            rs = r.dtype.scale if r.dtype.is_decimal else 0
-            prod = ld.astype(jnp.int64) * rd.astype(jnp.int64)
-            drop = ls + rs - self.dtype.scale
-            if drop > 0:
-                prod = _round_div(prod, 10 ** drop)
-            return prod, _and_valid(lv, rv)
-        ld, rd, v = self._eval_children_promoted(ctx)
-        return ld * rd, v
+        ops = self.decimal_operands()
+        if ops is None:
+            ld, rd, v = self._eval_children_promoted(ctx)
+            return ld * rd, v
+        # operands at their OWN scales: the exact product has scale
+        # s1 + s2, rounded HALF_UP to the result's where the adjustment
+        # dropped digits
+        (l, r), res = self.children, self.dtype
+        (ld, lv), (rd, rv) = l.eval(ctx), r.eval(ctx)
+        valid = _and_valid(lv, rv)
+        if not res.is_wide_decimal:  # p1 + p2 + 1 <= 18: exact in int64
+            return ld.astype(jnp.int64) * rd.astype(jnp.int64), valid
+        from .ops import wide_decimal as _wd
+        prod, over = _wd.multiply(
+            ld if l.dtype.is_wide_decimal else ld.astype(jnp.int64),
+            rd if r.dtype.is_wide_decimal else rd.astype(jnp.int64),
+            ops[0].scale + ops[1].scale - res.scale)
+        if not self.may_overflow():
+            return prod, valid  # |a * b| < 10^(p1 + p2): always fits
+        return self._null_on_overflow(
+            ctx, prod, valid, over | ~_wd.fits(prod, res.precision))
 
-    def _result_type(self, lt, rt):
-        if lt.is_decimal and rt.is_integral:
-            rt = T.integral_as_decimal(rt)
-        if rt.is_decimal and lt.is_integral:
-            lt = T.integral_as_decimal(lt)
-        if lt.is_decimal and rt.is_decimal:
-            p = min(lt.precision + rt.precision + 1, 18)
-            s = min(lt.scale + rt.scale, p)
-            return T.decimal(p, s)
-        return T.common_type(lt, rt)
+
+def wide_decimal_outputs(exprs) -> int:
+    """The distinct computed nodes (no column, literal or alias, no
+    aggregate) whose value is a wide decimal, over the expression trees
+    of one program: what ``QueryStats.wide_decimal_slots`` counts a slot
+    each of, per batch."""
+    seen = set()
+
+    def walk(e):
+        if e is None:
+            return
+        if (getattr(e.dtype, "is_wide_decimal", False) and e.children
+                and not isinstance(e, (Alias, AggregateExpression))):
+            seen.add(e.fingerprint())
+        for c in e.children:
+            walk(c)
+    for e in exprs:
+        walk(e)
+    return len(seen)
 
 
 def _round_div(x: jax.Array, d: int) -> jax.Array:

@@ -28,7 +28,8 @@ from typing import Callable, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from ..batch import Field, Schema, _arrow_to_logical
+from ..batch import (Field, Schema, _arrow_to_logical, bucket_capacity,
+                     decimal_view)
 
 __all__ = ["parquet_schema", "parquet_source", "expand_paths", "ParquetSource",
            "prune_row_groups", "Predicate"]
@@ -255,6 +256,48 @@ def _aligned_tables(pf, pf_coded, coded: List[str],
         yield table(None, pieces)
 
 
+_NP_COMPARE = {"<": np.less, "<=": np.less_equal, ">": np.greater,
+               ">=": np.greater_equal, "==": np.equal}
+
+
+def _scaled_compare(col, op: str, value, out: Optional[np.ndarray]):
+    """``col <op> value`` over a decimal column of precision <= 18 as a
+    compare of its scaled words (:func:`decimal_view`) with the literal at
+    the column's scale, ANDed into ``out`` (a numpy mask, or None for a
+    new one): Arrow's decimal128 kernels take about twice as long.  NULL
+    rows come out False, which the filter drops as it drops NULL.  None
+    where this does not apply: another type, or a literal that is not a
+    whole number of the column's units."""
+    import decimal
+    from fractions import Fraction
+
+    import pyarrow as pa
+    ty = col.type
+    if not (pa.types.is_decimal(ty) and ty.precision <= 18) \
+            or isinstance(value, bool) \
+            or not isinstance(value, (int, decimal.Decimal)) \
+            or (isinstance(value, decimal.Decimal)
+                and not value.is_finite()):
+        return None
+    k = Fraction(value) * 10 ** ty.scale
+    if k.denominator != 1 or abs(k) >= 2 ** 63:
+        return None
+    k = np.int64(k.numerator)
+    m = np.empty(len(col), dtype=bool)
+    at = 0
+    for ch in (col.chunks if isinstance(col, pa.ChunkedArray) else [col]):
+        words = decimal_view(ch)
+        part = m[at:at + len(ch)]
+        _NP_COMPARE[op](words[:, 0] if words.ndim == 2 else words, k,
+                        out=part)
+        if ch.null_count:
+            part &= np.asarray(ch.is_valid())
+        at += len(ch)
+    if out is not None:
+        m &= out
+    return m
+
+
 def _exact_filter_mask(table, predicates: Sequence[Predicate]):
     """Kleene-AND mask of the pushed conjuncts over a decoded host table.
 
@@ -265,8 +308,10 @@ def _exact_filter_mask(table, predicates: Sequence[Predicate]):
     filter that still runs downstream, so filtering here is exact, not
     advisory.  Returns None when any conjunct cannot be applied exactly.
     """
+    import pyarrow as pa
     import pyarrow.compute as pc
     mask = None
+    scaled = None  # the decimal conjuncts' numpy mask (_scaled_compare)
     ops = {"<": pc.less, "<=": pc.less_equal, ">": pc.greater,
            ">=": pc.greater_equal, "==": pc.equal}
     for name, op, value in predicates:
@@ -275,11 +320,14 @@ def _exact_filter_mask(table, predicates: Sequence[Predicate]):
         col = table[name]
         try:
             if op in ops:
+                m = _scaled_compare(col, op, value, scaled)
+                if m is not None:
+                    scaled = m
+                    continue
                 m = ops[op](col, value)
             elif op == "in":
                 # null list elements only affect non-matching rows (null
                 # result), which the filter drops either way
-                import pyarrow as pa
                 vals = [v for v in value if v is not None]
                 ty = col.type if hasattr(col, "type") else None
                 if ty is not None and pa.types.is_dictionary(ty):
@@ -292,7 +340,37 @@ def _exact_filter_mask(table, predicates: Sequence[Predicate]):
         except Exception:
             return None  # incomparable literal/column types: skip exact path
         mask = m if mask is None else pc.and_kleene(mask, m)
+    if scaled is not None:
+        scaled = pa.array(scaled)
+        mask = scaled if mask is None else pc.and_kleene(mask, scaled)
     return mask
+
+
+# A batch's host filter that keeps this share of its rows or more, and
+# leaves it in the same capacity bucket, is left to the device filter
+# above the scan (pushdown keeps it): the batch uploads the same slots and
+# the device does the same work either way, and the copy of every column
+# would cost the scan's thread host time for nothing.  A batch under
+# _HOST_FILTER_MIN_BYTES is filtered all the same: its copy costs less
+# than the sync with which an operator downstream may have to count the
+# rows the device filter left.
+_HOST_FILTER_MAX_KEEP = 0.9
+_HOST_FILTER_MIN_BYTES = 16 << 20
+
+
+def _filter_pays(table, mask) -> bool:
+    """Whether filtering ``table`` by ``mask`` on the host saves the
+    device anything, or costs less than it might (above)."""
+    import pyarrow.compute as pc
+    n = table.num_rows
+    kept = pc.sum(mask).as_py() or 0
+    if kept < _HOST_FILTER_MAX_KEEP * n \
+            or table.nbytes < _HOST_FILTER_MIN_BYTES:
+        return True
+    strings = any(_arrow_to_logical(ty).is_string
+                  for ty in table.schema.types)
+    return bucket_capacity(kept, has_strings=strings) \
+        != bucket_capacity(n, has_strings=strings)
 
 
 def _dv_fingerprint(rows) -> tuple:
@@ -635,7 +713,7 @@ class ParquetSource:
                 t = t.append_column(n, col)
             if self.exact_filter and file_preds:
                 mask = _exact_filter_mask(t, file_preds)
-                if mask is not None:
+                if mask is not None and _filter_pays(t, mask):
                     t = t.filter(mask)
                     if t.num_rows == 0:
                         continue

@@ -27,7 +27,7 @@ from .. import types as T
 from ..batch import ColumnBatch, DeviceColumn, Field, HostStringColumn, Schema
 from ..config import TpuConf
 from ..exprs import (AggregateExpression, Alias, BoundReference, EvalContext,
-                     Expression)
+                     Expression, wide_decimal_outputs)
 from ..ops import batch_utils, dense_agg, groupby
 from ..utils import tracing
 from ..utils.metrics import MetricSet, QueryStats, fetch, fetch_scalars, \
@@ -445,6 +445,12 @@ class StageExec(TpuExec):
         return f"TpuStage [{kinds}] -> {self._schema.names()}"
 
     # fingerprint identifies the traced program (cache key)
+    def expressions(self) -> List[Expression]:
+        """Every expression the stage's program evaluates."""
+        return [e for kind, payload in self.steps
+                for e in ([payload] if kind == "filter"
+                          else [e for _, e, _ in payload])]
+
     def fingerprint(self) -> str:
         def host_fp(src):
             if isinstance(src, tuple) and src[0] == "hc":
@@ -534,6 +540,7 @@ class StageExec(TpuExec):
         partitioned = child.outputs_partitions
         pid0 = getattr(ctx, "partition_id_base", 0)
         bstate = {"row_base": 0, "pid": pid0}
+        n_wide = wide_decimal_outputs(self.expressions())
 
         def run_one(b: ColumnBatch) -> ColumnBatch:
             arrays = []
@@ -614,6 +621,9 @@ class StageExec(TpuExec):
                                        b.sel, ansi)
 
             def _device_result():
+                if n_wide:
+                    QueryStats.get().wide_decimal_slots += \
+                        n_wide * b.capacity
                 outs = fn(tuple(arrays), tuple(extras),
                           b.sel, np.int32(b.num_rows))
                 if ansi:
@@ -796,6 +806,19 @@ class AggregateExec(TpuExec):
         return "|".join(parts)
 
     # -- helpers ------------------------------------------------------------------
+    def _wide_outputs(self, stage: Optional["StageExec"] = None) -> int:
+        """Wide decimal values the update program computes a row (the
+        group keys', the aggregates' inputs', a fused stage's)."""
+        exprs = [e for _, e in self.group_exprs]
+        exprs += [c for _, a in self.agg_exprs for c in a.children]
+        return wide_decimal_outputs(
+            exprs + (stage.expressions() if stage is not None else []))
+
+    @staticmethod
+    def _count_wide(n_wide: int, capacity: int) -> None:
+        if n_wide:
+            QueryStats.get().wide_decimal_slots += n_wide * capacity
+
     def _buffer_ops(self) -> List[str]:
         ops = []
         for _, agg in self.agg_exprs:
@@ -881,12 +904,14 @@ class AggregateExec(TpuExec):
             fp = "agg-ungrouped|" + self._fingerprint()
 
         batch_partials = _cached_program(fp, build)
+        n_wide = 0 if self.mode == "final" else self._wide_outputs(fused_stage)
 
         from ..memory.retry import with_retry
 
         def run_one(b: ColumnBatch):
             arrays = tuple((c.data, c.valid) if isinstance(c, DeviceColumn)
                            else None for c in b.columns)
+            self._count_wide(n_wide, b.capacity)
             return batch_partials(arrays, b.sel, np.int32(b.num_rows))
 
         # merge runs as ONE jitted program per pair — never eager ops:
@@ -1003,6 +1028,8 @@ class AggregateExec(TpuExec):
             f"agg-fin|{self.mode}|" + self._fingerprint(),
             lambda: program("agg_ungrouped_finalize", _fin))
         res = fin(tuple(acc))
+        host = {} if self.mode == "partial" \
+            else self._host_buffers(res, 0, finalized=True)
 
         cols: List = []
         fields = []
@@ -1015,17 +1042,13 @@ class AggregateExec(TpuExec):
                     fields.append(Field(f"{name}#buf{bi}", dt, True))
                     cols.append(DeviceColumn(dt, bd, bv))
             elif getattr(agg, "host_finalize", False):
-                import pyarrow as pa
                 nb = len(agg.buffers())
-                bufs = res[oi: oi + nb]
+                bufs = [host[oi + k] for k in range(nb)]
                 oi += nb
-                arr = agg.finalize_host(list(bufs), 1,
+                arr = agg.finalize_host(bufs, 1,
                                         getattr(self, "_ansi", False))
-                if len(arr) < cap:
-                    arr = pa.concat_arrays(
-                        [arr, pa.nulls(cap - len(arr), type=arr.type)])
                 fields.append(Field(name, agg.dtype, agg.nullable))
-                cols.append(HostStringColumn(arr))
+                cols.append(_device_column(arr, agg.dtype, cap))
             else:
                 data, valid = res[oi]
                 oi += 1
@@ -1169,6 +1192,8 @@ class AggregateExec(TpuExec):
         key_nonnull = (isinstance(kcol, DeviceColumn)
                        and kcol.valid is None)
 
+        n_wide = self._wide_outputs()
+
         def run():
             import itertools
             accs = _init_acc()
@@ -1200,6 +1225,7 @@ class AggregateExec(TpuExec):
                 if batch.num_rows == 0:
                     continue
                 with m.time("opTime"):
+                    self._count_wide(n_wide, batch.capacity)
                     accs_t, present, leftover, n_compacted = ufn(
                         arrays_of(batch), batch.sel,
                         np.int32(batch.num_rows), tuple(accs), present,
@@ -1477,6 +1503,7 @@ class AggregateExec(TpuExec):
         kcol = first.columns[primary.ordinal]
         key_nonnull = (isinstance(kcol, DeviceColumn)
                        and kcol.valid is None)
+        n_wide = self._wide_outputs()
 
         def run():
             from ..memory.spill import get_catalog
@@ -1524,6 +1551,7 @@ class AggregateExec(TpuExec):
                     return
                 buffered.append(catalog.register(batch, priority=1))
                 with m.time("opTime"):
+                    self._count_wide(n_wide, batch.capacity)
                     accs_t, res_t, present, leftover, n_compacted = ufn(
                         arrays_of(batch), batch.sel,
                         np.int32(batch.num_rows), tuple(accs),
@@ -1720,10 +1748,14 @@ class AggregateExec(TpuExec):
             return _cached_program(
                 f"agg-grid|{dims}|" + self._fingerprint(), build_grid)
 
+        n_wide = 0 if self.mode == "final" else self._wide_outputs()
+
         def batch_group(arrays, sel, num_rows):
             # dense-grid fast path for dictionary-coded keys (no sort, no
             # permutation gathers — see grid_group_reduce); dims re-read
             # per batch because dictionaries grow incrementally
+            self._count_wide(n_wide, next(a[0].shape[0] for a in arrays
+                                          if a is not None))
             dims = _grid_dims()
             if dims is not None:
                 return _grid_program(dims)(arrays, sel, num_rows)
@@ -1870,6 +1902,7 @@ class AggregateExec(TpuExec):
                     arrays = tuple(
                         (c.data, c.valid) if isinstance(c, DeviceColumn)
                         else None for c in batch.columns)
+                    self._count_wide(n_wide, batch.capacity)
                     ks, cs, active = pt(arrays, batch.sel,
                                         np.int32(batch.num_rows))
                     out_now.append(self._to_buffer_batch(
@@ -2190,6 +2223,7 @@ class AggregateExec(TpuExec):
 
         fin = _cached_program("agg-fin|" + self._fingerprint(), build)
         fin_vals = list(fin(arrays))
+        host = self._host_buffers(arrays, n_keys)
         cols: List = list(pending.columns[:n_keys])
         oi = 0
         bi = n_keys
@@ -2197,17 +2231,12 @@ class AggregateExec(TpuExec):
             nb = len(agg.buffers())
             if getattr(agg, "host_finalize", False):
                 # wide-decimal (etc.) results: exact host reconstruction
-                # from the device buffer limbs into an arrow column
-                import pyarrow as pa
-                n = pending.num_rows
+                # from the device buffer limbs, back on the device
                 arr = agg.finalize_host(
-                    [arrays[bi + k] for k in range(nb)], n,
+                    [host[bi + k] for k in range(nb)], pending.num_rows,
                     getattr(self, "_ansi", False))
-                if len(arr) < pending.capacity:
-                    arr = pa.concat_arrays(
-                        [arr, pa.nulls(pending.capacity - len(arr),
-                                       type=arr.type)])
-                cols.append(HostStringColumn(arr))
+                cols.append(_device_column(arr, agg.dtype,
+                                           pending.capacity))
             else:
                 d, v = fin_vals[oi]
                 oi += 1
@@ -2215,6 +2244,24 @@ class AggregateExec(TpuExec):
             bi += nb
         out = ColumnBatch(self._schema, cols, pending.num_rows, pending.sel)
         return self._decode_string_keys(out)
+
+    def _host_buffers(self, arrays, first: int,
+                      finalized: bool = False) -> Dict[int, tuple]:
+        """The buffers of the host-finalized aggregates, brought to the
+        host in ONE transfer: {ordinal in ``arrays``: (host array, None)}.
+        ``arrays`` holds from ordinal ``first`` on every aggregate's
+        buffers, or (``finalized``) one output for each other aggregate."""
+        want, bi = [], first
+        for _, agg in self.agg_exprs:
+            nb = len(agg.buffers())
+            if getattr(agg, "host_finalize", False):
+                want += range(bi, bi + nb)
+            bi += nb if getattr(agg, "host_finalize", False) \
+                or not finalized else 1
+        if not want:
+            return {}
+        got = fetch([arrays[i][0] for i in want])  # fusion-ok (finalize tail: the exact decimal results are made on the host, one batched fetch)
+        return {i: (g, None) for i, g in zip(want, got)}
 
     def _empty_cols(self):
         cols = []
@@ -2229,6 +2276,18 @@ class AggregateExec(TpuExec):
                     f.dtype, jnp.zeros((cap,), dtype=f.dtype.numpy_dtype),
                     jnp.zeros((cap,), dtype=bool)))
         return cols
+
+
+def _device_column(arr, dtype: T.DataType, capacity: int) -> DeviceColumn:
+    """An aggregate's exactly finalized decimal result (an arrow array the
+    host made) as a device column of ``capacity`` slots, so that what
+    follows the aggregate computes on it as on any other column."""
+    from ..batch import _pad_to, decimal_words
+    data, valid = decimal_words(arr, dtype.is_wide_decimal)
+    if valid is None:
+        valid = np.ones(len(arr), dtype=bool)
+    d, v = upload((_pad_to(data, capacity), _pad_to(valid, capacity)))
+    return DeviceColumn(dtype, d, v)
 
 
 @functools.lru_cache(maxsize=256)

@@ -529,8 +529,9 @@ def _plan_distinct_one_pass(child, group_exprs, cd, plain, order):
 
     Returns None where a plain leaf does not re-aggregate by expression
     (first / last, the central moments, covariance, percentiles,
-    collect_*, and the sum of a decimal, whose merge the device cannot
-    keep in level 1's type): the caller falls back to the join form."""
+    collect_*, and the sum or average of a decimal, whose merge the
+    device cannot keep in level 1's type): the caller falls back to the
+    join form."""
     import copy
 
     from .. import aggfns as A
@@ -573,8 +574,12 @@ def _plan_distinct_one_pass(child, group_exprs, cd, plain, order):
             if kind in (A.Min, A.Max):
                 return kind(partial(e))
             if kind is A.Average:
-                # Average's own accumulator: a float64 sum and a count
+                # Average's own accumulator: a float64 sum and a count.
+                # A decimal's average is a decimal made on the host from
+                # its sum's lanes: no merge expression keeps it exact
                 x = e.children[0]
+                if bind(x, schema).dtype.is_decimal:
+                    return None
                 return E.Divide(
                     A.Sum(partial(A.Sum(E.Cast(x, T.FLOAT64)))),
                     A.Sum(partial(A.Count(x))))
@@ -613,7 +618,7 @@ def _plan_count_distinct_join(df, group_exprs, cds, plain, order):
     one pass over them takes an Expand, which no plan here has yet) and
     with a plain aggregate that cannot be merged from per-group partials
     by an expression (first / last, the central moments, covariance,
-    percentiles, collect_*; the sum of a decimal).  Groupless aggregates
+    percentiles, collect_*; the sum or average of a decimal).  Groupless aggregates
     are each ONE row whatever the input holds (a count over no rows is 0,
     a sum NULL) and join via a constant key put on after they are
     computed."""

@@ -14,6 +14,7 @@ TPU-native re-design of the reference's type system:
 
 from __future__ import annotations
 
+import decimal as _decimal
 import enum
 from dataclasses import dataclass
 from typing import Iterable, Optional
@@ -205,6 +206,87 @@ _INT_DECIMAL_DIGITS = {TypeKind.INT8: 3, TypeKind.INT16: 5,
 def integral_as_decimal(a: DataType) -> DataType:
     """View an integral type as the narrowest decimal that can hold it."""
     return decimal(min(_INT_DECIMAL_DIGITS[a.kind], 18), 0)
+
+
+# -- Spark 3.x decimal result types (DecimalPrecision, with its default
+# spark.sql.decimalOperations.allowPrecisionLoss=true) ------------------------
+
+MAX_DECIMAL_PRECISION = 38
+MINIMUM_ADJUSTED_SCALE = 6
+
+
+def decimal_for_integral(a: DataType) -> DataType:
+    """DecimalType.forType: an integral COLUMN in decimal arithmetic
+    (tinyint (3,0), smallint (5,0), int (10,0), bigint (20,0))."""
+    return decimal({TypeKind.INT8: 3, TypeKind.INT16: 5, TypeKind.INT32: 10,
+                    TypeKind.INT64: 20}[a.kind], 0)
+
+
+def decimal_of_literal(v: int) -> DataType:
+    """DecimalType.fromLiteral: an integral LITERAL at its own digits
+    (``1`` is decimal(1,0))."""
+    return decimal(len(str(abs(int(v)))), 0)
+
+
+def decimal_bounded(p: int, s: int) -> DataType:
+    return decimal(min(p, MAX_DECIMAL_PRECISION),
+                   min(s, MAX_DECIMAL_PRECISION))
+
+
+def adjust_precision_scale(p: int, s: int) -> DataType:
+    """DecimalType.adjustPrecisionScale: past 38 digits the integer digits
+    are kept and the scale gives way, down to min(s, 6)."""
+    if p <= MAX_DECIMAL_PRECISION:
+        return decimal(p, s)
+    int_digits = p - s
+    return decimal(MAX_DECIMAL_PRECISION,
+                   max(MAX_DECIMAL_PRECISION - int_digits,
+                       min(s, MINIMUM_ADJUSTED_SCALE)))
+
+
+def decimal_add_type(a: DataType, b: DataType) -> DataType:
+    """``+`` / ``-``: p = max(s1, s2) + max(p1 - s1, p2 - s2) + 1."""
+    s = max(a.scale, b.scale)
+    return adjust_precision_scale(
+        s + max(a.precision - a.scale, b.precision - b.scale) + 1, s)
+
+
+def decimal_multiply_type(a: DataType, b: DataType) -> DataType:
+    """``*``: p = p1 + p2 + 1, s = s1 + s2."""
+    return adjust_precision_scale(a.precision + b.precision + 1,
+                                  a.scale + b.scale)
+
+
+def decimal_divide_type(a: DataType, b: DataType) -> DataType:
+    """``/``: s = max(6, s1 + p2 + 1), p = p1 - s1 + s2 + s."""
+    s = max(MINIMUM_ADJUSTED_SCALE, a.scale + b.precision + 1)
+    return adjust_precision_scale(a.precision - a.scale + b.scale + s, s)
+
+
+def decimal_sum_type(a: DataType) -> DataType:
+    return decimal_bounded(a.precision + 10, a.scale)
+
+
+def decimal_avg_type(a: DataType) -> DataType:
+    return decimal_bounded(a.precision + 4, a.scale + 4)
+
+
+# python's decimal rounds to its context's 28 digits: wide values go
+# through a context that holds them whole
+_EXACT = _decimal.Context(prec=100)
+
+
+def decimal_value(unscaled: int, scale: int) -> _decimal.Decimal:
+    """The Decimal of ``unscaled`` units of 10^-scale, exactly, with that
+    exponent (``Decimal(x).scaleb(-s)`` rounds past 28 digits)."""
+    return _decimal.Decimal(int(unscaled)).scaleb(-scale, context=_EXACT)
+
+
+def unscaled_value(v: _decimal.Decimal, scale: int) -> int:
+    """A Decimal as a count of 10^-scale units (rounded half-even where it
+    holds finer digits), exactly."""
+    return int(v.scaleb(scale, context=_EXACT).to_integral_value(
+        context=_EXACT))
 
 
 def common_type(a: DataType, b: DataType) -> DataType:

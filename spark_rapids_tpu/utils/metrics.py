@@ -84,6 +84,14 @@ class QueryStats:
         # seconds inside ``scan:decode`` spans (io/), summed over the
         # threads that decode: can pass the query's wall
         self.decode_s = 0.0
+        # seconds inside ``scan:decimal`` spans (batch.decimal_scaled: a
+        # decimal column's scaled values read from its arrow buffer),
+        # summed over the threads that convert, like decode_s
+        self.decimal_decode_s = 0.0
+        # slots (batch capacity x outputs) of 128-bit decimal values the
+        # device computes for expressions (exprs.wide_decimal_outputs),
+        # counted on the host where each batch's program is dispatched
+        self.wide_decimal_slots = 0
         # mesh fragments (parallel/spmd.py, shuffle.mode=ICI): fragments
         # run, the seconds of their four phases (``ici:materialize`` /
         # ``ici:feed`` / ``ici:step`` / ``ici:gather`` spans, on the

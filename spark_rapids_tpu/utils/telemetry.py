@@ -172,6 +172,14 @@ METRICS = (
     ("query_decode_seconds_total", "counter", "",
      "Seconds inside scan:decode spans, summed over the threads that "
      "decode."),
+    ("query_decimal_decode_seconds_total", "counter", "",
+     "Seconds inside scan:decimal spans (a decimal column's scaled "
+     "values read from its arrow buffer), summed over the threads that "
+     "convert."),
+    ("query_wide_decimal_slots_total", "counter", "",
+     "Slots (batch capacity x outputs) of 128-bit decimal values the "
+     "device computed for expressions, counted where each batch's "
+     "program is dispatched."),
     # mesh fragments (parallel/spmd.py, shuffle.mode=ICI)
     ("query_ici_fragments_total", "counter", "",
      "Plan fragments run on the ICI mesh by finished queries."),
@@ -439,6 +447,8 @@ _QS_FOLD = (
     ("upload_bytes", "query_upload_bytes_total"),
     ("upload_s", "query_upload_seconds_total"),
     ("decode_s", "query_decode_seconds_total"),
+    ("decimal_decode_s", "query_decimal_decode_seconds_total"),
+    ("wide_decimal_slots", "query_wide_decimal_slots_total"),
     ("ici_fragments", "query_ici_fragments_total"),
     ("ici_materialize_s", "query_ici_materialize_seconds_total"),
     ("ici_feed_s", "query_ici_feed_seconds_total"),

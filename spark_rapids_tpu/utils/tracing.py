@@ -124,6 +124,10 @@ SPANS = (
     ("scan:decode", "host_exec",
      "io/parquet.py, io/sources.py: one decoded table (QueryStats."
      "decode_s sums it over threads)."),
+    ("scan:decimal", "host_exec",
+     "batch.py decimal_scaled: one decimal column's scaled values read "
+     "from its arrow buffer, inside op:scanTime: the convert term on a "
+     "producer (QueryStats.decimal_decode_s sums it over threads)."),
     ("scan:wait", "h2d_wait",
      "io/parquet.py, io/sources.py: the scan blocked on its prefetch "
      "thread's next decoded table."),
@@ -234,7 +238,7 @@ RESOLVED_TERMS = ("decode", "convert", "upload", "dispatch", "fetch_wait",
 _WAIT = len(RESOLVED_TERMS)  # a producer's own wait: resolved upstream
 
 _SLOT_OF = {"scan:decode": _DECODE, "op:scanTime": _CONVERT,
-            "scan:upload": _UPLOAD}
+            "scan:decimal": _CONVERT, "scan:upload": _UPLOAD}
 _SLOT_OF_TERM = {"dispatch": _DISPATCH, "fetch_wait": _FETCH_WAIT}
 
 

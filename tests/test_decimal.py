@@ -52,7 +52,10 @@ class TestDecimalPromotion:
             "b": pa.array([Decimal("0.25")], type=pa.decimal128(5, 2)),
         })
         df = session.create_dataframe(t)
-        out = df.select((F.col("a") + F.col("b")).alias("s")).to_pandas()
+        q = df.select((F.col("a") + F.col("b")).alias("s"))
+        # Spark: max(s1, s2) + max(p1 - s1, p2 - s2) + 1 digits
+        assert str(q.schema.fields[0].dtype) == "decimal(7,2)"
+        out = q.to_pandas()
         assert out["s"][0] == Decimal("1.75")
 
     def test_mixed_scale_compare(self, session):
@@ -69,13 +72,19 @@ class TestDecimalPromotion:
 
     def test_decimal_plus_int_column(self, session):
         df = _df(session, ["1.00", "2.00", "3.00"])
-        out = df.select((F.col("d") + F.col("i")).alias("s")).to_pandas()
+        q = df.select((F.col("d") + F.col("i")).alias("s"))
+        # an int column is decimal(10,0) in decimal arithmetic
+        assert str(q.schema.fields[0].dtype) == "decimal(13,2)"
+        out = q.to_pandas()
         assert list(out["s"]) == [Decimal("1.00"), Decimal("3.00"),
                                   Decimal("5.00")]
 
     def test_decimal_mul_int(self, session):
         df = _df(session, ["1.25", "2.00"])
-        out = df.select((F.col("d") * 4).alias("m")).to_pandas()
+        q = df.select((F.col("d") * 4).alias("m"))
+        # the literal 4 is decimal(1,0): p1 + p2 + 1 digits, s1 + s2
+        assert str(q.schema.fields[0].dtype) == "decimal(7,2)"
+        out = q.to_pandas()
         assert list(out["m"]) == [Decimal("5.00"), Decimal("8.00")]
 
     def test_decimal_compare_float(self, session):
@@ -90,7 +99,9 @@ class TestDecimalPromotion:
 
     def test_null_propagation(self, session):
         df = _df(session, ["1.00", None])
-        out = df.select((F.col("d") + 1).alias("s")).to_pandas()
+        q = df.select((F.col("d") + 1).alias("s"))
+        assert str(q.schema.fields[0].dtype) == "decimal(6,2)"
+        out = q.to_pandas()
         assert out["s"][0] == Decimal("2.00")
         assert out["s"][1] is None
 

@@ -61,6 +61,21 @@ def _avg(col):
     return of
 
 
+def _avg_decimal(col):
+    """Spark's avg of a decimal(7,2): the sum over the count at Divide's
+    decimal(38,23), then the result's decimal(11,6), HALF_UP each time."""
+    ctx = decimal.Context(prec=60, rounding=decimal.ROUND_HALF_UP)
+
+    def of(g):
+        vals = [v for v in g[col] if v is not None]
+        if not vals:
+            return None
+        q = ctx.divide(sum(vals, decimal.Decimal(0)), len(vals))
+        return q.quantize(decimal.Decimal("1E-23"), context=ctx).quantize(
+            decimal.Decimal("1E-6"), context=ctx)
+    return of
+
+
 def _least(col, fn):
     def of(g):
         vals = [v for v in g[col] if v is not None and v == v]
@@ -88,8 +103,7 @@ PLAIN = {
                 ("b", F.max(F.col("i")), _least("i", max)),
                 ("c", F.min(F.col("d")), _least("d", min))],
     "avg": [("a", F.avg(F.col("x")), _avg("x")),
-            ("b", F.avg(F.col("i")), _avg("i")),
-            ("c", F.avg(F.col("d")), _avg("d"))],
+            ("b", F.avg(F.col("i")), _avg("i"))],
     "compound": [("a", F.sum(F.col("w")) / F.sum(F.col("i")),
                   _ratio("w", "i"))],
     "same_leaf_twice": [("a", F.sum(F.col("w")), _sum("w")),
@@ -271,6 +285,12 @@ FALLBACKS = {
          F.sum(F.col("d")).alias("b"), F.min(F.col("d")).alias("c")],
         lambda g: (g.v.nunique(), sum(v for v in g.d if v is not None),
                    min(v for v in g.d if v is not None))),
+    # the average of a decimal is a decimal (Spark's Average), made on the
+    # host from the sum's lanes: no merge of level-1 partials keeps it
+    "avg_decimal": (
+        [F.count_distinct(F.col("v")).alias("a"),
+         F.avg(F.col("d")).alias("b")],
+        lambda g: (g.v.nunique(), _avg_decimal("d")(g))),
     "sum_beside_stddev": (
         [F.sum(F.col("w")).alias("a"),
          F.count_distinct(F.col("v")).alias("b"),

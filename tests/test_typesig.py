@@ -25,6 +25,11 @@ class TestSigAlgebra:
         assert "not supported" in sig.check(T.STRING)
         assert "decimal" in TypeSig.device_compute.check(T.decimal(38, 2))
         assert "int" in sig.describe() and "double" in sig.describe()
+        # + - * and avg produce Spark's decimal types up to precision 38
+        from spark_rapids_tpu import aggfns, exprs
+        for cls in (exprs.Add, exprs.Subtract, exprs.Multiply,
+                    aggfns.Average):
+            assert cls.output_sig.check(T.decimal(38, 6)) is None, cls
 
     def test_add_subtract(self):
         s = TypeSig.common - TypeSig.string
