@@ -243,7 +243,6 @@ class CpuOpExec(TpuExec):
         return table.filter(pa.array(keep))
 
     def _run_aggregate(self, ctx, p: L.Aggregate):
-        import pandas as pd
         from .. import aggfns as A
         from ..plan.planner import strip_alias
         in_schema = self.children[0].output_schema
@@ -265,7 +264,24 @@ class CpuOpExec(TpuExec):
         if not key_vals:
             outs = [self._agg_scalar(b, cv, n) for _, b, cv in agg_specs]
             return values_to_arrow(p.schema(), outs, 1)
+        if not p.rollup:
+            return self._group_rows(p, key_vals, agg_specs)
+        # a grouping-set chain: each coarser set groups the rows again
+        # with the keys it drops NULL and its own grouping id
+        import pyarrow as pa
+        tables = [self._group_rows(p, key_vals, agg_specs)]
+        gid_name, gid_b, _ = key_vals[-1]
+        for kept, number in p.rollup:
+            level = [(name, b, (d, v) if i in kept
+                      else (d, np.zeros(n, dtype=bool)))
+                     for i, (name, b, (d, v)) in enumerate(key_vals[:-1])]
+            level.append((gid_name, gid_b,
+                          (np.full(n, number, dtype=np.int64), None)))
+            tables.append(self._group_rows(p, level, agg_specs))
+        return pa.concat_tables(tables)
 
+    def _group_rows(self, p: L.Aggregate, key_vals, agg_specs):
+        import pandas as pd
         # pandas group-by with nulls as a group (dropna=False)
         df = {}
         for name, b, (d, v) in key_vals:

@@ -54,10 +54,13 @@ _EXEC_ROWS: List[Tuple[str, List[str], str, str]] = [
     ("Union / Distinct / Range",
      ["plan.exec_nodes.UnionExec", "plan.exec_nodes.RangeExec"], "TPU", ""),
     ("Expand (rollup / cube, grouping, grouping_id)",
-     ["plan.exec_nodes.ExpandExec"],
-     "TPU", "one projection a grouping set under the ordinary aggregate; "
-     "string keys pass through or are NULL-ed over their dictionary; "
-     "computed string keys fall back"),
+     ["plan.exec_nodes.ExpandExec", "plan.physical.AggregateExec"],
+     "TPU", "a rollup whose aggregates re-aggregate (sum, count, min, max, "
+     "avg) takes no Expand: the aggregate reduces the finest set and each "
+     "coarser set from the one before it; otherwise one projection a "
+     "grouping set under the ordinary aggregate; string keys pass through "
+     "or are NULL-ed over their dictionary; computed string keys fall "
+     "back"),
     ("Exchange (hash/single/broadcast)",
      ["plan.exchange_exec.ShuffleExchangeExec",
       "plan.join_exec.BroadcastExchangeExec"],

@@ -500,7 +500,8 @@ def _lower(node, leaves: List[tuple], conf, n_dev: int, axis: str,
     each added to ``leaves`` as ``(leaf, subtree)``.
 
     Raises NotLowerable only for conditions that poison the whole fragment
-    (a schema no device representation exists for)."""
+    (a schema no device representation exists for, a grouping-set
+    chain's final aggregate)."""
     from ..plan.coalesce import CoalesceBatchesExec
     from ..plan.exchange_exec import ShuffleExchangeExec
     from ..plan.fusion import FusedRegionExec
@@ -537,6 +538,10 @@ def _lower(node, leaves: List[tuple], conf, n_dev: int, axis: str,
     if isinstance(node, AggregateExec):
         if node.mode not in ("partial", "final") or not node.group_exprs:
             return _make_leaf(node, leaves)
+        if node.rollup:
+            # a chain's coarser sets read the finest set's groups of every
+            # shard: the fragment ends below it and it runs single-process
+            raise NotLowerable("a grouping-set chain's final aggregate")
         child = _lower(node.children[0], leaves, conf, n_dev, axis,
                        depth_has_exchange, cap_scale)
         return _Aggregate(node, child)

@@ -85,7 +85,9 @@ class Aggregate(LogicalPlan):
     def __init__(self, child: LogicalPlan,
                  group_exprs: List[Tuple[str, Expression]],
                  agg_exprs: List[Tuple[str, Expression]],
-                 distinct_one_pass: bool = False):
+                 distinct_one_pass: bool = False,
+                 rollup: Optional[Tuple[Tuple[Tuple[int, ...], int], ...]]
+                 = None):
         self.children = (child,)
         self.group_exprs = group_exprs
         self.agg_exprs = agg_exprs  # each contains an AggregateExpression tree
@@ -93,13 +95,20 @@ class Aggregate(LogicalPlan):
         # aggregates over one copy of the child (sql/dataframe.py
         # _plan_distinct_one_pass); counted where the plan is converted
         self.distinct_one_pass = distinct_one_pass
+        # a grouping-set chain (sql/dataframe.py _plan_grouping_sets): the
+        # group keys are the finest set's, the grouping id last, and each
+        # (kept key indices, grouping id) after it is a coarser set whose
+        # groups are reduced from the groups of the set before it
+        self.rollup = rollup
 
     def schema(self) -> Schema:
         in_schema = self.children[0].schema()
         fields = []
-        for name, e in self.group_exprs:
+        for i, (name, e) in enumerate(self.group_exprs):
             b = bind(e, in_schema)
-            fields.append(Field(name, b.dtype, b.nullable))
+            # a coarser set of a chain puts NULL in the keys it drops
+            fields.append(Field(name, b.dtype, b.nullable or (
+                self.rollup is not None and i < len(self.group_exprs) - 1)))
         for name, e in self.agg_exprs:
             b = bind(e, in_schema)
             fields.append(Field(name, b.dtype, b.nullable))
@@ -108,7 +117,9 @@ class Aggregate(LogicalPlan):
     def node_desc(self):
         return (f"Aggregate keys=[{', '.join(n for n, _ in self.group_exprs)}] "
                 f"aggs=[{', '.join(n for n, _ in self.agg_exprs)}]"
-                + (" distinct_one_pass" if self.distinct_one_pass else ""))
+                + (" distinct_one_pass" if self.distinct_one_pass else "")
+                + (f" rollup_levels={len(self.rollup)}" if self.rollup
+                   else ""))
 
 
 class SortOrder:

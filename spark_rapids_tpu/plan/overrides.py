@@ -341,14 +341,15 @@ class NodeMeta:
 # ---------------------------------------------------------------------------------
 
 def _plan_aggregate(child_phys: TpuExec, group_bound, agg_bound,
-                    conf: TpuConf) -> TpuExec:
+                    conf: TpuConf, rollup=None) -> TpuExec:
     """Grouped aggregation as partial → shuffle exchange → final, the
     reference's two-phase shape (GpuHashAggregateExec partial/final around
     GpuShuffleExchangeExec); ungrouped aggregates reduce to one scalar and
-    need no exchange."""
+    need no exchange.  A grouping-set chain's coarser sets (``rollup``)
+    are reduced where the finest set's groups are final."""
     if not group_bound or not conf["spark.rapids.tpu.sql.exchange.enabled"]:
         return AggregateExec(child_phys, group_bound, agg_bound,
-                             mode="complete")
+                             mode="complete", rollup=rollup)
     if conf["spark.rapids.tpu.shuffle.mode"] == "CACHE_ONLY" \
             and conf["spark.rapids.tpu.sql.agg.singleProcessComplete"]:
         # single-process: the partial -> exchange -> final shape exists to
@@ -357,7 +358,7 @@ def _plan_aggregate(child_phys: TpuExec, group_bound, agg_bound,
         # partial-agg sampling + exchange staging).  ICI/HOST modes keep
         # the two-phase shape — their exchanges do real distribution.
         return AggregateExec(child_phys, group_bound, agg_bound,
-                             mode="complete")
+                             mode="complete", rollup=rollup)
     from .exchange_exec import ShuffleExchangeExec
     # string keys: partial and final share one dictionary registry so codes
     # stay comparable across the exchange (ops/strings.py)
@@ -376,7 +377,7 @@ def _plan_aggregate(child_phys: TpuExec, group_bound, agg_bound,
     final_keys = [(n, BoundReference(i, e.dtype, e.nullable, n))
                   for i, (n, e) in enumerate(group_bound)]
     return AggregateExec(exchange, final_keys, agg_bound, mode="final",
-                         string_dicts=shared_dicts)
+                         string_dicts=shared_dicts, rollup=rollup)
 
 
 def _cpu_node(plan: L.LogicalPlan, children: List[TpuExec]) -> TpuExec:
@@ -432,7 +433,8 @@ def _convert(meta: NodeMeta, conf: TpuConf) -> TpuExec:
         if p.distinct_one_pass:
             from ..utils.metrics import QueryStats
             QueryStats.get().distinct_one_pass_aggs += 1
-        return _plan_aggregate(child_phys, group_bound, agg_bound, conf)
+        return _plan_aggregate(child_phys, group_bound, agg_bound, conf,
+                               p.rollup)
 
     if isinstance(p, L.Distinct):
         child_phys = _convert(meta.children[0], conf)

@@ -741,16 +741,23 @@ class AggregateExec(TpuExec):
 
     def __init__(self, child: TpuExec, group_exprs: List[Tuple[str, Expression]],
                  agg_exprs: List[Tuple[str, AggregateExpression]],
-                 mode: str = "complete", string_dicts: Optional[dict] = None):
+                 mode: str = "complete", string_dicts: Optional[dict] = None,
+                 rollup=None):
         super().__init__([child])
         self.group_exprs = group_exprs
         self.agg_exprs = agg_exprs
         self.mode = mode
+        # a grouping-set chain's coarser sets, (kept key indices, grouping
+        # id) each: the group keys are the finest set's with the grouping
+        # id last (plan/logical.Aggregate.rollup; _rollup_levels)
+        self.rollup = rollup
         # group-index → StringDictionary for string-typed keys (shared with
         # the partner partial/final exec so codes stay comparable across the
         # exchange; see ops/strings.py)
         self.string_dicts = string_dicts if string_dicts is not None else {}
-        out_fields = [Field(n, e.dtype, e.nullable) for n, e in group_exprs]
+        n_set_keys = len(group_exprs) - 1 if rollup else 0
+        out_fields = [Field(n, e.dtype, e.nullable or i < n_set_keys)
+                      for i, (n, e) in enumerate(group_exprs)]
         if mode == "partial":
             for name, agg in agg_exprs:
                 for bi, (dt, op) in enumerate(agg.buffers()):
@@ -766,7 +773,9 @@ class AggregateExec(TpuExec):
     def node_desc(self):
         keys = [n for n, _ in self.group_exprs]
         aggs = [f"{a.func}({n})" for n, a in self.agg_exprs]
-        return f"TpuHashAggregate [{self.mode}] keys={keys} aggs={aggs}"
+        return (f"TpuHashAggregate [{self.mode}] keys={keys} aggs={aggs}"
+                + (f" rollup_levels={len(self.rollup)}" if self.rollup
+                   else ""))
 
     def child_coalesce_goal(self, i, conf):
         # grouped modes: bigger input batches -> fewer reduce/merge passes.
@@ -1762,7 +1771,8 @@ class AggregateExec(TpuExec):
             return sort_batch_group(arrays, sel, num_rows)
 
         buffer_schema = self._buffer_schema()
-        if self.mode == "final" and child.outputs_partitions:
+        if self.mode == "final" and child.outputs_partitions \
+                and not self.rollup:
             # a shuffle guarantees each group is confined to one partition
             # batch: finalize per batch, no cross-batch merge (streaming)
             from ..runtime.pipeline import (effective_depth,
@@ -1872,7 +1882,8 @@ class AggregateExec(TpuExec):
         # same memory, and tripping the fallback needlessly costs
         # per-bucket merge passes (TPC-H Q21's 5.8M-group dedups were
         # the measured victim); a wide buffer conversely trips EARLIER
-        # than the row cap would.
+        # than the row cap would.  A chain's coarser sets read every group
+        # of the finest set, so its groups are never split.
         width = 0
         for f_ in buffer_schema:
             if f_.dtype.is_string:
@@ -1881,8 +1892,9 @@ class AggregateExec(TpuExec):
                 width += 64
             else:
                 width += np.dtype(f_.dtype.numpy_dtype).itemsize
-        limit = max(1, ctx.conf["spark.rapids.tpu.sql.batchSizeBytes"]
-                    // max(width, 1))
+        limit = None if self.rollup else max(
+            1, ctx.conf["spark.rapids.tpu.sql.batchSizeBytes"]
+            // max(width, 1))
         buckets = None
         bucket_over = None  # single OR-accumulated device overflow flag
         held = _HeldPartials(self, ops, n_keys, limit)
@@ -1920,7 +1932,8 @@ class AggregateExec(TpuExec):
                                     else (bucket_over | flag)
                             continue
                         held.add(part, bound=gb)
-                        if gb is None and held.rows > limit:
+                        if gb is None and limit is not None \
+                                and held.rows > limit:
                             # add() merged before its rows could pass
                             # the limit: the count is the merged one
                             pending = held.take()
@@ -2199,7 +2212,48 @@ class AggregateExec(TpuExec):
         return batch_utils.compact_packed(
             self._concat_merge(parts, ops, n_keys), bound=bound)
 
+    def _rollup_levels(self, finest: ColumnBatch) -> ColumnBatch:
+        """The groups of every set of a chain laid end to end: the finest
+        set's as the aggregate made them, then each coarser set's, reduced
+        by ``agg_merge_grouped`` from the groups of the set before it with
+        the keys it drops NULL and its grouping id.  Each level is
+        compacted to the rung over its groups (a fetch of their count),
+        so it costs the groups below it and not the child's rows.  No
+        level is merged with another: their grouping ids differ.
+
+        Every key enters a level's merge with a validity mask and the
+        grouping id without one, so the levels that share a rung share
+        one program."""
+        n_keys = len(self.group_exprs)
+        merge = _merge_fn(tuple(self._buffer_ops()), n_keys)
+        stats = QueryStats.get()
+        levels = [finest]
+        prev = finest
+        for kept, number in self.rollup:
+            cols = list(prev.columns)
+            like = cols[n_keys - 1].data     # the grouping id: (capacity,)
+            for i in range(n_keys - 1):
+                c = cols[i]
+                if i not in kept:
+                    cols[i] = DeviceColumn(
+                        c.dtype, jnp.zeros_like(c.data),
+                        jnp.zeros_like(like, dtype=bool))
+                elif c.valid is None:
+                    cols[i] = DeviceColumn(c.dtype, c.data,
+                                           jnp.ones_like(like, dtype=bool))
+            cols[n_keys - 1] = DeviceColumn(
+                cols[n_keys - 1].dtype, jnp.full_like(like, number), None)
+            ok, ov, gmask = merge(tuple((c.data, c.valid) for c in cols),
+                                  prev.sel, np.int32(prev.num_rows))
+            prev = batch_utils.compact_packed(self._to_buffer_batch(
+                prev.schema, list(ok), list(ov), gmask))
+            stats.rollup_levels_merged += 1
+            levels.append(prev)
+        return batch_utils.concat_packed(levels)
+
     def _finalize_grouped(self, pending: ColumnBatch) -> ColumnBatch:
+        if self.rollup:
+            pending = self._rollup_levels(pending)
         n_keys = len(self.group_exprs)
         arrays = tuple((c.data, c.valid) for c in pending.columns)
         agg_exprs = self.agg_exprs  # don't capture self in the cached fn

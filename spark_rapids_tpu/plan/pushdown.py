@@ -170,7 +170,7 @@ def _walk_impl(node: L.LogicalPlan, required: Optional[Set[str]],
             _refs(e for _, e in node.agg_exprs)
         child = _walk(node.children[0], child_req, [])
         return L.Aggregate(child, node.group_exprs, node.agg_exprs,
-                           node.distinct_one_pass)
+                           node.distinct_one_pass, node.rollup)
 
     if isinstance(node, L.Sort):
         child_req = None if required is None else \

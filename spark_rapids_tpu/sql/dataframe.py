@@ -194,9 +194,12 @@ class DataFrame:
 
     def rollup(self, *cols: Union[str, Column]) -> "GroupedData":
         """GROUP BY ROLLUP(cols): the n+1 grouping sets cols[:n], ...,
-        cols[:1], () in one pass.  ``agg`` lowers them to an Expand under
-        the ordinary aggregate; ``functions.grouping`` / ``grouping_id``
-        tell a NULL the set put there from one in the data."""
+        cols[:1], () in one pass.  ``agg`` reduces the child's rows at the
+        finest set and each coarser set from the one before it (an Expand
+        under the ordinary aggregate where an aggregate does not
+        re-aggregate from its partials); ``functions.grouping`` /
+        ``grouping_id`` tell a NULL the set put there from one in the
+        data."""
         n = len(cols)
         return GroupedData(self, [_named(c) for c in cols],
                            [tuple(range(k)) for k in range(n, -1, -1)])
@@ -728,6 +731,29 @@ class PivotedData:
 GROUPING_ID = "spark_grouping_id"
 
 
+def _is_chain(sets, n: int) -> bool:
+    """The first set holds every key and each set after it is the one
+    before it less some keys, as ROLLUP's sets are."""
+    return (set(sets[0]) == set(range(n))
+            and all(set(b) < set(a) for a, b in zip(sets, sets[1:])))
+
+
+def _reaggregates(agg_exprs) -> bool:
+    """Every aggregate's result over a group is the same aggregate over
+    the partial results of any split of the group's rows: sum, count,
+    min, max, and avg as a sum and a count."""
+    from .. import aggfns as A
+    from ..exprs import AggregateExpression
+
+    def ok(e) -> bool:
+        if isinstance(e, AggregateExpression):
+            return type(e) in (A.Sum, A.Count, A.CountStar, A.Min, A.Max,
+                               A.Average)
+        return all(ok(c) for c in e.children)
+
+    return all(ok(e) for _, e in agg_exprs)
+
+
 def _plan_grouping_sets(child: L.LogicalPlan, group_exprs, sets, agg_exprs
                         ) -> L.LogicalPlan:
     """ROLLUP / CUBE as Spark plans them: an Expand with one projection a
@@ -736,7 +762,13 @@ def _plan_grouping_sets(child: L.LogicalPlan, group_exprs, sets, agg_exprs
     set's number as ``spark_grouping_id``), the ordinary Aggregate over
     keys + grouping id (so a NULL key in the data and a key the set
     null-ed stay different groups), and a Project that drops the id.
-    ``grouping(col)`` / ``grouping_id()`` become bits of the id."""
+    ``grouping(col)`` / ``grouping_id()`` become bits of the id.
+
+    Sets that form a chain (ROLLUP's) under aggregates that re-aggregate
+    from their partials take no Expand: the Aggregate reduces the child's
+    rows once, at the finest set with grouping id 0, and carries the
+    coarser sets as ``rollup``, each reduced from the groups of the set
+    before it (``plan/physical.AggregateExec._rollup_levels``)."""
     from .. import bitwisefns as B
     from .. import types as T
     from ..exprs import bind
@@ -767,22 +799,30 @@ def _plan_grouping_sets(child: L.LogicalPlan, group_exprs, sets, agg_exprs
         node.children = tuple(rewrite(c) for c in e.children)
         return node
 
+    def number(members) -> int:
+        return sum(1 << (n - 1 - i) for i in range(n) if i not in members)
+
     agg_exprs = [(name, rewrite(e)) for name, e in agg_exprs]
-    reads = sorted(set().union(*[e.references() for _, e in agg_exprs])
-                   - {GROUPING_ID})
-    null_of = [E.Literal(None, bind(e, schema).dtype) for _, e in group_exprs]
-    projections = []
-    for members in sets:
-        number = sum(1 << (n - 1 - i) for i in range(n) if i not in members)
-        projections.append(
+    if _is_chain(sets, n) and _reaggregates(agg_exprs):
+        keys = list(group_exprs) + [(GROUPING_ID, E.Literal(0, T.INT64))]
+        node = _decompose_agg_exprs(child, keys, agg_exprs)
+        agg = node if isinstance(node, L.Aggregate) else node.children[0]
+        agg.rollup = tuple((tuple(m), number(m)) for m in sets[1:])
+    else:
+        reads = sorted(set().union(*[e.references() for _, e in agg_exprs])
+                       - {GROUPING_ID})
+        null_of = [E.Literal(None, bind(e, schema).dtype)
+                   for _, e in group_exprs]
+        projections = [
             [(r, E.UnresolvedColumn(r)) for r in reads]
             + [(f"__gk{i}", e if i in members else null_of[i])
                for i, (_, e) in enumerate(group_exprs)]
-            + [(GROUPING_ID, E.Literal(number, T.INT64))])
-    keys = [(name, E.UnresolvedColumn(f"__gk{i}"))
-            for i, (name, _) in enumerate(group_exprs)] + [(GROUPING_ID, gid)]
-    node = _decompose_agg_exprs(L.Expand(child, projections), keys,
-                                agg_exprs)
+            + [(GROUPING_ID, E.Literal(number(members), T.INT64))]
+            for members in sets]
+        keys = [(name, E.UnresolvedColumn(f"__gk{i}"))
+                for i, (name, _) in enumerate(group_exprs)]
+        node = _decompose_agg_exprs(L.Expand(child, projections),
+                                    keys + [(GROUPING_ID, gid)], agg_exprs)
     return L.Project(node, [(name, E.UnresolvedColumn(name))
                             for name, _ in group_exprs + agg_exprs])
 

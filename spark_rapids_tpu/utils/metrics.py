@@ -150,6 +150,10 @@ class QueryStats:
         self.window_rows = 0
         self.expand_exec_s = 0.0
         self.expand_slot_rows = 0
+        # a grouping-set chain's coarser sets (plan/physical.py
+        # AggregateExec._rollup_levels): one a set reduced from the groups
+        # of the set one key finer, where no Expand copied the child
+        self.rollup_levels_merged = 0
         self.cpu_fallback_nodes = 0
         # the joins (plan/join_exec.py): seconds inside ``join:pair``
         # spans (one probe batch against its build side; whole-span

@@ -218,8 +218,8 @@ METRICS = (
      "concatenated and reduced again."),
     ("query_agg_merge_parts_total", "counter", "",
      "Partial results that entered those merges: over "
-     "query_agg_merges_total, 2.0 is one merge a part, 9.0 a nine-set "
-     "rollup merged once."),
+     "query_agg_merges_total, 2.0 is one merge a part, 8.0 an eight-set "
+     "cube merged once."),
     ("query_distinct_one_pass_aggs_total", "counter", "",
      "count(DISTINCT) aggregates planned as two stacked aggregates over "
      "one copy of their child (none where the join form ran the child "
@@ -240,6 +240,9 @@ METRICS = (
     ("query_expand_slot_rows_total", "counter", "",
      "Slots grouping-set expansions handed on (capacity x projections "
      "x batches, live or not)."),
+    ("query_rollup_levels_merged_total", "counter", "",
+     "Grouping sets of a chain (ROLLUP) reduced from the groups of the "
+     "set one key finer by agg_merge_grouped, with no Expand."),
     ("query_cpu_fallback_nodes_total", "counter", "",
      "Plan nodes placed on the CPU (CpuOpExec) in plans that were run."),
     # the joins (plan/join_exec.py)
@@ -462,6 +465,7 @@ _QS_FOLD = (
     ("window_rows", "query_window_rows_total"),
     ("expand_exec_s", "query_expand_exec_seconds_total"),
     ("expand_slot_rows", "query_expand_slot_rows_total"),
+    ("rollup_levels_merged", "query_rollup_levels_merged_total"),
     ("cpu_fallback_nodes", "query_cpu_fallback_nodes_total"),
     ("join_exec_s", "query_join_exec_seconds_total"),
     ("join_pairs", "query_join_pairs_total"),

@@ -80,15 +80,15 @@ def _sparse(values):
     return (np.asarray(values).astype(np.int64) << 40)
 
 
-# -- a rollup over one batch: one merge, where there was one a set -------------
+# -- a rollup over one batch: one merge a coarser set, none across sets -------
 
 @pytest.mark.parametrize("n_keys", [3, 8])
 def test_a_rollup_over_one_batch_merges_once(fresh_session, monkeypatch,
                                              n_keys):
-    """Expand hands the aggregate one projection a grouping set.  The
-    sets that follow the first (every key) bring fewer rows than it
-    holds, so nothing is merged before the stream's end merges all of
-    them at once."""
+    """The aggregate reduces the one batch at the finest set (every key)
+    and holds that one partial, so no partials are merged; each coarser
+    set is then ONE merge of the groups of the set one key finer
+    (``rollup_levels_merged``), and no set is merged with another."""
     f = F()
     sess = fresh_session
     watch = _Watch(monkeypatch)
@@ -101,18 +101,15 @@ def test_a_rollup_over_one_batch_merges_once(fresh_session, monkeypatch,
     cols["v"] = np.round(rng.uniform(0, 100, n), 2)
     t = pa.table(cols)
     keys = list(cols)[:-1]
-    before = QueryStats.process().agg_merges
+    before = QueryStats.process().rollup_levels_merged
     out = sess.create_dataframe(t).rollup(*keys).agg(
         f.sum(f.col("v")).alias("v"), f.grouping_id().alias("gid"))
     tbl, _m, st = _collect(sess, out)
-    sets = n_keys + 1
-    assert (st.agg_merges, st.agg_merge_parts) == (1, sets)
-    assert [len(m) for m in watch.merges] == [sets]
-    # the full set first, at its batch's capacity; the rest compacted
-    assert watch.merges[0][0] == (n, 2048)
-    assert all(cap == 1024 for _r, cap in watch.merges[0][1:])
+    assert (st.agg_merges, st.agg_merge_parts) == (0, 0)
+    assert watch.merges == [] and watch.taken == [n]
+    assert st.rollup_levels_merged == n_keys and st.expand_slot_rows == 0
     # folded outward like every other counter
-    assert QueryStats.process().agg_merges == before + 1
+    assert QueryStats.process().rollup_levels_merged == before + n_keys
     pdf = t.to_pandas()
     parts = []
     for level in range(n_keys, -1, -1):
@@ -132,7 +129,9 @@ def test_two_large_sets_that_fill_the_batch_still_merge_once(fresh_session,
                                                              monkeypatch):
     """The first two sets compact to half their batch's slots each and
     fill it together, but the second holds fewer rows than the first: a
-    merge there would reduce the first set's rows twice for nothing."""
+    merge there would reduce the first set's rows twice for nothing.  A
+    variance is not re-aggregated from its partials, so this rollup keeps
+    Expand: one projection a set, each a partial held."""
     f = F()
     sess = fresh_session
     watch = _Watch(monkeypatch)
@@ -144,8 +143,10 @@ def test_two_large_sets_that_fill_the_batch_still_merge_once(fresh_session,
                   "v": rng.integers(0, 100, n).astype(np.int64)})
     keys = ["a", "b", "w", "u"]
     out = sess.create_dataframe(t).rollup(*keys).agg(
-        f.sum(f.col("v")).alias("v"), f.grouping_id().alias("gid"))
+        f.sum(f.col("v")).alias("v"), f.grouping_id().alias("gid"),
+        f.variance(f.col("v")).alias("var_v"))
     tbl, _m, st = _collect(sess, out)
+    assert st.rollup_levels_merged == 0 and st.expand_slot_rows > 0
     pdf = t.to_pandas()
     groups = [len(pdf.groupby(keys[:i])) if i else 1 for i in (4, 3, 2, 1, 0)]
     assert 512 < groups[1] < groups[0] < 1024

@@ -79,19 +79,22 @@ def _grouping_sets_pandas(pdf, keys, sets):
     return pd.concat(parts, ignore_index=True)
 
 
-@pytest.mark.parametrize("how,sets", [
-    ("rollup", [(0, 1, 2), (0, 1), (0,), ()]),
-    ("cube", [(0, 1, 2), (0, 1), (0, 2), (0,), (1, 2), (1,), (2,), ()]),
+@pytest.mark.parametrize("how,sets,lowered", [
+    # a chain: the coarser sets reduced from the finest, no Expand
+    ("rollup", [(0, 1, 2), (0, 1), (0,), ()], "rollup_levels=3"),
+    ("cube", [(0, 1, 2), (0, 1), (0, 2), (0,), (1, 2), (1,), (2,), ()],
+     "TpuExpand [8 projections]"),
 ])
-def test_grouping_sets_equal_the_union_of_group_bys(session, sales, how, sets):
+def test_grouping_sets_equal_the_union_of_group_bys(session, sales, how, sets,
+                                                    lowered):
     f = F()
     df = session.create_dataframe(sales)
     keys = ["a", "b", "c"]
     out = getattr(df, how)(*keys).agg(
         f.sum(f.col("v")).alias("v"), f.grouping_id().alias("gid"))
     tree = _on_device(session, out)
-    assert f"TpuExpand [{len(sets)} projections]" in tree
-    assert "Expand" in out.explain_string()
+    assert lowered in tree
+    assert ("Expand" in out.explain_string()) == (how == "cube")
     want = _grouping_sets_pandas(sales.to_pandas(), keys, sets)
     # a NULL from the data and a NULL from the set are different groups:
     # (None, None, None) occurs once a grouping id that nulls all three
@@ -247,14 +250,16 @@ def test_the_reporting_operators_are_counted_and_traced(fresh_session, sales):
     from spark_rapids_tpu.utils.metrics import QueryStats
     f, w = F(), W()
     df = fresh_session.create_dataframe(sales)
-    out = (df.rollup("a", "c").agg(f.sum(f.col("v")).alias("v"))
+    # a cube: a rollup's sets form a chain and take no Expand
+    out = (df.cube("a", "c").agg(f.sum(f.col("v")).alias("v"))
            .select("a", "c", "v", f.rank().over(
                w.partition_by("a").order_by(f.col("v").desc())).alias("rk")))
     with QueryStats.scoped() as qs:
         rows = out.collect()
     assert qs.window_rows == len(rows) and qs.window_exec_s > 0
-    # three projections of one 1,024-slot batch
-    assert qs.expand_slot_rows == 3 * 1024 and qs.expand_exec_s > 0
+    # four projections of one 1,024-slot batch
+    assert qs.expand_slot_rows == 4 * 1024 and qs.expand_exec_s > 0
+    assert qs.rollup_levels_merged == 0
     assert qs.cpu_fallback_nodes == 0
     names = {e[1] for e in fresh_session.last_trace().events}
     assert {"window:exec", "expand:project", "program:window",
@@ -328,11 +333,13 @@ def test_every_grouping_set_runs_one_aggregate_program(fresh_session):
         "dk": pa.array(np.arange(40), type=pa.int64()),
         "region": pa.array([f"r{i % 4}" for i in range(40)]),
         "name": pa.array([f"n{i}" for i in range(40)])}))
+    # a cube: a rollup's sets form a chain and take no Expand
     out = (fact.join(dim.hint("broadcast"), on=[("k", "dk")])
-           .rollup("region", "name", "w").agg(f.sum(f.col("v")).alias("s")))
+           .cube("region", "name", "w").agg(f.sum(f.col("v")).alias("s")))
     before = set(physical._STAGE_CACHE)
     rows = out.collect()
-    assert len(rows) == 1 + 4 + 40 + 80
+    # (), (w), (region), (region, w), (name), (region, name), and with w
+    assert len(rows) == 1 + 2 + 4 + 8 + 40 + 40 + 80 + 80
     new = [physical._STAGE_CACHE[k] for k in set(physical._STAGE_CACHE)
            - before if k.startswith("agg-grouped|")]
     assert new and all(p.call._cache_size() == 1 for p in new)
